@@ -13,9 +13,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from keystone_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.scipy.linalg import cho_factor, cho_solve
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -16,9 +16,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from keystone_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.scipy.linalg import solve_triangular
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -33,6 +33,9 @@ def _load() -> Optional[ctypes.CDLL]:
         return _lib
     # Always invoke make: it no-ops when up to date and rebuilds after source
     # edits; binaries are gitignored so a foreign-machine .so never ships.
+    # A failed make means unavailable — a .so left on disk by an earlier
+    # build is not what the sources say, and every entry point below raises
+    # with the build error rather than run it.
     try:
         subprocess.run(
             ["make", "-s"],
@@ -43,11 +46,10 @@ def _load() -> Optional[ctypes.CDLL]:
         )
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         _build_error = getattr(e, "stderr", str(e)) or str(e)
-        if not os.path.exists(_LIB_PATH):
-            return None
-    # Binding/ABI failures (stale .so from an older build + a failed make,
-    # missing optional symbols) must degrade to unavailable(), never raise —
-    # the auto ingest backend depends on a clean False to fall back to PIL.
+        return None
+    # Binding/ABI failures (missing optional symbols) must degrade to
+    # unavailable(), never raise — the auto ingest backend depends on a
+    # clean False to fall back to PIL.
     try:
         lib = ctypes.CDLL(_LIB_PATH)
         f32p = ctypes.POINTER(ctypes.c_float)

@@ -59,7 +59,12 @@ class BlockLinearMapper(Transformer):
                 out = out + np.asarray(self.b)
             return out
         for (s, e), w in zip(self.blocks, self.W_blocks):
-            contrib = X[..., s:e] @ w
+            # HIGHEST like the solve that produced W: at the TPU default
+            # (one bf16 pass) a 65,536-term score is 3e-3 off float32
+            # (measured on a v5e).
+            contrib = jnp.matmul(
+                X[..., s:e], w, precision=jax.lax.Precision.HIGHEST
+            )
             out = contrib if out is None else out + contrib
         if self.b is not None:
             out = out + self.b
@@ -73,20 +78,23 @@ class BlockLinearMapper(Transformer):
 def resolve_block_size(block_size, d: int) -> int:
     """Resolve ``block_size="auto"`` to the largest memory-safe block.
 
-    The r3 silicon sweep showed solver TFLOPS rising ~8× from block 1024
-    to 8192 (larger blocks = bigger MXU gemms and fewer sequentially-
-    lowered factorizations), so auto picks the smallest power of two that
-    covers d — i.e. a single exact block whenever d fits — capped at 8192
-    on accelerators (4096, the historical fixed default, on CPU, whose
-    factorizations don't tile) and shrunk until the cached ridge inverses
-    (d·b bytes) stay within a quarter of the HBM budget, the same envelope
-    the gram-cache auto rule assumes."""
+    Larger blocks mean bigger MXU gemms and fewer sequentially-lowered
+    factorizations (the 2026-07-29 one-chip sweep rose ~8× in solver
+    TFLOPS from block 1024 to 8192), so auto picks the smallest power of
+    two that covers d — i.e. a single exact block whenever d fits — capped
+    at 8192 on accelerators (4096, the historical fixed default, on CPU,
+    whose factorizations don't tile) and shrunk until the cached ridge
+    inverses (d·b bytes) stay within a quarter of what the device reports
+    (``device_hbm_bytes``), the same envelope the gram-cache auto rule
+    assumes."""
     if block_size != "auto":
         return int(block_size)
+    from keystone_tpu.utils.metrics import device_hbm_bytes
+
     cap = 4096 if jax.default_backend() == "cpu" else 8192
     b = min(cap, 1 << int(np.ceil(np.log2(max(d, 128)))))
     itemsize = jnp.dtype(config.default_dtype).itemsize
-    while b > 128 and d * b * itemsize > config.hbm_budget_bytes // 4:
+    while b > 128 and d * b * itemsize > device_hbm_bytes() // 4:
         b //= 2
     return b
 
@@ -167,8 +175,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         stream = self.stream
         itemsize = jnp.dtype(config.default_dtype).itemsize
         if stream is None:
+            from keystone_tpu.utils.metrics import device_hbm_bytes
+
             a_bytes = int(np.prod(np.shape(data))) * itemsize
-            stream = a_bytes > config.hbm_budget_bytes // 2
+            stream = a_bytes > device_hbm_bytes() // 2
 
         if stream:
             # Features stay in host RAM — the caller's array, uncopied and

@@ -22,6 +22,21 @@ from keystone_tpu.config import config
 from keystone_tpu.nodes.learning.kmeans import _fit_kmeans, _sq_dists
 from keystone_tpu.workflow import Estimator, Transformer
 
+# HIGHEST precision throughout: ||(x - μ)/σ||² is expanded into gemm-shaped
+# terms of order 1e2..1e3 that cancel, and the moments ex2 - mean² cancel
+# again. At the TPU default (one bf16 pass) the EM converges to a different
+# mixture than float32 does (measured on a v5e; see fisher_vector._fv_tpu).
+_mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
+def _quad(X, means, inv):
+    """(n, k) ||(x - μ_j)/σ_j||², gemm-shaped."""
+    return (
+        _mm(X * X, inv.T)
+        - 2.0 * _mm(X, (means * inv).T)
+        + jnp.sum(means * means * inv, axis=1)
+    )
+
 
 class GaussianMixtureModel(Transformer):
     """Fitted GMM. As a transformer it emits per-component soft assignments
@@ -35,13 +50,7 @@ class GaussianMixtureModel(Transformer):
     def log_likelihoods(self, X):
         """(n, k) log p(x | component j) + log w_j."""
         X = jnp.asarray(X)
-        inv = 1.0 / self.variances  # (k, d)
-        # Expand ||(x - μ)/σ||² into gemm-shaped terms.
-        quad = (
-            (X * X) @ inv.T
-            - 2.0 * X @ (self.means * inv).T
-            + jnp.sum(self.means * self.means * inv, axis=1)
-        )
+        quad = _quad(X, self.means, 1.0 / self.variances)
         log_det = jnp.sum(jnp.log(self.variances), axis=1)
         d = X.shape[1]
         log_norm = -0.5 * (d * jnp.log(2 * jnp.pi) + log_det)
@@ -65,26 +74,21 @@ def _fit_gmm(X, key, k: int, max_iters: int, min_var: float):
     onehot = jax.nn.one_hot(assign, k, dtype=X.dtype)
     counts = jnp.maximum(onehot.sum(axis=0), 1.0)
     weights0 = counts / n
-    means0 = (onehot.T @ X) / counts[:, None]
-    ex2 = (onehot.T @ (X * X)) / counts[:, None]
+    means0 = _mm(onehot.T, X) / counts[:, None]
+    ex2 = _mm(onehot.T, X * X) / counts[:, None]
     vars0 = jnp.maximum(ex2 - means0**2, min_var)
 
     def em(_i, carry):
         weights, means, variances = carry
-        inv = 1.0 / variances
-        quad = (
-            (X * X) @ inv.T
-            - 2.0 * X @ (means * inv).T
-            + jnp.sum(means * means * inv, axis=1)
-        )
+        quad = _quad(X, means, 1.0 / variances)
         log_norm = -0.5 * (
             d * jnp.log(2 * jnp.pi) + jnp.sum(jnp.log(variances), axis=1)
         )
         log_r = jnp.log(weights) + log_norm - 0.5 * quad
         r = jax.nn.softmax(log_r, axis=-1)  # (n, k)
         nk = jnp.maximum(r.sum(axis=0), 1e-6)
-        new_means = (r.T @ X) / nk[:, None]
-        new_ex2 = (r.T @ (X * X)) / nk[:, None]
+        new_means = _mm(r.T, X) / nk[:, None]
+        new_ex2 = _mm(r.T, X * X) / nk[:, None]
         new_vars = jnp.maximum(new_ex2 - new_means**2, min_var)
         return nk / n, new_means, new_vars
 

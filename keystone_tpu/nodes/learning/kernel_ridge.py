@@ -20,9 +20,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-from keystone_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from keystone_tpu.config import config

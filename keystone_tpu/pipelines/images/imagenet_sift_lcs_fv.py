@@ -31,7 +31,7 @@ from typing import Optional
 
 import numpy as np
 
-from keystone_tpu.loaders.imagenet import ImageNetLoader
+from keystone_tpu.loaders.imagenet import ImageNetLoader, LabeledData
 from keystone_tpu.nodes.images import GrayScaler
 from keystone_tpu.nodes.images.external import SIFTExtractor
 from keystone_tpu.nodes.images.external.fisher_vector import (
@@ -73,7 +73,7 @@ class ImageNetSiftLcsFVConfig:
     label_map_path: Optional[str] = None
     sift_step: int = 4
     sift_bin: int = 4
-    sift_backend: str = "native"
+    sift_backend: str = "xla"
     lcs_step: int = 4
     lcs_bin: int = 4
     pca_dims: int = 64
@@ -338,24 +338,28 @@ def run_streamed(conf: ImageNetSiftLcsFVConfig) -> dict:
     }
 
 
-def run(conf: ImageNetSiftLcsFVConfig) -> dict:
-    conf = resolve_scale(conf)
-    if conf.stream:
-        return run_streamed(conf)
+def load_data(conf: ImageNetSiftLcsFVConfig):
+    """(train, test, num_classes) for the in-memory path: the real data
+    when ``conf.data_path`` is set, the seeded synthetic set otherwise."""
     if conf.data_path:
         if not (conf.test_data_path and conf.label_map_path):
             raise ValueError("real data requires test path and label map")
         label_map = ImageNetLoader.load_label_map(conf.label_map_path)
         train = ImageNetLoader.load(conf.data_path, label_map)
         test = ImageNetLoader.load(conf.test_data_path, label_map)
-        num_classes = int(max(train.labels.max(), test.labels.max())) + 1
-    else:
-        train, test = ImageNetLoader.synthetic(
-            n=conf.synthetic_n, num_classes=conf.synthetic_classes
-        )
-        num_classes = conf.synthetic_classes
+        return train, test, int(max(train.labels.max(), test.labels.max())) + 1
+    train, test = ImageNetLoader.synthetic(
+        n=conf.synthetic_n, num_classes=conf.synthetic_classes
+    )
+    return train, test, conf.synthetic_classes
 
-    t0 = time.perf_counter()
+
+def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
+    """Fit the in-memory pipeline on ``train``: returns ``(featurizer,
+    scored)``, the fitted two-branch featurizer (image → gathered Fisher
+    vectors) and the fitted pipeline (image → class scores). The one
+    construction shared by ``run`` (the CLI) and ``chip_smoke.py``;
+    ``conf`` must already be through ``resolve_scale``."""
     featurizer = build_featurizer(conf, train.data)
     targets = ClassLabelIndicators(num_classes)(train.labels)
     solver = BlockWeightedLeastSquaresEstimator(
@@ -365,14 +369,30 @@ def run(conf: ImageNetSiftLcsFVConfig) -> dict:
         mixture_weight=conf.mixture_weight,
         checkpoint_dir=conf.checkpoint_dir,
     )
-    scored = featurizer.and_then(solver, train.data, targets)
+    return featurizer, featurizer.and_then(solver, train.data, targets).fit()
+
+
+def top_k_pipeline(conf: ImageNetSiftLcsFVConfig, scored: Pipeline) -> Pipeline:
+    """Image in, top-k class indices out: what evaluation scores and what a
+    daemon serves."""
+    return scored.and_then(TopKClassifier(conf.top_k))
+
+
+def run(conf: ImageNetSiftLcsFVConfig) -> dict:
+    conf = resolve_scale(conf)
+    if conf.stream:
+        return run_streamed(conf)
+    train, test, num_classes = load_data(conf)
+
+    t0 = time.perf_counter()
+    _featurizer, scored = fit(conf, train, num_classes)
     if conf.augment:
         patcher, averager = _build_tta(conf, test.data.shape[1])
         view_scores = np.asarray(scored(patcher(test.data)).get())
         avg = averager.average_scores(view_scores)
         topk = np.asarray(TopKClassifier(conf.top_k)(avg))
     else:
-        pipeline = scored.and_then(TopKClassifier(conf.top_k))
+        pipeline = top_k_pipeline(conf, scored)
         topk = np.asarray(pipeline(test.data).get())  # (n, top_k)
     elapsed = time.perf_counter() - t0
 
@@ -411,8 +431,9 @@ def main(argv=None):
     p.add_argument("--augment-crop", type=int, default=0,
                    help="crop side in pixels (0 = 7/8 of the image side)")
     p.add_argument("--fv-backend", choices=["tpu", "pallas", "native"], default="tpu")
-    p.add_argument("--sift-backend", choices=["native", "xla"], default="native",
-                   help="xla runs dense SIFT on the device (host keeps only decode)")
+    p.add_argument("--sift-backend", choices=["native", "xla"], default="xla",
+                   help="xla runs dense SIFT on the device (host keeps only "
+                   "decode); native is the C++ kernel on the host")
     p.add_argument("--stream", action="store_true",
                    help="out-of-core: stream images, hold only features")
     p.add_argument("--stream-batch", type=int, default=256)

@@ -1,8 +1,8 @@
 """VOCSIFTFisher — SIFT + Fisher-vector VOC multi-label pipeline.
 
 Ref: src/main/scala/pipelines/images/voc/VOCSIFTFisher.scala
-(SURVEY.md §2.11, §3.4) [unverified]: grayscale → native dense SIFT →
-PCA (fit on a descriptor sample) → GMM (native EM) → FisherVector →
+(SURVEY.md §2.11, §3.4) [unverified]: grayscale → dense SIFT →
+PCA (fit on a descriptor sample) → GMM (EM) → FisherVector →
 SignedHellingerMapper → L2 normalize → block least squares → mAP.
 """
 
@@ -36,7 +36,7 @@ class VOCSIFTFisherConfig:
     test_annotation_dir: Optional[str] = None
     sift_step: int = 4
     sift_bin: int = 4
-    sift_backend: str = "native"
+    sift_backend: str = "xla"
     pca_dims: int = 64
     gmm_k: int = 16
     gmm_iters: int = 20
@@ -124,8 +124,9 @@ def main(argv=None):
     p.add_argument("--gmm-k", type=int, default=16)
     p.add_argument("--lam", type=float, default=1e-3)
     p.add_argument("--fv-backend", choices=["tpu", "pallas", "native"], default="tpu")
-    p.add_argument("--sift-backend", choices=["native", "xla"], default="native",
-                   help="xla runs dense SIFT on the device (host keeps only decode)")
+    p.add_argument("--sift-backend", choices=["native", "xla"], default="xla",
+                   help="xla runs dense SIFT on the device (host keeps only "
+                   "decode); native is the C++ kernel on the host")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--synthetic-n", type=int, default=192)
     a = p.parse_args(argv)

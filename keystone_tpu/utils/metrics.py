@@ -987,10 +987,9 @@ def environment_fingerprint(devices: bool = True) -> Dict[str, Any]:
     cross-run comparisons (e.g. a p99 delta between rounds) are
     interpretable instead of mystery noise.
 
-    ``devices=False`` skips the device probe — for orchestrator processes
-    (bench.py's driver, tools/bench_mfu.py) that deliberately never
-    initialize the backend in-process because a dead TPU plugin can HANG
-    initialization, not just fail it."""
+    ``devices=False`` skips the device probe — for a parent process
+    (tools/bench_mfu.py) that must not initialize the backend: a chip
+    belongs to one process at a time, and its children need it."""
     import platform as _platform
 
     def _redact(name: str, value: str) -> str:
@@ -1076,10 +1075,12 @@ def _memory_stats_device():
 
 def device_hbm_bytes(default: int | None = None) -> int:
     """Memory budget of device 0 as the runtime reports it (``bytes_limit``
-    from ``memory_stats``), falling back to ``config.hbm_budget_bytes`` for
-    backends that don't report one (notably CPU). The device probe AND the
-    reported limit are memoized per process — the limit is static, and
-    re-asking the runtime per call is a host sync. Always returns an int."""
+    from ``memory_stats``). The CPU backend reports none and gets
+    ``config.hbm_budget_bytes``; a TPU that reports none is an error —
+    sizing a TPU program against a constant it never confirmed is how a
+    block that does not fit gets chosen. The device probe AND the reported
+    limit are memoized per process — the limit is static, and re-asking
+    the runtime per call is a host sync. Always returns an int."""
     from keystone_tpu.config import config
 
     global _hbm_limit_memo
@@ -1095,6 +1096,12 @@ def device_hbm_bytes(default: int | None = None) -> int:
                     found = int(raw)
             except Exception:  # lint: broad-ok backend-specific probe failures all mean 'no reported limit'
                 pass
+            if found is False and dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev} reports no bytes_limit in memory_stats(); "
+                    "refusing to size TPU programs against the CPU default "
+                    "config.hbm_budget_bytes"
+                )
         with _memprobe_lock:
             _hbm_limit_memo = found
         limit = found
@@ -1106,11 +1113,8 @@ def device_hbm_bytes(default: int | None = None) -> int:
 def peak_hbm_bytes() -> int | None:
     """HBM high-water of device 0 (``peak_bytes_in_use``), or None where
     the runtime doesn't report it (notably CPU). Shared by the
-    single-number evidence rows (bench line, streamed-overlap step) and
-    the profiler's per-node HBM deltas; the checkride ``memory_stats``
-    step deliberately keeps its own multi-key probe — it exists to record
-    the runtime's whole key set, including whatever a different runtime
-    names the peak.
+    single-number evidence rows (bench line, chip_smoke.py) and the
+    profiler's per-node HBM deltas.
 
     The device handle and the does-this-runtime-report-a-peak verdict are
     memoized per process (the CPU backend answers None forever; asking it
@@ -1660,19 +1664,24 @@ class CompileEventCounter:
     """Counts XLA backend compiles via ``jax.monitoring`` — each compile
     emits one compile-cache event. THE process's compile oracle, shared by
     the serving bench and the zero-post-warmup-compile tests so they can't
-    drift apart if a jax upgrade renames the event. Listener registration
-    is global and permanent: create one per process and snapshot
-    ``.count`` around phases."""
+    drift apart if a jax upgrade renames the event. ``.hits`` counts the
+    requests the persistent compilation cache answered from disk.
+    Listener registration is global and permanent: create one per process
+    and snapshot ``.count`` around phases."""
 
     EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+    HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
     def __init__(self):
         self.count = 0
+        self.hits = 0
         jax.monitoring.register_event_listener(self._on_event)
 
     def _on_event(self, name, **kwargs):
         if name == self.EVENT:
             self.count += 1
+        elif name == self.HIT_EVENT:
+            self.hits += 1
 
 
 class ServingCounters:
@@ -1797,6 +1806,9 @@ class ShardingCounters(CounterSet):
     - ``pallas_sharded_calls`` — sharded chain executions whose lowered
       body runs a Pallas kernel (``uses_pallas``) — the 'kernel actually
       active on the sharded path' evidence the ImageNet bench gates on
+    - ``pallas_mosaic_calls`` / ``pallas_interpret_calls`` — calls of the
+      Pallas Fisher-vector kernel lowered through Mosaic, or run by the
+      Pallas interpreter (CPU only); chip_smoke.py asserts the former
     """
 
 

@@ -18,8 +18,6 @@ Rule catalog (KG = Keystone Graph):
 - ``KG002 serve-row-coupled`` — a ``row_independent=False`` stage on the
   chain: bucket padding would change real outputs
   (``RowDependenceError`` at serve time).
-- ``KG003 serve-nonlinear`` — a gather join / multi-input node on the
-  chain: the bucketed engine compiles ONE linear program per bucket.
 - ``KG101 recompile-hazard`` — a shape-polymorphic input feeding jit
   consumers with no bucket ladder configured: every distinct row count
   recompiles the whole fused chain.
@@ -115,7 +113,6 @@ logger = logging.getLogger("keystone_tpu")
 GRAPH_RULES: Dict[str, str] = {
     "KG001": "non-jittable (host) transformer on the serving chain",
     "KG002": "row-coupled stage on the serving chain (padding unsound)",
-    "KG003": "gather/multi-input node on the serving chain (not linear)",
     "KG101": "shape-polymorphic input feeds jit consumers without buckets",
     "KG102": "silent dtype upcast / mixed-dtype seam across nodes",
     "KG103": "dataset batch rows never divide the active data mesh",
@@ -316,27 +313,32 @@ def propagate_specs(
 def _walk_serve_chain(graph: Graph, source: SourceId, sink: GraphId):
     """Walk sink -> source the way ``GraphExecutor.serving_chain`` would
     after fit: see through cache nodes, follow a DelegatingOperator's
-    input edge (its estimator resolves at fit time). Returns
-    (chain_nodes sink-first, first_nonlinear_node_or_None)."""
+    input edge (its estimator resolves at fit time), and walk every
+    branch of a gather join (it lowers to one ``GatherTransformer``).
+    Returns the serve-path nodes, sink-first per branch."""
     chain: List[NodeId] = []
-    gid: GraphId = sink
-    while gid != source:
-        if isinstance(gid, SourceId):
-            return chain, None  # foreign source; composition artifact
-        op = graph.operators[gid]
-        deps = graph.dependencies[gid]
-        if getattr(op, "persist", False):
-            gid = deps[0]
-            continue
-        if isinstance(op, DelegatingOperator):
-            chain.append(gid)
-            gid = deps[1]  # [estimator, input]
-            continue
-        if isinstance(op, GatherOperator) or len(deps) != 1:
-            return chain, gid
-        chain.append(gid)
-        gid = deps[0]
-    return chain, None
+    pending: List[GraphId] = [sink]
+    while pending:
+        gid = pending.pop()
+        while gid != source:
+            if isinstance(gid, SourceId):
+                break  # foreign source; composition artifact
+            op = graph.operators[gid]
+            deps = graph.dependencies[gid]
+            if getattr(op, "persist", False):
+                gid = deps[0]
+            elif isinstance(op, DelegatingOperator):
+                chain.append(gid)
+                gid = deps[1]  # [estimator, input]
+            elif isinstance(op, GatherOperator):
+                pending.extend(deps)
+                break
+            elif len(deps) != 1:
+                break  # a dataset-rooted graph: no serve input to reach
+            else:
+                chain.append(gid)
+                gid = deps[0]
+    return chain
 
 
 # ---------------------------------------------------------------------------
@@ -381,18 +383,8 @@ def lint_graph(
                 hint="prune with graph.pruned([sink])",
             ))
 
-    # -- serveability: KG001 / KG002 / KG003 -------------------------------
-    chain, nonlinear = _walk_serve_chain(graph, source, sink)
-    if nonlinear is not None:
-        emit(Diagnostic(
-            "KG003", serve_sev, _node_label(graph, nonlinear),
-            f"{graph.operators[nonlinear].label()} joins multiple inputs; "
-            "the bucketed serving engine compiles one linear program per "
-            "bucket and cannot host a join",
-            hint="serve the branches separately, or apply the gathered "
-                 "pipeline un-compiled (per-shape jit)",
-        ))
-    for nid in chain:
+    # -- serveability: KG001 / KG002 ---------------------------------------
+    for nid in _walk_serve_chain(graph, source, sink):
         op = graph.operators[nid]
         if not isinstance(op, TransformerOperator):
             continue

@@ -32,6 +32,7 @@ from keystone_tpu.workflow.graph import (
 from keystone_tpu.workflow.operators import (
     DelegatingOperator,
     EstimatorOperator,
+    GatherOperator,
     Operator,
     TransformerOperator,
 )
@@ -721,53 +722,67 @@ class GraphExecutor:
 
     def serving_chain(self, graph: Graph, source: SourceId, sink: GraphId):
         """Lower a FITTED pipeline graph to the one transformer the serving
-        layer AOT-compiles: optimize (fusing jittable chains), then require
-        the source→sink path to be a linear chain of jittable
-        TransformerOperators. Identity cache nodes are seen through;
-        anything else (gather joins, unfitted estimators, host nodes) is
-        refused with an error naming the offender — the serving engine
-        compiles ONE program per bucket and cannot host-hop mid-chain.
+        layer AOT-compiles: optimize (fusing jittable chains), then walk
+        sink→source requiring jittable TransformerOperators. A gather
+        join lowers to a ``GatherTransformer`` over its lowered branches,
+        so the two-branch featurizers stay one program. Identity cache
+        nodes are seen through; anything else (unfitted estimators, host
+        nodes) is refused with an error naming the offender — the serving
+        engine compiles ONE program per bucket and cannot host-hop
+        mid-chain.
         """
-        from keystone_tpu.workflow.pipeline import FusedTransformer
+        from keystone_tpu.workflow.pipeline import (
+            FusedTransformer,
+            GatherTransformer,
+        )
 
         g = self.env.optimizer.execute(graph, [sink])
-        chain: List[Any] = []
-        gid = sink
-        while gid != source:
-            if isinstance(gid, SourceId):
-                raise ValueError(
-                    f"serve path ends at foreign source {gid!r}, not the "
-                    "pipeline's own input"
-                )
-            op = g.operators[gid]
-            deps = g.dependencies[gid]
-            if getattr(op, "persist", False):  # identity Cache node
+
+        def fuse(stages):
+            return stages[0] if len(stages) == 1 else FusedTransformer(stages)
+
+        def lower(gid) -> List[Any]:
+            """The stages computing node ``gid`` from the pipeline input."""
+            chain: List[Any] = []
+            while gid != source:
+                if isinstance(gid, SourceId):
+                    raise ValueError(
+                        f"serve path ends at foreign source {gid!r}, not "
+                        "the pipeline's own input"
+                    )
+                op = g.operators[gid]
+                deps = g.dependencies[gid]
+                if getattr(op, "persist", False):  # identity Cache node
+                    gid = deps[0]
+                    continue
+                if isinstance(op, GatherOperator):
+                    # Every branch runs back to the input on its own.
+                    chain.append(
+                        GatherTransformer([fuse(lower(d)) for d in deps])
+                    )
+                    break
+                if not isinstance(op, TransformerOperator):
+                    raise TypeError(
+                        f"cannot compile {op.label()} for serving: the "
+                        "serve path must be fitted transformers (fit the "
+                        "pipeline first; estimator/host nodes cannot join "
+                        "the single-program bucketed executable)"
+                    )
+                if not op.transformer.jittable:
+                    raise TypeError(
+                        f"{type(op.transformer).__name__} is not jittable; "
+                        "the AOT serving path compiles the whole chain as "
+                        "one XLA program"
+                    )
+                chain.append(op.transformer)
                 gid = deps[0]
-                continue
-            if not isinstance(op, TransformerOperator):
-                raise TypeError(
-                    f"cannot compile {op.label()} for serving: the serve "
-                    "path must be a fitted, linear transformer chain (fit "
-                    "the pipeline first; gather/estimator/host nodes cannot "
-                    "join the single-program bucketed executable)"
-                )
-            if not op.transformer.jittable:
-                raise TypeError(
-                    f"{type(op.transformer).__name__} is not jittable; the "
-                    "AOT serving path compiles the whole chain as one XLA "
-                    "program"
-                )
-            if len(deps) != 1:
-                raise TypeError(
-                    f"serve path node {op.label()} has {len(deps)} inputs; "
-                    "bucketed serving requires a linear chain"
-                )
-            chain.append(op.transformer)
-            gid = deps[0]
+            chain.reverse()
+            return chain
+
+        chain = lower(sink)
         if not chain:
             raise ValueError("pipeline has no transformers on the serve path")
-        chain.reverse()
-        return chain[0] if len(chain) == 1 else FusedTransformer(chain)
+        return fuse(chain)
 
 
 class PipelineEnv:

@@ -378,6 +378,43 @@ class FusedTransformer(Transformer):
         return "Fused(" + " | ".join(type(s).__name__ for s in self.stages) + ")"
 
 
+class GatherTransformer(Transformer):
+    """Branches over one input, outputs concatenated on the feature axis:
+    ``Pipeline.gather`` as a single transformer.
+
+    The serving engine compiles ONE program per bucket, so
+    ``GraphExecutor.serving_chain`` lowers a fitted gather join (the
+    two-branch ImageNet featurizer) to this; XLA sees both branches and
+    the concatenate in the same computation.
+    """
+
+    def __init__(self, branches: Sequence[Transformer]):
+        self.branches = list(branches)
+        self.jittable = all(b.jittable for b in self.branches)
+        self.row_independent = all(
+            getattr(b, "row_independent", True) for b in self.branches
+        )
+        self.uses_pallas = any(
+            getattr(b, "uses_pallas", False) for b in self.branches
+        )
+
+    def apply_batch(self, X):
+        return jnp.concatenate(
+            [b.apply_batch(X) for b in self.branches], axis=-1
+        )
+
+    def apply_sharded(self, X, layout):
+        return jnp.concatenate(
+            [b.apply_sharded(X, layout) for b in self.branches], axis=-1
+        )
+
+    def signature(self):
+        return ("gather",) + tuple(b.signature() for b in self.branches)
+
+    def __repr__(self):
+        return "Gather(" + ", ".join(repr(b) for b in self.branches) + ")"
+
+
 # ---------------------------------------------------------------------------
 # Estimators
 # ---------------------------------------------------------------------------
@@ -877,14 +914,14 @@ class Pipeline:
         ladder — on every device of the replica pool (``devices=``, env
         ``KEYSTONE_SERVE_DEVICES``, default all local) — before first
         traffic, then serve mixed-size batches with zero steady-state
-        recompiles. Requires the serve path to be a linear chain of
-        jittable, row-independent transformers.
+        recompiles. Requires the serve path to be jittable,
+        row-independent transformers (chains, and gather joins of them).
         """
         from keystone_tpu.workflow.analysis import enforce_lint
         from keystone_tpu.workflow.serving import CompiledPipeline
 
         # Opt-in static gate: with KEYSTONE_LINT=error a chain the engine
-        # would refuse (host/row-coupled/gather nodes) fails HERE, with a
+        # would refuse (host/row-coupled nodes) fails HERE, with a
         # rule id and fix hint, before fit or warmup spend any compute.
         # The engine always has a bucket ladder, so KG101 is moot.
         enforce_lint(self, "compiled", serve=True, have_ladder=True)

@@ -60,7 +60,6 @@ def test_augmented_evaluator():
         ev.average_scores(scores[:3])
 
 
-@needs_native
 def test_voc_sift_fisher_end_to_end():
     from keystone_tpu.pipelines.images.voc_sift_fisher import (
         VOCSIFTFisherConfig,
@@ -82,7 +81,6 @@ def test_voc_sift_fisher_end_to_end():
     assert out["map"] > 0.7, out["summary"]
 
 
-@needs_native
 def test_imagenet_sift_lcs_fv_end_to_end():
     from keystone_tpu.pipelines.images.imagenet_sift_lcs_fv import (
         ImageNetSiftLcsFVConfig,
@@ -191,7 +189,9 @@ def test_fitted_native_pipeline_save_load(tmp_path):
     from keystone_tpu.workflow import load_pipeline, save_pipeline
 
     train, test = VOCLoader.synthetic(n=48, num_classes=4)
-    conf = VOCSIFTFisherConfig(pca_dims=16, gmm_k=4, descriptor_sample=10000)
+    conf = VOCSIFTFisherConfig(
+        pca_dims=16, gmm_k=4, descriptor_sample=10000, sift_backend="native"
+    )
     feat = build_featurizer(conf, train.data)
     targets = (2.0 * train.labels - 1.0).astype(np.float32)
     p = feat.and_then(
@@ -207,7 +207,6 @@ def test_fitted_native_pipeline_save_load(tmp_path):
     )
 
 
-@needs_native
 def test_imagenet_with_test_time_augmentation():
     from keystone_tpu.pipelines.images.imagenet_sift_lcs_fv import (
         ImageNetSiftLcsFVConfig,

@@ -60,7 +60,6 @@ def test_two_process_psum_solve(tmp_path):
     for pid in (0, 1):
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["KEYSTONE_PLATFORM"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["KEYSTONE_COORDINATOR"] = f"127.0.0.1:{port}"
         env["KEYSTONE_NUM_PROCESSES"] = "2"

@@ -123,6 +123,7 @@ def test_voc_fisher_bit_identical():
     train, test = VOCLoader.synthetic(n=32, num_classes=4)
     conf = VOCSIFTFisherConfig(
         pca_dims=8, gmm_k=2, gmm_iters=2, descriptor_sample=5000,
+        sift_backend="native",  # the host-bound node the walk overlaps
     )
     feat = build_featurizer(conf, train.data)
     targets = (2.0 * train.labels - 1.0).astype(np.float32)
@@ -148,6 +149,7 @@ def test_imagenet_two_branch_featurizer_bit_identical():
     train, test = ImageNetLoader.synthetic(n=24, num_classes=4, size=32)
     conf = resolve_scale(ImageNetSiftLcsFVConfig(
         pca_dims=8, gmm_k=2, gmm_iters=2, descriptor_sample=5000,
+        sift_backend="native",
     ))
     feat = build_featurizer(conf, train.data)
     _assert_walks_agree(feat, test.data[:8])

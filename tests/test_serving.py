@@ -243,7 +243,7 @@ def test_compiled_pipeline_from_fitted_estimator_pipeline(rng):
     )
 
 
-def test_serving_refuses_nonlinear_and_host_chains(rng):
+def test_serving_refuses_host_chains_and_compiles_gather_joins(rng):
     from keystone_tpu.workflow import Pipeline
 
     class HostOp(Transformer):
@@ -254,9 +254,16 @@ def test_serving_refuses_nonlinear_and_host_chains(rng):
 
     with pytest.raises(TypeError, match="jittable"):
         CompiledPipeline(HostOp())
-    gathered = Pipeline.gather([L2Normalizer(), SignedHellingerMapper()])
-    with pytest.raises(TypeError, match="linear"):
-        gathered.compiled()
+    with pytest.raises(TypeError, match="jittable"):
+        Pipeline.gather([L2Normalizer(), HostOp()]).compiled()
+    # A gather join of jittable branches is one program: the served rows
+    # equal the un-compiled pipeline's, tail stage included.
+    gathered = Pipeline.gather(
+        [L2Normalizer(), SignedHellingerMapper().and_then(L2Normalizer())]
+    ).and_then(SignedHellingerMapper())
+    X = rng.normal(size=(5, 6)).astype(np.float32)
+    served = gathered.compiled(buckets=(8,), devices=1)(X)
+    np.testing.assert_array_equal(served, np.asarray(gathered(X).get()))
 
 
 # ---------------------------------------------------------------------------

@@ -520,9 +520,9 @@ def main() -> None:
                     "BENCH_serve.json")
     args = ap.parse_args()
 
-    from keystone_tpu.utils.platform import ensure_live_backend
+    from keystone_tpu.utils.platform import device_info
 
-    backend = ensure_live_backend()
+    backend = device_info()["platform"]
 
     from bench_serve import write_result
     from keystone_tpu.config import config

@@ -9,7 +9,7 @@ Generates a synthetic-JPEG synset tree, then measures:
    vs serial decode-then-featurize.
 
 Usage: python tools/bench_ingest.py [--images 512] [--size 256]
-Prints one JSON line; paste the numbers into NOTES_r2.md.
+Prints one JSON line.
 
 --stream-solve switches to the chunked-solver overlap benchmark instead:
 a synthetic out-of-core row stream whose producer is priced like a real
@@ -77,9 +77,9 @@ def stream_solve(args) -> None:
     - overlapped: the PrefetchIterator + double-buffered H2D + donated
       accumulation path.
     """
-    from keystone_tpu.utils.platform import ensure_live_backend
+    from keystone_tpu.utils.platform import device_info
 
-    backend = ensure_live_backend()
+    backend = device_info()["platform"]
     import jax
 
     from keystone_tpu.linalg import solve_least_squares_chunked
@@ -215,9 +215,9 @@ def main() -> None:
         stream_solve(args)
         return
 
-    from keystone_tpu.utils.platform import ensure_live_backend
+    from keystone_tpu.utils.platform import device_info
 
-    backend = ensure_live_backend()
+    backend = device_info()["platform"]
     import jax
     import jax.numpy as jnp
     from jax import lax

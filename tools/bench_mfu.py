@@ -1,16 +1,16 @@
 """Block-size / dtype MFU sweep for the BCD solver (BASELINE.md north-star
 metric prep — VERDICT round-2 item 2).
 
-For each (block, dtype) it runs the bench worker's solve, converts the
-analytic FLOP count to TFLOPS/chip, and reports MFU against the chip's
-plausible peak. Run on a live TPU:
+For each (block, dtype) it runs ``bench.py`` in a child process, and reports
+its TFLOPS/chip against the peak of that mode on the chip the child named.
+Needs a TPU (``bench.py`` exits non-zero without one):
 
     python tools/bench_mfu.py --blocks 1024 2048 4096 8192 --dtypes f32 bf16
 
-On CPU it still runs (scaled-down problem, labelled) so the harness itself
-stays verified while the chip is down. Prints one JSON line per config plus
-a final summary table on stderr. Configs that clamp to the same effective
-block (CPU scale has d=2048) are measured once.
+This parent never touches JAX: a chip belongs to one process at a time, and
+each child takes it in turn. Prints one JSON line per config plus a final
+summary table on stderr. Configs that clamp to the same effective block are
+measured once.
 """
 
 from __future__ import annotations
@@ -18,61 +18,68 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
-import bench  # repo-root bench.py: worker protocol + plausible peaks
+import bench  # repo-root bench.py: the dtype modes (imports no jax)
+
+
+def run_bench(env: dict, scale_key: str, dtype: str, timeout: float):
+    """``bench.py`` in a child; its JSON line, or None with the child's
+    stderr tail on ours."""
+    cmd = [sys.executable, os.path.join(REPO, "bench.py"),
+           "--scale", scale_key, "--dtype", dtype]
+    try:
+        proc = subprocess.run(
+            cmd, env=env, capture_output=True, text=True, timeout=timeout
+        )
+    except subprocess.TimeoutExpired as e:
+        tail = e.stderr or b""
+        if isinstance(tail, bytes):
+            tail = tail.decode(errors="replace")
+        print(f"bench.py timed out; stderr tail:\n{tail[-2000:]}",
+              file=sys.stderr)
+        return None
+    for line in reversed(proc.stdout.strip().splitlines()):
+        try:
+            parsed = json.loads(line)
+        except ValueError:
+            continue
+        if isinstance(parsed, dict) and "metric" in parsed:
+            return parsed
+    print(f"bench.py rc={proc.returncode}, no JSON line; stderr tail:\n"
+          f"{(proc.stderr or '')[-2000:]}", file=sys.stderr)
+    return None
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", type=int, nargs="+",
                     default=[1024, 2048, 4096, 8192])
-    ap.add_argument("--dtypes", nargs="+",
-                    choices=sorted(bench.PLAUSIBLE_PEAK_TFLOPS),
+    ap.add_argument("--dtypes", nargs="+", choices=sorted(bench.MXU_PASSES),
                     default=["f32", "bf16", "f32h"])
     ap.add_argument("--timeout", type=float, default=900.0)
-    ap.add_argument(
-        "--scale",
-        choices=["auto", "tpu", "tpu-xl"],
-        default="auto",
-        help="auto = tpu when live / cpu fallback; tpu-xl = the "
-        "reference-scale d=262144 config (live TPU only)",
-    )
+    ap.add_argument("--scale", choices=["tpu", "tpu-xl"], default="tpu",
+                    help="tpu-xl = the reference-scale d=262144 config")
     args = ap.parse_args()
 
     from keystone_tpu.utils.metrics import environment_fingerprint
-    from keystone_tpu.utils.platform import cpu_mesh_env, probe_backend
 
     # One provenance line up front (deviceless: this process never inits
-    # the backend — workers do); each row then only carries its backend.
+    # the backend — the children do); each row then names its device.
     print(json.dumps({
         "metric": "env_fingerprint",
         **environment_fingerprint(devices=False),
     }), flush=True)
 
-    def probe_live_tpu() -> bool:
-        info = probe_backend(timeout=120)
-        return info is not None and info.get("platform") != "cpu"
-
-    live_tpu = probe_live_tpu()
-    if args.scale == "auto":
-        scale_key = "tpu" if live_tpu else "cpu"
-    elif args.scale == "tpu-xl" and not live_tpu:
-        print("tpu-xl scale needs a live TPU; falling back to cpu scale",
-              file=sys.stderr)
-        scale_key = "cpu"
-    else:
-        scale_key = args.scale
-    base_env = dict(os.environ) if live_tpu else cpu_mesh_env(8)
-
     rows = []
     for dtype in args.dtypes:
-        peak = bench.PLAUSIBLE_PEAK_TFLOPS[dtype]
         seen_blocks = set()
         for block in args.blocks:
-            env = dict(base_env)
+            env = dict(os.environ)
             env["KEYSTONE_BENCH_BLOCK"] = str(block)
             # KEYSTONE_PROFILE_DIR=... captures a jax profiler trace of
             # every sweep config: the worker's timed loop runs under
@@ -83,36 +90,27 @@ def main() -> None:
                 env["KEYSTONE_PROFILE_DIR"] = os.path.join(
                     env["KEYSTONE_PROFILE_DIR"], f"mfu_b{block}_{dtype}"
                 )
-            # bench._run_worker tails worker stderr on failure — the
-            # diagnostics contract the round-1 gate failure taught us.
-            r = bench._run_worker(env, scale_key, dtype, args.timeout)
+            r = run_bench(env, args.scale, dtype, args.timeout)
             if r is None or r.get("value") is None:
                 print(json.dumps(
                     {"block": block, "dtype": dtype, "error": "run failed"}
                 ))
-                # A mid-sweep TPU death would otherwise cost one full
-                # timeout per remaining config (tpu AND tpu-xl scales) —
-                # re-probe and degrade.
-                if scale_key != "cpu" and not probe_live_tpu():
-                    print("TPU died mid-sweep; falling back to the CPU "
-                          "scale for the rest", file=sys.stderr)
-                    scale_key = "cpu"
-                    base_env = cpu_mesh_env(8)
                 continue
-            actual_block = r["detail"]["block"]  # divisor-clamped by worker
+            actual_block = r["detail"]["block"]  # divisor-clamped by bench
             if actual_block in seen_blocks:
                 continue
             seen_blocks.add(actual_block)
-            mfu = r["value"] / peak
+            mfu = r["value"] / r["detail"]["peak_tflops"]
             line = {
                 "block": actual_block,
                 "dtype": dtype,
                 "backend": r.get("backend"),
+                "device": r.get("device"),
                 "tflops_per_chip": r["value"],
                 "mfu_vs_plausible_peak": round(mfu, 4),
                 "seconds_per_solve": r["detail"]["seconds_per_solve"],
                 # Accuracy rides with speed (the f32h-vs-f32 decision
-                # needs both), matching the checkride sweep rows.
+                # needs both).
                 "relative_residual": r["detail"].get("relative_residual"),
             }
             rows.append(line)

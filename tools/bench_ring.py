@@ -14,7 +14,7 @@ on whatever backend is live:
   at identical shapes; the ring's comm advantage needs a real multi-chip
   mesh, which this environment does not expose — recorded as such.
 
-Prints ONE JSON line (checkride `ring_vs_dp` step).
+Prints ONE JSON line.
 """
 
 from __future__ import annotations
@@ -106,9 +106,9 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=2)
     args = ap.parse_args()
 
-    from keystone_tpu.utils.platform import ensure_live_backend
+    from keystone_tpu.utils.platform import device_info
 
-    backend = ensure_live_backend()
+    backend = device_info()["platform"]
     import jax
 
     # Validate up front, naming the offending flag — a non-divisible d

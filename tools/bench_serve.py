@@ -1048,9 +1048,9 @@ def main() -> None:
                     "bench's pipelined dispatch")
     args = ap.parse_args()
 
-    from keystone_tpu.utils.platform import ensure_live_backend
+    from keystone_tpu.utils.platform import device_info
 
-    backend = ensure_live_backend()
+    backend = device_info()["platform"]
     import jax
 
     from keystone_tpu.config import config

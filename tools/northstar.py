@@ -4,15 +4,16 @@ measured single-chip rates.
 BASELINE.md's authoritative target is "ImageNet FV+BlockLS end-to-end
 <= 10 min on TPU v5e-64, >= 10x the published 16-node EC2 baseline". No
 64-chip slice exists in this environment, so this tool does the honest
-next-best thing: a stage-by-stage bottleneck model whose inputs are the
-checkride's MEASURED single-chip numbers (TPU_REPORT.json) wherever they
-exist, with every remaining constant printed as a labelled assumption.
-Stages with no silicon measurement are reported as REQUIRED rates (what
-the hosts/chips must sustain for the 10-min budget), not as claims.
+next-best thing: a stage-by-stage bottleneck model whose inputs are
+MEASURED single-chip numbers (per-step chip rows in TPU_REPORT.json)
+wherever they exist, with every remaining constant printed as a labelled
+assumption. Stages with no chip measurement say "not measured" and are
+reported as REQUIRED rates (what the hosts/chips must sustain for the
+10-min budget), not as claims. PR 21 deleted TPU_REPORT.json with the
+harness that wrote it, so until a benchmark writes chip rows again every
+chip stage reads "not measured".
 
-This is a PROJECTION, not a measurement — the output says so. It
-self-upgrades: re-run after the sentinel captures more TPU steps and the
-"assumed" rows flip to "measured(tpu)".
+This is a PROJECTION, not a measurement — the output says so.
 
 Workload constants follow the reference pipeline (SURVEY.md §2.11
 ImageNetSiftLcsFV [unverified]): N=1.28M train images, two descriptor
@@ -94,7 +95,7 @@ def main() -> None:
         N_IMAGES, D_FEATURES, K_CLASSES, SOLVER_BLOCK, SOLVER_EPOCHS
     )
     # Prefer the AT-SHAPE measurement (bench_imagenet: d=65536, k=1000,
-    # block=8192 on silicon) — its rate needs no transfer assumption. The
+    # block=8192 on the chip) — its rate needs no transfer assumption. The
     # k=16 headline rows are the fallback, labelled as the rescale they are.
     shaped = _tpu(steps, "bench_imagenet")
     b = shaped or _tpu(steps, "bench_bf16") or _tpu(steps, "bench_f32")
@@ -121,7 +122,7 @@ def main() -> None:
             {
                 "stage": "BWLS solve",
                 "minutes": None,
-                "basis": "awaiting silicon (run make tpu-checkride)",
+                "basis": "not measured (no chip row for the solver)",
             }
         )
 
@@ -154,7 +155,7 @@ def main() -> None:
             {
                 "stage": "FV encode",
                 "minutes": None,
-                "basis": "awaiting silicon (pallas_fv step not yet on tpu)",
+                "basis": "not measured (no chip row for the FV kernel)",
             }
         )
 
@@ -180,7 +181,7 @@ def main() -> None:
     spent = sum(r["minutes"] or 0 for r in rows) * 60
     remaining = max(budget_s - spent, 0.0)
     req = N_IMAGES / remaining if remaining > 0 else float("inf")
-    DECODE_PER_CORE = 273.0  # img/s/core, native pool 512->256px (NOTES_r3 §7)
+    DECODE_PER_CORE = 273.0  # img/s/core, native pool 512->256px (round-3 host measurement)
     basis = (
         f"REQUIREMENT: fleet must sustain {req:,.0f} img/s aggregate in "
         "the remaining budget"
@@ -230,9 +231,9 @@ def main() -> None:
         "chip_stages_minutes": chip_minutes,
         "stages": rows,
     }
-    # Measured END-TO-END anchor (VERDICT r3 missing #6): the pipeline_rate
-    # checkride step runs the whole featurize→FV→solve program on one chip
-    # at full per-image geometry. Its img/s cross-checks the sum-of-stage
+    # Measured END-TO-END anchor: a pipeline_rate row is the whole
+    # featurize→FV→solve program on one chip at full per-image geometry.
+    # Its img/s cross-checks the sum-of-stage
     # model above — if the anchor disagrees with the stage sum, trust the
     # anchor.
     pr = _tpu(steps, "pipeline_rate")

@@ -312,6 +312,12 @@ def main(argv=None) -> int:
                     help="run the live end-to-end smoke and exit 0/1")
     args = ap.parse_args(argv)
 
+    # Warmup compiles rungs x replicas before the first request; a restart
+    # against the same artifact loads them from the compile cache.
+    from keystone_tpu.utils.platform import setup_compile_cache
+
+    setup_compile_cache()
+
     if args.smoke:
         result = run_smoke()
         print(json.dumps(result))
