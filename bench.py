@@ -173,16 +173,12 @@ def measure(scale_key: str, dtype: str, device: dict) -> dict:
     assert resid < 0.2, f"BCD did not make progress (resid={resid})"
 
     # Time enough repetitions to amortize dispatch noise (>= 2s or 5 runs).
-    # KEYSTONE_PROFILE_DIR additionally captures an XLA trace of the loop.
-    from keystone_tpu.utils.metrics import maybe_trace
-
     reps, total = 0, 0.0
-    with maybe_trace(f"bcd_{scale_key}_{dtype}"):
-        while total < 2.0 and reps < 5:
-            t0 = time.perf_counter()
-            run()
-            total += time.perf_counter() - t0
-            reps += 1
+    while total < 2.0 and reps < 5:
+        t0 = time.perf_counter()
+        run()
+        total += time.perf_counter() - t0
+        reps += 1
     dt = total / reps
 
     from keystone_tpu.utils.metrics import environment_fingerprint, peak_hbm_bytes

@@ -625,52 +625,62 @@ def _solve_fused(
     """The scan-fused solve body: stacked blocks → (optional) one batched
     factor program → one epochs program (or one per epoch when
     checkpointing). Returns the same (W blocks, ranges) as the legacy loop."""
+    from keystone_tpu.utils.metrics import active_tracer, span_of
+
+    # The three spans time the host: tracing and dispatching each phase.
+    # The device runs a phase after its span has closed, so in a profiler
+    # trace each names the gap in front of its program.
+    tracer = active_tracer()  # resolved once per solve
     precision = _precision()
     nb = len(blocks)
-    a3 = _stack_blocks_fn(mesh, axis, nb)(A.data)
+    with span_of(tracer, "solver.stack", "solver", blocks=nb):
+        a3 = _stack_blocks_fn(mesh, axis, nb)(A.data)
     if cache_grams:
         # Chunked like _factor_blocks (shared _factor_chunk policy): bounds
         # the factor transient to chunk·b² buffers instead of nb·b².
         chunk = _factor_chunk(blocks[0][1] - blocks[0][0])
-        factor = _fused_factor_fn(
-            mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
-        )
-        if chunk >= nb:
-            invs = factor(a3, lam_arr, w_rows)
-        else:
-            parts = []
-            for c0 in range(0, nb, chunk):
-                part = factor(a3[c0 : c0 + chunk], lam_arr, w_rows)
-                if throttle:
-                    # An unserialized burst of independent collective
-                    # programs deadlocks the CPU in-process rendezvous
-                    # (same guard as _factor_blocks).
-                    part.block_until_ready()
-                parts.append(part)
-            invs = jnp.concatenate(parts, axis=0)
+        with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk):
+            factor = _fused_factor_fn(
+                mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
+            )
+            if chunk >= nb:
+                invs = factor(a3, lam_arr, w_rows)
+            else:
+                parts = []
+                for c0 in range(0, nb, chunk):
+                    part = factor(a3[c0 : c0 + chunk], lam_arr, w_rows)
+                    if throttle:
+                        # An unserialized burst of independent collective
+                        # programs deadlocks the CPU in-process rendezvous
+                        # (same guard as _factor_blocks).
+                        part.block_until_ready()
+                    parts.append(part)
+                invs = jnp.concatenate(parts, axis=0)
     else:
         # Dummy scan operand: the uncached body re-derives each block's
         # inverse in-place; scan only needs a leading-nb structure to carry.
         invs = jnp.zeros((nb, 1, 1), dtype=R.dtype)
-    W3 = jnp.stack(W)
-    if checkpoint_dir is None:
-        step = _fused_epochs_fn(
-            mesh, axis, precision, weighted, num_iters - start_epoch,
-            cache_grams, fold_blocks(mesh.shape[axis]),
-        )
-        R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
-    else:
-        step = _fused_epochs_fn(
-            mesh, axis, precision, weighted, 1, cache_grams,
-            fold_blocks(mesh.shape[axis]),
-        )
-        for epoch in range(start_epoch, num_iters):
-            R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
-            _save_epoch(
-                checkpoint_dir, epoch + 1,
-                [W3[i] for i in range(nb)], R, fingerprint,
+    with span_of(tracer, "solver.epochs", "solver", blocks=nb,
+                 epochs=num_iters - start_epoch):
+        W3 = jnp.stack(W)
+        if checkpoint_dir is None:
+            step = _fused_epochs_fn(
+                mesh, axis, precision, weighted, num_iters - start_epoch,
+                cache_grams, fold_blocks(mesh.shape[axis]),
             )
-        wait_for_checkpoints(checkpoint_dir)
+            R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
+        else:
+            step = _fused_epochs_fn(
+                mesh, axis, precision, weighted, 1, cache_grams,
+                fold_blocks(mesh.shape[axis]),
+            )
+            for epoch in range(start_epoch, num_iters):
+                R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
+                _save_epoch(
+                    checkpoint_dir, epoch + 1,
+                    [W3[i] for i in range(nb)], R, fingerprint,
+                )
+            wait_for_checkpoints(checkpoint_dir)
     return [W3[i] for i in range(nb)], blocks
 
 
@@ -1119,7 +1129,7 @@ def block_coordinate_descent_streamed(
         t0 = tracer.now()
         out = _transfer(block)
         tracer.record(
-            "bcd.h2d", "solver", t0,
+            "solver.h2d", "solver", t0,
             shape=[int(block.shape[0]), int(block.shape[1])],
         )
         return out
@@ -1264,7 +1274,7 @@ def block_coordinate_descent_streamed(
                         # Dispatch time unless throttled (the block above
                         # makes the CPU path synchronous anyway).
                         tracer.record(
-                            "bcd.block_update", "solver", t0, epoch=epoch,
+                            "solver.block_update", "solver", t0, epoch=epoch,
                             block=i, cached_inverse=was_cached,
                             async_dispatch=not throttle,
                         )

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from keystone_tpu.config import config
 from keystone_tpu.nodes.learning.kmeans import _fit_kmeans, _sq_dists
+from keystone_tpu.utils.metrics import active_tracer, span_of, upload_nbytes
 from keystone_tpu.workflow import Estimator, Transformer
 
 # HIGHEST precision throughout: ||(x - μ)/σ||² is expanded into gemm-shaped
@@ -109,8 +110,13 @@ class GaussianMixtureModelEstimator(Estimator):
         self.seed = seed
 
     def fit(self, data) -> GaussianMixtureModel:
-        X = jnp.asarray(data, dtype=config.default_dtype)
-        w, m, v = _fit_gmm(
-            X, jax.random.PRNGKey(self.seed), self.k, self.max_iters, self.min_var
-        )
-        return GaussianMixtureModel(w, m, v)
+        # The k-means that seeds the EM runs inside _fit_gmm's one jitted
+        # program, so it has no span of its own here.
+        with span_of(active_tracer(), "gmm.fit", "featurizer",
+                     bytes=upload_nbytes(data)):
+            X = jnp.asarray(data, dtype=config.default_dtype)
+            w, m, v = _fit_gmm(
+                X, jax.random.PRNGKey(self.seed), self.k, self.max_iters,
+                self.min_var,
+            )
+            return GaussianMixtureModel(w, m, v)

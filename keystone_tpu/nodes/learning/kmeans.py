@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from keystone_tpu.config import config
+from keystone_tpu.utils.metrics import active_tracer, span_of, upload_nbytes
 from keystone_tpu.workflow import Estimator, Transformer
 
 
@@ -82,8 +83,10 @@ class KMeansPlusPlusEstimator(Estimator):
         self.seed = seed
 
     def fit(self, data) -> KMeansModel:
-        X = jnp.asarray(data, dtype=config.default_dtype)
-        centers = _fit_kmeans(
-            X, jax.random.PRNGKey(self.seed), self.k, self.max_iters
-        )
-        return KMeansModel(centers)
+        with span_of(active_tracer(), "kmeans.fit", "featurizer",
+                     bytes=upload_nbytes(data)):
+            X = jnp.asarray(data, dtype=config.default_dtype)
+            centers = _fit_kmeans(
+                X, jax.random.PRNGKey(self.seed), self.k, self.max_iters
+            )
+            return KMeansModel(centers)

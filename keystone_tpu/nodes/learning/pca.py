@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from keystone_tpu.linalg import RowMatrix, tsqr_r
+from keystone_tpu.utils.metrics import active_tracer, span_of, upload_nbytes
 from keystone_tpu.workflow import Estimator, Transformer
 
 
@@ -72,11 +73,15 @@ class PCAEstimator(Estimator):
         self.center = center
 
     def fit(self, data) -> PCATransformer:
-        X = jnp.asarray(data)
-        mean = X.mean(axis=0) if self.center else None
-        Xc = X - mean if self.center else X
-        _u, _s, vt = jnp.linalg.svd(Xc, full_matrices=False)
-        return PCATransformer(vt[: self.dims].T, mean)
+        # Upload of the sample and dispatch of the SVD: the device runs it
+        # after the span has closed.
+        with span_of(active_tracer(), "pca.fit", "featurizer",
+                     bytes=upload_nbytes(data)):
+            X = jnp.asarray(data)
+            mean = X.mean(axis=0) if self.center else None
+            Xc = X - mean if self.center else X
+            _u, _s, vt = jnp.linalg.svd(Xc, full_matrices=False)
+            return PCATransformer(vt[: self.dims].T, mean)
 
 
 class DistributedPCAEstimator(Estimator):

@@ -40,6 +40,7 @@ from keystone_tpu.nodes.images.external.fisher_vector import (
 from keystone_tpu.nodes.images.lcs import LCSExtractor
 from keystone_tpu.nodes.learning import BlockWeightedLeastSquaresEstimator
 from keystone_tpu.nodes.util import ClassLabelIndicators, TopKClassifier
+from keystone_tpu.utils.metrics import active_tracer, span_of
 from keystone_tpu.workflow import Pipeline
 
 
@@ -360,16 +361,23 @@ def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
     vectors) and the fitted pipeline (image → class scores). The one
     construction shared by ``run`` (the CLI) and ``chip_smoke.py``;
     ``conf`` must already be through ``resolve_scale``."""
-    featurizer = build_featurizer(conf, train.data)
-    targets = ClassLabelIndicators(num_classes)(train.labels)
-    solver = BlockWeightedLeastSquaresEstimator(
-        block_size=conf.block_size,
-        num_iters=conf.num_iters,
-        lam=conf.lam,
-        mixture_weight=conf.mixture_weight,
-        checkpoint_dir=conf.checkpoint_dir,
-    )
-    return featurizer, featurizer.and_then(solver, train.data, targets).fit()
+    # The root span of one whole fit: both branches' eager fits
+    # (build_featurizer) and the graph's, so every span below shares its
+    # root_id. It closes when the solver's programs are dispatched, not
+    # when the device has run them.
+    with span_of(active_tracer(), "fit", "pipeline",
+                 pipeline="ImageNetSiftLcsFV", rows=int(len(train.data))):
+        featurizer = build_featurizer(conf, train.data)
+        targets = ClassLabelIndicators(num_classes)(train.labels)
+        solver = BlockWeightedLeastSquaresEstimator(
+            block_size=conf.block_size,
+            num_iters=conf.num_iters,
+            lam=conf.lam,
+            mixture_weight=conf.mixture_weight,
+            checkpoint_dir=conf.checkpoint_dir,
+        )
+        fitted = featurizer.and_then(solver, train.data, targets).fit()
+    return featurizer, fitted
 
 
 def top_k_pipeline(conf: ImageNetSiftLcsFVConfig, scored: Pipeline) -> Pipeline:

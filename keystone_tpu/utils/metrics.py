@@ -1,17 +1,20 @@
 """Observability: tracing, latency histograms, the unified metrics
-registry, stage timers, and XLA cost introspection.
+registry, and XLA cost introspection.
 
 Ref: the reference's `Logging` trait with per-stage wall times in pipeline
 mains + Spark metrics (SURVEY.md §5 metrics row) [unverified]. KeystoneML
 attributed per-stage wall time to every pipeline node to drive its
 optimizer; the analog here is three layers:
 
-- ``Tracer`` — nested spans (name, start, duration, thread, attrs) in a
-  bounded ring buffer, exported as Chrome-trace JSON viewable in Perfetto
-  next to ``jax.profiler`` captures from ``maybe_trace``. Gated on
-  ``KEYSTONE_TRACE`` and resolved ONCE per stream/solve/service via
-  ``active_tracer()`` (the ``active_plan()`` discipline), so the disabled
-  tracer costs a None check, never a per-record context manager.
+- ``Tracer`` — nested spans (name, start, duration, thread, attrs, and
+  ``id`` / ``parent_id`` / ``root_id``) in a bounded ring buffer, exported
+  as Chrome-trace JSON; ``span()`` also mirrors each span as a
+  ``jax.profiler.TraceAnnotation`` named ``ks:<name>``, so a profiler
+  session holds the program's spans on the device trace's own clock.
+  Armed by ``KEYSTONE_TRACE`` or by a live profiler session and resolved
+  ONCE per stream/solve/service via ``active_tracer()`` (the
+  ``active_plan()`` discipline), so the disabled tracer costs a None
+  check, never a per-record context manager.
 - ``LatencyHistogram`` / ``Gauge`` — HdrHistogram-style fixed log buckets
   (p50/p95/p99 within one bucket's ~4% quantization) and point-in-time
   gauges with a high-water mark, both thread-safe.
@@ -27,6 +30,7 @@ Plus the pre-existing FLOP/byte counts straight from the compiled HLO
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import logging
 import math
@@ -35,25 +39,12 @@ import re
 import threading
 import time
 from collections import OrderedDict, deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
 logger = logging.getLogger("keystone_tpu")
-
-
-@contextmanager
-def stage_timer(name: str, sink: Dict[str, float] | None = None):
-    """Logs (and optionally records) the wall time of a pipeline stage."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        logger.info("stage=%s seconds=%.4f", name, dt)
-        if sink is not None:
-            sink[name] = dt
 
 
 def compiled_cost(compiled) -> Dict[str, Any]:
@@ -75,23 +66,6 @@ def cost_analysis(fn: Callable, *args) -> Dict[str, Any]:
     return compiled_cost(jax.jit(fn).lower(*args).compile())
 
 
-@contextmanager
-def maybe_trace(tag: str):
-    """Capture a jax profiler trace when KEYSTONE_PROFILE_DIR is set — the
-    tensorboard-consumable artifact for MXU-utilization work on hardware.
-    No-op (zero overhead) when the knob is absent."""
-    import os
-
-    out = os.environ.get("KEYSTONE_PROFILE_DIR")
-    if not out:
-        yield
-        return
-    path = os.path.join(out, tag)
-    with jax.profiler.trace(path):
-        yield
-    logger.info("profiler trace written to %s", path)
-
-
 # ---------------------------------------------------------------------------
 # Span tracing
 # ---------------------------------------------------------------------------
@@ -102,9 +76,16 @@ class Tracer:
     start, duration, thread, attrs) spans, exported as Chrome-trace JSON.
 
     Spans nest two ways: timestamps on one thread track contain each other
-    (which is all Perfetto needs to draw the flame), and ``span()``
-    additionally records the per-thread parent name so tests and the
-    report CLI can assert nesting without reconstructing it from time.
+    (which is all Perfetto needs to draw the flame), and every record
+    carries ``id``, ``parent_id`` (the span open around it on its thread,
+    None for a root) and ``root_id`` (its root's ``id``: what ``req_id`` is
+    to a request, all spans of one fit share it), so two spans of one name
+    are told apart. ``span()`` also keeps the parent's *name* in
+    ``args["parent"]``, and mirrors itself as a
+    ``jax.profiler.TraceAnnotation("ks:" + name)`` with its scalar attrs:
+    while a profiler session is live the span lies on the host plane of
+    the same ``.xplane.pb`` as the device's lines. Never open one inside a
+    function that ``jit`` traces: it would time the trace, once.
     ``record()`` takes externally-captured endpoints — the shape the hot
     paths use (one ``now()`` before, one ``record()`` after, no generator
     frame in the timed region) and the shape cross-thread spans need
@@ -128,13 +109,20 @@ class Tracer:
     #: How many tail-sampled requests keep their full span trees.
     RETAIN_CAPACITY = 64
 
+    #: The prefix of a span's mirror in a profiler trace.
+    ANNOTATION_PREFIX = "ks:"
+
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
         self.epoch_ns = time.perf_counter_ns()
+        # time.time() endpoints (jax.monitoring's time spans) onto the
+        # ring's clock: one offset, taken here.
+        self.wall_offset_ns = time.time_ns() - self.epoch_ns
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=self.capacity)
         self._retained: "OrderedDict[int, List[dict]]" = OrderedDict()
         self._tls = threading.local()
+        self._ids = itertools.count(1)
         self.dropped = 0  # spans evicted by the ring bound
 
     @staticmethod
@@ -142,18 +130,8 @@ class Tracer:
         """Monotonic timestamp (ns) on the tracer's clock."""
         return time.perf_counter_ns()
 
-    def record(
-        self,
-        name: str,
-        cat: str,
-        start_ns: int,
-        end_ns: Optional[int] = None,
-        **attrs,
-    ) -> None:
-        """Record one completed span from explicit endpoints (``end_ns``
-        None = now). ``attrs`` must be JSON-representable."""
-        if end_ns is None:
-            end_ns = time.perf_counter_ns()
+    def _append(self, name, cat, start_ns, end_ns, sid, parent_id, root_id,
+                attrs) -> None:
         t = threading.current_thread()
         with self._lock:
             if len(self._spans) == self.capacity:
@@ -166,9 +144,34 @@ class Tracer:
                     "dur_ns": max(0, end_ns - start_ns),
                     "tid": t.ident,
                     "thread": t.name,
+                    "id": sid,
+                    "parent_id": parent_id,
+                    "root_id": root_id,
                     "args": attrs,
                 }
             )
+
+    def record(
+        self,
+        name: str,
+        cat: str,
+        start_ns: int,
+        end_ns: Optional[int] = None,
+        **attrs,
+    ) -> None:
+        """Record one completed span from explicit endpoints (``end_ns``
+        None = now). ``attrs`` must be JSON-representable. Its parent is
+        the innermost ``span()`` open on this thread that began no later
+        than it did. Ring only: nothing is mirrored to the profiler."""
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        sid = next(self._ids)
+        parent_id, root_id = None, sid
+        for _name, pid, rid, began in reversed(getattr(self._tls, "stack", ())):
+            if began <= start_ns:
+                parent_id, root_id = pid, rid
+                break
+        self._append(name, cat, start_ns, end_ns, sid, parent_id, root_id, attrs)
 
     def instant(self, name: str, cat: str = "app", **attrs) -> None:
         """A zero-duration marker (cache hits, rejections)."""
@@ -178,21 +181,31 @@ class Tracer:
     @contextmanager
     def span(self, name: str, cat: str = "app", **attrs):
         """Context-managed span; yields the attrs dict so the body can add
-        keys it only knows afterwards (e.g. an output shape). Tracks the
-        per-thread span stack and stamps the parent name."""
+        keys it only knows afterwards (e.g. an output shape: such keys
+        reach the ring, not the profiler's mirror). Tracks the per-thread
+        span stack and stamps the parent."""
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
+        mirror = jax.profiler.TraceAnnotation(
+            self.ANNOTATION_PREFIX + name,
+            **{k: v for k, v in attrs.items() if isinstance(v, (int, float, str))},
+        )
+        sid = next(self._ids)
+        parent_id, root_id = None, sid
         if stack:
-            attrs.setdefault("parent", stack[-1])
-        stack.append(name)
+            parent_name, parent_id, root_id, _began = stack[-1]
+            attrs.setdefault("parent", parent_name)
+        mirror.__enter__()
         t0 = time.perf_counter_ns()
+        stack.append((name, sid, root_id, t0))
         try:
             yield attrs
         finally:
             end = time.perf_counter_ns()
             stack.pop()
-            self.record(name, cat, t0, end, **attrs)
+            mirror.__exit__(None, None, None)
+            self._append(name, cat, t0, end, sid, parent_id, root_id, attrs)
 
     def spans(self) -> List[dict]:
         """Snapshot of the ring's current spans (oldest first)."""
@@ -290,7 +303,7 @@ class Tracer:
     def export(self, path: Optional[str] = None) -> dict:
         """The ring as a Chrome-trace document (``{"traceEvents": [...]}``,
         timestamps/durations in microseconds) — loadable by Perfetto /
-        chrome://tracing alongside ``maybe_trace``'s jax profiler capture.
+        chrome://tracing.
         Tail-sampled span trees ride along under a ``tailSampled`` key
         (req id -> events) so ``tools/trace_report.py --request`` can
         reconstruct a slow request even after the ring churned past it;
@@ -331,29 +344,58 @@ class Tracer:
 
 _tracer_lock = threading.Lock()
 _tracer: Optional[Tracer] = None
-_tracer_key: Optional[tuple] = None
+_tracer_key: Optional[int] = None
+_compile_spans: Optional["CompileSpanListener"] = None  # one per process
 
 
 def active_tracer() -> Optional[Tracer]:
     """The process-wide Tracer, or None when tracing is disabled.
 
-    Built from ``config.trace`` / ``config.trace_buffer`` (env
-    ``KEYSTONE_TRACE`` / ``KEYSTONE_TRACE_BUFFER``) and rebuilt when those
-    change, so tests flip the knob without a reload. Call sites grab the
-    tracer ONCE per stream/solve/service/execution — never per record —
-    so the disabled tracer (None) adds nothing to hot loops (the
-    ``active_plan()`` discipline)."""
-    global _tracer, _tracer_key
+    Armed by ``config.trace`` (env ``KEYSTONE_TRACE``) or by a live
+    ``jax.profiler`` session: whoever profiles the device gets the
+    program's spans in the same trace, with no second switch. Sized by
+    ``config.trace_buffer`` and rebuilt when that changes. Call sites grab
+    the tracer ONCE per stream/solve/service/execution — never per record
+    — so the disabled tracer (None) adds nothing to hot loops (the
+    ``active_plan()`` discipline). The ring outlives a profiler session:
+    ``recorded_tracer()`` reads it afterwards."""
+    global _tracer, _tracer_key, _compile_spans
     from keystone_tpu.config import config
 
-    if not config.trace:
+    if not config.trace and not jax.profiler.TraceAnnotation.is_enabled():
         return None
-    key = (True, config.trace_buffer)
+    key = config.trace_buffer
     with _tracer_lock:
         if key != _tracer_key or _tracer is None:
             _tracer = Tracer(config.trace_buffer)
             _tracer_key = key
+        if _compile_spans is None:
+            _compile_spans = CompileSpanListener()
         return _tracer
+
+
+def recorded_tracer() -> Optional[Tracer]:
+    """The tracer ``active_tracer()`` last built, armed or not: how a
+    reader gets at what was recorded under a profiler session that has
+    since stopped. Never arms tracing."""
+    with _tracer_lock:
+        return _tracer
+
+
+def span_of(tracer: Optional[Tracer], name: str, cat: str = "app", **attrs):
+    """``tracer.span(...)``, or a null context (yielding None) where the
+    tracer is None: one ``with`` at a cold site, whatever the switch."""
+    if tracer is None:
+        return nullcontext()
+    return tracer.span(name, cat, **attrs)
+
+
+def upload_nbytes(value) -> int:
+    """The bytes a ``jnp.asarray(value)`` sends to the device: the size of
+    a host array, 0 for what is already a ``jax.Array``."""
+    if isinstance(value, jax.Array):
+        return 0
+    return int(getattr(value, "nbytes", 0))
 
 
 def reset_tracer() -> None:
@@ -1682,6 +1724,53 @@ class CompileEventCounter:
             self.count += 1
         elif name == self.HIT_EVENT:
             self.hits += 1
+
+
+class CompileSpanListener:
+    """Puts what ``jax.monitoring`` times of every program a process
+    builds on the armed tracer's ring: ``jax.trace`` (a re-trace to a
+    jaxpr), ``jax.lower`` (jaxpr to MLIR) and ``jax.compile`` (the backend
+    compile *or* the load from the persistent cache), each with the
+    program's ``fun_name``, as children of whatever span is open on their
+    thread: that is how a re-trace is put down to a node. ``jax.compile``
+    carries ``cache_hit`` where the compilation cache's own events on that
+    thread since the last request tell it. Registration is global and
+    permanent, like ``CompileEventCounter``'s: ``active_tracer()`` makes
+    the one of the process, and with no tracer armed an event costs the
+    None check."""
+
+    NAMES = {
+        "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+        "/jax/core/compile/backend_compile_duration": "jax.compile",
+    }
+
+    def __init__(self):
+        self._tls = threading.local()
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_time_span_listener(self._on_time_span)
+
+    def _on_event(self, name, **kwargs):
+        if name == CompileEventCounter.EVENT:
+            self._tls.cache_hit = False
+        elif name == CompileEventCounter.HIT_EVENT:
+            self._tls.cache_hit = True
+
+    def _on_time_span(self, event, start, end, **kwargs):
+        name = self.NAMES.get(event)
+        if name is None:
+            return
+        attrs = {"fun_name": str(kwargs.get("fun_name", ""))}
+        if name == "jax.compile":
+            hit = getattr(self._tls, "cache_hit", None)
+            self._tls.cache_hit = None
+            if hit is not None:
+                attrs["cache_hit"] = hit
+        tracer = active_tracer()
+        if tracer is not None:
+            offset = tracer.wall_offset_ns
+            tracer.record(name, "jax", int(start * 1e9) - offset,
+                          int(end * 1e9) - offset, **attrs)
 
 
 class ServingCounters:

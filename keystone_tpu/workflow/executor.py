@@ -102,12 +102,11 @@ def _observed_execute(op, deps, tracer, profile, worker=None,
     if queue_wait_ns is not None:
         extra["queue_wait_ms"] = round(queue_wait_ns / 1e6, 4)
     if profile is None:
-        t0 = tracer.now()
-        out = op.execute(deps)
-        tracer.record(
-            "node:" + label, "executor", t0,
-            cache="miss", shape=_span_shape(out), **extra,
-        )
+        with tracer.span(
+            "node:" + label, "executor", cache="miss", **extra
+        ) as attrs:
+            out = op.execute(deps)
+            attrs["shape"] = _span_shape(out)
         return out
 
     import jax
@@ -173,6 +172,9 @@ def _observed_execute(op, deps, tracer, profile, worker=None,
         data_shards=value_data_shards(out),
     )
     if tracer is not None:
+        # The profiled walk keeps explicit endpoints (a forced profile
+        # re-times the warm run, and trace_report --fit must agree with the
+        # profile's rows): ring only, no mirror in a profiler trace.
         tracer.record(
             "node:" + label, "executor", t0, end,
             cache="miss", shape=_span_shape(out), profiled=True, **extra,

@@ -151,12 +151,7 @@ def test_bcd_checkpoint_resume(rng, tmp_path):
 
 
 def test_stage_timer_and_cost_analysis(rng):
-    from keystone_tpu.utils.metrics import achieved_tflops, cost_analysis, stage_timer
-
-    sink = {}
-    with stage_timer("featurize", sink):
-        pass
-    assert "featurize" in sink
+    from keystone_tpu.utils.metrics import achieved_tflops, cost_analysis
 
     X = jnp.asarray(rng.normal(size=(64, 64)).astype(np.float32))
     cost = cost_analysis(lambda a: a @ a, X)

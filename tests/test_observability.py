@@ -676,3 +676,227 @@ def test_trace_demo_full_coverage(tmp_path):
     assert any(k.startswith("serving/") for k in rows)
     # and tracing was left OFF for the rest of the suite
     assert active_tracer() is None
+
+
+# ---------------------------------------------------------------------------
+# Fit-plane spans: one switch, one clock, identity
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def session(tmp_path):
+    """A live ``jax.profiler`` session as the benchmark starts one (no
+    Python tracer); stops it and drops the cached tracer afterwards."""
+    import jax
+
+    from keystone_tpu.utils.metrics import reset_tracer
+
+    reset_tracer()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    live = []
+
+    def start():
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        live.append(True)
+        return str(tmp_path)
+
+    def stop():
+        if live:
+            live.pop()
+            jax.profiler.stop_trace()
+
+    try:
+        yield start, stop
+    finally:
+        stop()
+        reset_tracer()
+
+
+def _host_annotations(trace_dir, prefix="ks:"):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(prefix):
+                        found.setdefault(ev.name, []).append(dict(ev.stats))
+    return found
+
+
+def test_profiler_session_arms_the_tracer_and_the_ring_outlives_it(session):
+    from keystone_tpu.utils.metrics import recorded_tracer
+
+    start, stop = session
+    assert not config.trace and active_tracer() is None
+    trace_dir = start()
+    tr = active_tracer()
+    assert tr is not None and active_tracer() is tr
+    with tr.span("fisher.fetch", "featurizer", bytes=10, shape=[2, 5]):
+        pass
+    stop()
+    assert active_tracer() is None  # the session was the only switch
+    assert recorded_tracer() is tr  # ... and the ring is still readable
+    (span,) = tr.spans()
+    assert span["name"] == "fisher.fetch" and span["args"]["bytes"] == 10
+    # One clock: the span's mirror lies in the session's own trace, under
+    # the fixed prefix, with its scalar attrs.
+    mirrors = _host_annotations(trace_dir)
+    assert mirrors["ks:fisher.fetch"][0]["bytes"] == 10
+    assert "shape" not in mirrors["ks:fisher.fetch"][0]
+
+
+def test_span_identity_tells_equal_names_apart():
+    tr = Tracer(64)
+    with tr.span("fit", "t"):
+        with tr.span("node:apply", "t"):
+            with tr.span("leaf", "t"):
+                pass
+        with tr.span("node:apply", "t"):
+            pass
+    with tr.span("fit", "t"):
+        with tr.span("node:apply", "t"):
+            pass
+    spans = tr.spans()
+    assert len({s["id"] for s in spans}) == len(spans) == 6
+    first, second = [s for s in spans if s["name"] == "fit"]
+    assert first["parent_id"] is None and first["root_id"] == first["id"]
+    assert second["root_id"] == second["id"] != first["id"]
+    nodes = [s for s in spans if s["name"] == "node:apply"]
+    assert [n["parent_id"] for n in nodes] == [first["id"], first["id"], second["id"]]
+    assert [n["root_id"] for n in nodes] == [first["id"], first["id"], second["id"]]
+    (leaf,) = [s for s in spans if s["name"] == "leaf"]
+    assert leaf["parent_id"] == nodes[0]["id"] and leaf["root_id"] == first["id"]
+    assert leaf["args"]["parent"] == "node:apply"  # the name stays too
+
+
+def test_record_takes_the_span_open_around_it_as_parent():
+    tr = Tracer(16)
+    before = tr.now()
+    with tr.span("outer", "t"):
+        with tr.span("inner", "t"):
+            t0 = tr.now()
+            tr.record("inside", "t", t0)
+            # Began before either span opened: enclosed by neither.
+            tr.record("straddles", "t", before)
+    tr.instant("alone", "t")
+    by_name = {s["name"]: s for s in tr.spans()}
+    assert by_name["inside"]["parent_id"] == by_name["inner"]["id"]
+    assert by_name["inside"]["root_id"] == by_name["outer"]["id"]
+    assert by_name["straddles"]["parent_id"] is None
+    assert by_name["alone"]["parent_id"] is None
+    assert by_name["alone"]["root_id"] == by_name["alone"]["id"]
+
+
+def test_compile_listener_puts_a_fresh_jit_under_the_open_span(traced):
+    import jax
+    import jax.numpy as jnp
+
+    tr = traced(True)
+
+    def fresh_program_for_the_listener(x):
+        return jnp.tanh(x) * 3.0 + 1.0
+
+    with tr.span("node:fresh", "executor"):
+        jax.jit(fresh_program_for_the_listener)(jnp.ones((7,))).block_until_ready()
+    spans = tr.spans()
+    (node,) = [s for s in spans if s["name"] == "node:fresh"]
+    mine = [s for s in spans
+            if "fresh_program_for_the_listener" in s["args"].get("fun_name", "")]
+    assert {s["name"] for s in mine} == {"jax.trace", "jax.lower", "jax.compile"}
+    lo, hi = node["start_ns"], node["start_ns"] + node["dur_ns"]
+    slack = 5_000_000  # time.time() endpoints laid on the ring's clock
+    for s in mine:
+        assert s["parent_id"] == node["id"] and s["root_id"] == node["id"]
+        assert s["cat"] == "jax" and s["dur_ns"] > 0
+        assert lo - slack <= s["start_ns"] and s["start_ns"] + s["dur_ns"] <= hi + slack
+    # A second call builds nothing: no new record.
+    count = len(tr.spans())
+    with tr.span("node:fresh", "executor"):
+        pass
+    assert len(tr.spans()) == count + 1
+
+
+def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
+    from keystone_tpu.loaders.imagenet import ImageNetLoader
+    from keystone_tpu.pipelines.images import imagenet_sift_lcs_fv as imagenet
+
+    conf = imagenet.resolve_scale(imagenet.ImageNetSiftLcsFVConfig(
+        synthetic_n=48, synthetic_classes=4, pca_dims=8, gmm_k=4,
+        gmm_iters=2, descriptor_sample=1000, num_iters=2, block_size=32,
+    ))
+    train, _test = ImageNetLoader.synthetic(48, 4, size=32, seed=conf.seed)
+    start, stop = session
+    trace_dir = start()
+    imagenet.fit(conf, train, 4)
+    stop()
+    from keystone_tpu.utils.metrics import recorded_tracer
+
+    spans = recorded_tracer().spans()
+    roots = [s for s in spans if s["parent_id"] is None]
+    assert [r["name"] for r in roots] == ["fit"]
+    (root,) = roots
+    assert root["args"]["rows"] == 48
+    assert all(s["root_id"] == root["id"] for s in spans)
+    names = {s["name"] for s in spans}
+    table = {"fit", "pipeline.fit", "fisher.describe", "fisher.fetch",
+             "fisher.flatten", "fisher.sample", "fisher.project", "pca.fit",
+             "gmm.fit",
+             "solver.stack", "solver.factor", "solver.epochs",
+             "jax.trace", "jax.lower", "jax.compile"}
+    assert table <= names, table - names
+    assert any(n.startswith("node:") for n in names)
+    by_id = {s["id"]: s for s in spans}
+    for s in spans:  # every solver span lies under the solver's node
+        if s["name"].startswith("solver."):
+            assert by_id[s["parent_id"]]["name"].startswith("node:Block")
+    # fisher.fetch counts exactly the device arrays fetched: each
+    # branch's descriptors, then its projected sample.
+    described = [s for s in spans if s["name"] == "pipeline.apply"
+                 and by_id[s["parent_id"]]["name"] == "fisher.describe"]
+    expected = []
+    for d in described:
+        expected += [int(np.prod(d["args"]["shape"])) * 4, 1000 * 8 * 4]
+    fetched = [s["args"]["bytes"] for s in spans if s["name"] == "fisher.fetch"]
+    assert len(described) == 2 and fetched == expected
+    # The same spans are in the session's trace, on the profiler's clock.
+    mirrors = _host_annotations(trace_dir)
+    assert {"ks:" + n for n in table if not n.startswith("jax.")} <= set(mirrors)
+    assert [m["bytes"] for m in mirrors["ks:fisher.fetch"]] == expected
+
+
+def test_solver_programs_keep_the_names_the_benchmark_filters_on():
+    """``benchmark/metrics/{solver_roofline,factor_ms,featurize_ms}.json``
+    tell the solver's device time by the HLO module names ``jit_local`` and
+    ``jit__batched_spd_inv``: a rename needs their readers changed first."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg import RowMatrix, bcd
+
+    mesh, axis = RowMatrix.from_array(np.zeros((16, 4), np.float32)).mesh, config.data_axis
+    rows, nb, b, k = 16 * mesh.shape[axis], 2, 8, 3
+    f32 = jnp.float32
+    shape = jax.ShapeDtypeStruct
+    precision = bcd._precision()
+    fold = bcd.fold_blocks(mesh.shape[axis])
+    a3, lam, w_rows = shape((nb, rows, b), f32), shape((), f32), shape((rows,), f32)
+    lowered = {
+        "stack": bcd._stack_blocks_fn(mesh, axis, nb).lower(shape((rows, nb * b), f32)),
+        "factor": bcd._fused_factor_fn(mesh, axis, precision, True, fold).lower(
+            a3, lam, w_rows),
+        "epochs": bcd._fused_epochs_fn(mesh, axis, precision, True, 2, True, fold).lower(
+            a3, shape((nb, b, b), f32), shape((rows, k), f32), shape((nb, b, k), f32),
+            lam, w_rows),
+    }
+    for phase, low in lowered.items():
+        assert "module @jit_local " in low.as_text(), phase
+    inv = bcd._batched_ridge_inv_fn(mesh).lower(shape((nb, b, b), f32))
+    assert "module @jit__batched_spd_inv " in inv.as_text()

@@ -86,7 +86,6 @@ def stream_solve(args) -> None:
     from keystone_tpu.loaders.stream import PrefetchIterator
     from keystone_tpu.utils.metrics import (
         environment_fingerprint,
-        maybe_trace,
         peak_hbm_bytes,
     )
 
@@ -144,12 +143,9 @@ def stream_solve(args) -> None:
     run_once(0)  # warm both paths' compile caches
     run_once(depth)
     reps = max(1, args.reps)
-    # KEYSTONE_PROFILE_DIR=... captures a jax profiler trace of the timed
-    # reps (all three modes), no code edits needed.
-    with maybe_trace("bench_ingest_stream_solve"):
-        serial_s = min(run_once(0, serialize=True)[0] for _ in range(reps))
-        async_s = min(run_once(0)[0] for _ in range(reps))
-        timed = [run_once(depth) for _ in range(reps)]
+    serial_s = min(run_once(0, serialize=True)[0] for _ in range(reps))
+    async_s = min(run_once(0)[0] for _ in range(reps))
+    timed = [run_once(depth) for _ in range(reps)]
     overlap_s, pf = min(timed, key=lambda t: t[0])
 
     chunk_bytes = rows * (d + k) * 4
@@ -223,7 +219,7 @@ def main() -> None:
     from jax import lax
 
     from keystone_tpu.loaders.imagenet import ImageNetLoader
-    from keystone_tpu.utils.metrics import environment_fingerprint, maybe_trace
+    from keystone_tpu.utils.metrics import environment_fingerprint
 
     # The loader caps pool size at the core count (decode is CPU-bound;
     # NOTES_r2 §8's non-monotone sweep was oversubscription thrash on a
@@ -299,28 +295,26 @@ def main() -> None:
         result["featurize_images_per_sec"] = round(feat_rate, 1)
         result["decode_feeds_featurization"] = best_rate >= feat_rate
 
-        # 3. serial vs overlapped end-to-end (KEYSTONE_PROFILE_DIR=...
-        # captures a jax profiler trace of both passes)
-        with maybe_trace("bench_ingest_imagenet"):
-            t0 = time.perf_counter()
-            data = ImageNetLoader.load(
-                root, label_map, size=args.size, workers=16
+        # 3. serial vs overlapped end-to-end
+        t0 = time.perf_counter()
+        data = ImageNetLoader.load(
+            root, label_map, size=args.size, workers=16
+        )
+        for s in range(0, len(data.data), args.batch):
+            jax.block_until_ready(
+                featurize(jnp.asarray(data.data[s : s + args.batch]))
             )
-            for s in range(0, len(data.data), args.batch):
-                jax.block_until_ready(
-                    featurize(jnp.asarray(data.data[s : s + args.batch]))
-                )
-            serial = time.perf_counter() - t0
+        serial = time.perf_counter() - t0
 
-            t0 = time.perf_counter()
-            n = 0
-            for X, _y in ImageNetLoader.stream_batches(
-                root, label_map, batch_size=args.batch, size=args.size,
-                workers=16,
-            ):
-                jax.block_until_ready(featurize(jnp.asarray(X)))
-                n += len(X)
-            overlap = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        n = 0
+        for X, _y in ImageNetLoader.stream_batches(
+            root, label_map, batch_size=args.batch, size=args.size,
+            workers=16,
+        ):
+            jax.block_until_ready(featurize(jnp.asarray(X)))
+            n += len(X)
+        overlap = time.perf_counter() - t0
         assert n == args.images
         result["serial_seconds"] = round(serial, 2)
         result["overlapped_seconds"] = round(overlap, 2)

@@ -81,15 +81,6 @@ def main() -> None:
         for block in args.blocks:
             env = dict(os.environ)
             env["KEYSTONE_BENCH_BLOCK"] = str(block)
-            # KEYSTONE_PROFILE_DIR=... captures a jax profiler trace of
-            # every sweep config: the worker's timed loop runs under
-            # maybe_trace, and a per-config subdirectory keeps same-dtype
-            # configs (identical worker-side tags) from overwriting each
-            # other.
-            if env.get("KEYSTONE_PROFILE_DIR"):
-                env["KEYSTONE_PROFILE_DIR"] = os.path.join(
-                    env["KEYSTONE_PROFILE_DIR"], f"mfu_b{block}_{dtype}"
-                )
             r = run_bench(env, args.scale, dtype, args.timeout)
             if r is None or r.get("value") is None:
                 print(json.dumps(

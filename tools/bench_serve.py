@@ -1057,7 +1057,6 @@ def main() -> None:
     from keystone_tpu.utils.metrics import (
         CompileEventCounter,
         environment_fingerprint,
-        maybe_trace,
         metrics_registry,
     )
     from keystone_tpu.workflow.serving import (
@@ -1087,8 +1086,7 @@ def main() -> None:
     config.plan_resources = True
 
     if args.precision:
-        with maybe_trace("bench_serve_precision"):
-            result = run_precision_bench(args)
+        result = run_precision_bench(args)
         result["backend"] = backend
         result["host_cores"] = os.cpu_count()
         result["env"] = environment_fingerprint()
@@ -1099,8 +1097,7 @@ def main() -> None:
         sys.exit(0 if result["ok"] else 1)
 
     if args.daemon:
-        with maybe_trace("bench_serve_daemon"):
-            result = run_daemon_bench(args)
+        result = run_daemon_bench(args)
         result["backend"] = backend
         result["host_cores"] = os.cpu_count()
         result["env"] = environment_fingerprint()
@@ -1111,8 +1108,7 @@ def main() -> None:
         sys.exit(0 if result["ok"] else 1)
 
     if args.telemetry:
-        with maybe_trace("bench_serve_telemetry"):
-            result = run_telemetry_bench(args)
+        result = run_telemetry_bench(args)
         result["backend"] = backend
         result["host_cores"] = os.cpu_count()
         result["env"] = environment_fingerprint()
@@ -1123,8 +1119,7 @@ def main() -> None:
         sys.exit(0 if result["ok"] else 1)
 
     if args.devices > 0:
-        with maybe_trace("bench_serve_replicas"):
-            result = run_replica_bench(args)
+        result = run_replica_bench(args)
         result["backend"] = backend
         line = json.dumps(result)
         print(line)
@@ -1140,10 +1135,7 @@ def main() -> None:
             max_batch=args.max_batch,
         )
         cp.warmup((args.d,))
-        # KEYSTONE_PROFILE_DIR=... additionally captures a jax profiler
-        # trace of the overload run, no code edits needed.
-        with maybe_trace("bench_serve_overload"):
-            overload = run_overload(cp, args)
+        overload = run_overload(cp, args)
         result = {
             "metric": "serve_overload",
             "backend": backend,
@@ -1168,44 +1160,41 @@ def main() -> None:
         rng.normal(size=(int(n), args.d)).astype(np.float32) for n in sizes
     ]
 
-    # KEYSTONE_PROFILE_DIR=... captures a jax profiler trace of both
-    # serving phases alongside the timing, no code edits needed.
-    with maybe_trace("bench_serve"):
-        # -- naive: per-shape jit ---------------------------------------------
-        naive = build_chain(args.d, args.features, args.classes, args.seed)
-        # One warm call at the top size — the naive server has seen SOME
-        # traffic; every new row count in the trace still recompiles.
-        jax.block_until_ready(naive.batch_call(trace[0][: args.max_batch]))
-        ev0 = compile_events.count
-        naive_lats = []
-        t0 = time.perf_counter()
-        for x in trace:
-            t1 = time.perf_counter()
-            jax.block_until_ready(naive.batch_call(x))
-            naive_lats.append(time.perf_counter() - t1)
-        naive_wall = time.perf_counter() - t0
-        naive_compiles = compile_events.count - ev0
+    # -- naive: per-shape jit ---------------------------------------------
+    naive = build_chain(args.d, args.features, args.classes, args.seed)
+    # One warm call at the top size — the naive server has seen SOME
+    # traffic; every new row count in the trace still recompiles.
+    jax.block_until_ready(naive.batch_call(trace[0][: args.max_batch]))
+    ev0 = compile_events.count
+    naive_lats = []
+    t0 = time.perf_counter()
+    for x in trace:
+        t1 = time.perf_counter()
+        jax.block_until_ready(naive.batch_call(x))
+        naive_lats.append(time.perf_counter() - t1)
+    naive_wall = time.perf_counter() - t0
+    naive_compiles = compile_events.count - ev0
 
-        # -- bucketed + AOT warmup --------------------------------------------
-        # One registry reset covers the serving counters AND the
-        # request-latency histogram the bucketed phase is about to fill.
-        metrics_registry.reset()
-        cp = CompiledPipeline(
-            build_chain(args.d, args.features, args.classes, args.seed),
-            max_batch=args.max_batch,
-        )
-        ev0 = compile_events.count
-        cp.warmup((args.d,))
-        warmup_compiles = compile_events.count - ev0
-        ev0 = compile_events.count
-        bucketed_lats = []
-        t0 = time.perf_counter()
-        for x in trace:
-            t1 = time.perf_counter()
-            cp(x)  # host-out: the np result is already synchronized
-            bucketed_lats.append(time.perf_counter() - t1)
-        bucketed_wall = time.perf_counter() - t0
-        post_warmup_compiles = compile_events.count - ev0
+    # -- bucketed + AOT warmup --------------------------------------------
+    # One registry reset covers the serving counters AND the
+    # request-latency histogram the bucketed phase is about to fill.
+    metrics_registry.reset()
+    cp = CompiledPipeline(
+        build_chain(args.d, args.features, args.classes, args.seed),
+        max_batch=args.max_batch,
+    )
+    ev0 = compile_events.count
+    cp.warmup((args.d,))
+    warmup_compiles = compile_events.count - ev0
+    ev0 = compile_events.count
+    bucketed_lats = []
+    t0 = time.perf_counter()
+    for x in trace:
+        t1 = time.perf_counter()
+        cp(x)  # host-out: the np result is already synchronized
+        bucketed_lats.append(time.perf_counter() - t1)
+    bucketed_wall = time.perf_counter() - t0
+    post_warmup_compiles = compile_events.count - ev0
 
     rows = int(sizes.sum())
     naive_p99 = float(np.percentile(np.asarray(naive_lats) * 1e3, 99))
