@@ -846,9 +846,8 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     assert root["args"]["rows"] == 48
     assert all(s["root_id"] == root["id"] for s in spans)
     names = {s["name"] for s in spans}
-    table = {"fit", "pipeline.fit", "fisher.describe", "fisher.fetch",
-             "fisher.flatten", "fisher.sample", "fisher.project", "pca.fit",
-             "gmm.fit",
+    table = {"fit", "pipeline.fit", "fisher.describe", "fisher.sample",
+             "fisher.project", "pca.fit", "gmm.fit",
              "solver.stack", "solver.factor", "solver.epochs",
              "jax.trace", "jax.lower", "jax.compile"}
     assert table <= names, table - names
@@ -857,19 +856,26 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     for s in spans:  # every solver span lies under the solver's node
         if s["name"].startswith("solver."):
             assert by_id[s["parent_id"]]["name"].startswith("node:Block")
-    # fisher.fetch counts exactly the device arrays fetched: each
-    # branch's descriptors, then its projected sample.
+    # The descriptors never come to the host: nothing is fetched, nothing
+    # flattened there, and each branch's sample is gathered on the device
+    # from all of its descriptors.
+    assert not {"fisher.fetch", "fisher.flatten"} & names
     described = [s for s in spans if s["name"] == "pipeline.apply"
                  and by_id[s["parent_id"]]["name"] == "fisher.describe"]
     expected = []
     for d in described:
-        expected += [int(np.prod(d["args"]["shape"])) * 4, 1000 * 8 * 4]
-    fetched = [s["args"]["bytes"] for s in spans if s["name"] == "fisher.fetch"]
-    assert len(described) == 2 and fetched == expected
+        n, m, width = d["args"]["shape"]
+        expected.append({"rows_in": n * m, "rows_out": 1000, "on_device": 1,
+                         "bytes": 1000 * width * 4})
+    sampled = [s["args"] for s in spans if s["name"] == "fisher.sample"]
+    assert len(described) == len(sampled) == 2
+    assert [{k: a[k] for k in e} for a, e in zip(sampled, expected)] == expected
     # The same spans are in the session's trace, on the profiler's clock.
     mirrors = _host_annotations(trace_dir)
     assert {"ks:" + n for n in table if not n.startswith("jax.")} <= set(mirrors)
-    assert [m["bytes"] for m in mirrors["ks:fisher.fetch"]] == expected
+    assert not {"ks:fisher.fetch", "ks:fisher.flatten"} & set(mirrors)
+    assert [{k: int(m[k]) for k in e}
+            for m, e in zip(mirrors["ks:fisher.sample"], expected)] == expected
 
 
 def test_solver_programs_keep_the_names_the_benchmark_filters_on():
