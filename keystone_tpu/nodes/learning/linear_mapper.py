@@ -26,6 +26,8 @@ from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
 class LinearMapper(Transformer):
+    array_fields = ("W", "b")
+
     def __init__(self, W, b: Optional[jax.Array] = None):
         self.W = jnp.asarray(W)
         self.b = None if b is None else jnp.asarray(b)

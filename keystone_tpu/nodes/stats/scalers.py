@@ -12,6 +12,8 @@ from keystone_tpu.workflow import Estimator, Transformer
 
 
 class StandardScalerModel(Transformer):
+    array_fields = ("mean", "std")
+
     def __init__(self, mean, std=None):
         self.mean = jnp.asarray(mean)
         self.std = None if std is None else jnp.asarray(std)

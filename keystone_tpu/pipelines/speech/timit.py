@@ -9,6 +9,13 @@ BlockLeastSquaresEstimator → MaxClassifier (SURVEY.md §2.11) [unverified].
 This is the first real stress of the distributed-linalg layer at high
 feature dimension: the random-feature projection is one large MXU gemm and
 the solve streams feature blocks through the psum-reduced BCD loop.
+
+Upstream gathers ``numCosines`` cosine nodes of 4096 features each (50 by
+default: 204,800 features) and solves at block 4096. Here they are one
+projection of ``num_cosines * num_features`` columns drawn block by block
+(``CosineRandomFeatures.create(blocks=...)``), so the scaler and the
+projection are one program; the defaults stay what a laptop runs (one
+block of 4096).
 """
 
 from __future__ import annotations
@@ -19,10 +26,13 @@ from dataclasses import dataclass
 from typing import Optional
 
 from keystone_tpu.evaluation import MulticlassClassifierEvaluator
+from keystone_tpu.loaders.labeled_data import LabeledData
 from keystone_tpu.loaders.timit import TimitFeaturesDataLoader
 from keystone_tpu.nodes.learning import BlockLeastSquaresEstimator
 from keystone_tpu.nodes.stats import CosineRandomFeatures, StandardScaler
 from keystone_tpu.nodes.util import ClassLabelIndicators, MaxClassifier
+from keystone_tpu.utils.metrics import active_tracer, program_counters, span_of
+from keystone_tpu.workflow import Pipeline
 
 
 @dataclass
@@ -31,7 +41,8 @@ class TimitConfig:
     labels_path: Optional[str] = None
     test_features_path: Optional[str] = None
     test_labels_path: Optional[str] = None
-    num_features: int = 4096
+    num_features: int = 4096  # features a cosine block
+    num_cosines: int = 1  # cosine blocks (upstream's numCosines: 50)
     gamma: float = 0.055  # the RBF bandwidth scale of the reference setup
     distribution: str = "gaussian"  # or "cauchy"
     lam: float = 0.1
@@ -40,6 +51,57 @@ class TimitConfig:
     num_phones: int = 24
     seed: int = 0
     synthetic_n: int = 4096
+
+
+def build_featurizer(conf: TimitConfig, train_data) -> Pipeline:
+    """Frames -> standard scaling -> ``num_cosines`` cosine blocks of
+    ``num_features`` each, in block order. The scaler is fitted here, so
+    the pipeline is two transformers that the optimizer fuses into one
+    program."""
+    scaler = StandardScaler().fit(train_data)
+    return scaler.and_then(
+        CosineRandomFeatures.create(
+            input_dim=int(train_data.shape[1]),
+            num_features=conf.num_cosines * conf.num_features,
+            gamma=conf.gamma,
+            distribution=conf.distribution,
+            seed=conf.seed,
+            blocks=conf.num_cosines,
+        )
+    )
+
+
+def fit(conf: TimitConfig, train: LabeledData,
+        num_phones: Optional[int] = None) -> Pipeline:
+    """Fit on ``train``: the fitted pipeline, frames in and the phone
+    index out (its stages: scaler, cosine features, block linear map,
+    argmax). The one construction ``run`` (the CLI) and the benchmark
+    share."""
+    num_phones = conf.num_phones if num_phones is None else num_phones
+    rows, dim_in = (int(s) for s in train.data.shape)
+    tracer = active_tracer()
+    # The root span of one whole fit. It closes when the solver's programs
+    # are dispatched, not when the device has run them.
+    with span_of(tracer, "fit", "pipeline", pipeline="timit", rows=rows):
+        featurizer = build_featurizer(conf, train.data)
+        # The features are computed once, here, and handed to the solver as
+        # data. Nothing waits for them: the span covers the chain's
+        # dispatch, and the device runs it while the host goes on.
+        with span_of(tracer, "features.random", "pipeline", rows=rows,
+                     dim_in=dim_in,
+                     dim_out=conf.num_cosines * conf.num_features,
+                     blocks=conf.num_cosines) as attrs:
+            sent = program_counters.get("argument_bytes")
+            features = featurizer(train.data).get()
+            if attrs is not None:
+                attrs["bytes"] = program_counters.get("argument_bytes") - sent
+        targets = ClassLabelIndicators(num_phones)(train.labels)
+        head = BlockLeastSquaresEstimator(
+            block_size=conf.block_size,
+            num_iters=conf.num_iters,
+            lam=conf.lam,
+        ).with_data(features, targets)
+        return featurizer.and_then(head).and_then(MaxClassifier()).fit()
 
 
 def run(conf: TimitConfig) -> dict:
@@ -58,25 +120,7 @@ def run(conf: TimitConfig) -> dict:
         num_phones = conf.num_phones
 
     t0 = time.perf_counter()
-    featurizer = StandardScaler().with_data(train.data).and_then(
-        CosineRandomFeatures.create(
-            input_dim=train.data.shape[1],
-            num_features=conf.num_features,
-            gamma=conf.gamma,
-            distribution=conf.distribution,
-            seed=conf.seed,
-        )
-    )
-    targets = ClassLabelIndicators(num_phones)(train.labels)
-    pipeline = featurizer.and_then(
-        BlockLeastSquaresEstimator(
-            block_size=conf.block_size,
-            num_iters=conf.num_iters,
-            lam=conf.lam,
-        ),
-        train.data,
-        targets,
-    ).and_then(MaxClassifier())
+    pipeline = fit(conf, train, num_phones)
     predictions = pipeline(test.data).get()
     elapsed = time.perf_counter() - t0
 
@@ -101,7 +145,10 @@ def main(argv=None):
     p.add_argument("--labels", dest="labels_path")
     p.add_argument("--test-features", dest="test_features_path")
     p.add_argument("--test-labels", dest="test_labels_path")
-    p.add_argument("--num-features", type=int, default=4096)
+    p.add_argument("--num-features", type=int, default=4096,
+                   help="features a cosine block")
+    p.add_argument("--num-cosines", type=int, default=1,
+                   help="cosine blocks (upstream's numCosines: 50)")
     p.add_argument("--gamma", type=float, default=0.055)
     p.add_argument(
         "--distribution", choices=["gaussian", "cauchy"], default="gaussian"
@@ -120,6 +167,7 @@ def main(argv=None):
             test_features_path=a.test_features_path,
             test_labels_path=a.test_labels_path,
             num_features=a.num_features,
+            num_cosines=a.num_cosines,
             gamma=a.gamma,
             distribution=a.distribution,
             lam=a.lam,

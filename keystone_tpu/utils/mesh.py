@@ -176,11 +176,13 @@ class SpecLayout:
         """Replicated: fitted params, grams, solved weights."""
         return NamedSharding(self.mesh, P())
 
-    def jit(self, fn, donate_argnums=(), **jit_kwargs):
+    def jit(self, fn, donate_argnums=(), params: bool = False, **jit_kwargs):
         """Lower ``fn`` (batch -> batch, row-independent) ONCE with the
         convention's explicit shardings: rows sharded in, rows sharded
         out. The explicit specs — not input inheritance — are what make
-        the chain's placement a contract instead of an accident.
+        the chain's placement a contract instead of an accident. With
+        ``params``, ``fn`` is (arrays, batch) -> batch and the arrays (any
+        pytree) are replicated.
 
         ``donate_argnums`` is honored only under ``config.donate_buffers``
         (KEYSTONE_DONATE_BUFFERS=0 pins it off) and is the caller's claim
@@ -193,9 +195,10 @@ class SpecLayout:
         the fake-device tests pin deletion and aliasing for real."""
         if donate_argnums and config.donate_buffers:
             jit_kwargs["donate_argnums"] = donate_argnums
+        rows = self.data()
         return jax.jit(
-            fn, in_shardings=self.data(), out_shardings=self.data(),
-            **jit_kwargs,
+            fn, in_shardings=(self.replicated(), rows) if params else rows,
+            out_shardings=rows, **jit_kwargs,
         )
 
     def put(self, x) -> jax.Array:

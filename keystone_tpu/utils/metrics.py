@@ -1288,6 +1288,13 @@ def node_cost_analysis(transformer, X) -> Optional[Dict[str, float]]:
             "bytes_accessed": cost["bytes_accessed"],
         }
         est.update(_memory_analysis(compiled))
+        # ``argument_bytes`` is the batch's: a program that takes its
+        # transformer's arrays as arguments (``Transformer.array_fields``)
+        # counts them too, and the planner that prices a row would bill
+        # the weights to every row.
+        if "argument_bytes" in est and getattr(transformer, "takes_arrays", bool)():
+            own = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(transformer))
+            est["argument_bytes"] = max(est["argument_bytes"] - own, 0.0)
     except Exception:  # lint: broad-ok the cost model is best-effort; any lowering/compile failure means 'no estimate', never a failed fit
         est = None
     with _node_cost_lock:
@@ -1903,6 +1910,22 @@ class ShardingCounters(CounterSet):
 
 sharding_counters = ShardingCounters()
 metrics_registry.register("sharding", sharding_counters)
+
+
+class ProgramCounters(CounterSet):
+    """What the jitted transformer programs were handed as arguments
+    (``workflow/pipeline.py``: a transformer that names its
+    ``array_fields``). Thread-safe (CounterSet).
+
+    - ``argument_bytes``: the size of the arrays a call handed its
+      program, summed over the calls; a chain whose arrays are constants
+      of its program adds nothing, so a span that reads the counter around
+      a call tells the two apart
+    """
+
+
+program_counters = ProgramCounters()
+metrics_registry.register("programs", program_counters)
 
 
 class ServePlanCounters(CounterSet):

@@ -104,7 +104,11 @@ class ChainFusionRule(Rule):
                 # cascade); dropping one cold entry degrades gracefully.
                 self._fuse_cache.pop(next(iter(self._fuse_cache)))
             fused = FusedTransformer(stages)
-            self._fuse_cache[key] = fused
+            # A chain whose program is found by its structure needs no memo
+            # to keep its executable, and the memo would pin its arrays
+            # (0.36 GB a TIMIT fit) for the life of the process.
+            if not fused.takes_arrays():
+                self._fuse_cache[key] = fused
         return fused
 
     def apply(self, graph: Graph, targets: Sequence[GraphId]) -> Graph:

@@ -20,6 +20,7 @@ values are (possibly sharded) device arrays.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, List, Optional, Sequence, Union
 
 import jax
@@ -49,6 +50,67 @@ def _is_array(x: Any) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# Programs that take a transformer's arrays as arguments
+# ---------------------------------------------------------------------------
+
+
+# Per-instance memos of a transformer (jitted callables; the donation memo's
+# keys carry the live mesh): never pickled, flattened or compared.
+_JIT_CACHES = ("_jit_cache", "_shard_jit_cache", "_donate_ok_cache")
+
+
+@functools.lru_cache(maxsize=None)
+def _program(name: str, layout=None, donate: bool = False):
+    """The jitted ``(transformer, X) -> transformer.apply_batch(X)``. Every
+    ``Transformer`` is a pytree (its ``array_fields`` the children, its
+    other fields the static part), so ``jax.jit`` keys on the tree's
+    structure and the arrays' shapes and dtypes: the compiled program holds
+    none of the arrays, and transformers that differ only in them share it.
+    One callable a ``name`` because the HLO module is named after the
+    function, and the device trace is read by module. With ``layout`` it is
+    the sharded lowering (arrays replicated, rows sharded in and out)."""
+
+    def apply(transformer, X):
+        if layout is None:
+            return transformer.apply_batch(X)
+        return transformer.apply_sharded(X, layout)
+
+    apply.__name__ = ("apply_" + name)[:96]
+    if layout is None:
+        return jax.jit(apply)
+    return layout.jit(apply, donate_argnums=(1,) if donate else (), params=True)
+
+
+class _Bound:
+    """``_program`` with its transformer filled in: what ``_jitted()``
+    hands out, with the parts of a jitted callable its callers use. Made
+    anew at each call (a cached one would tie the transformer into a
+    cycle), and it counts the bytes it hands over (``program_counters``:
+    what a constant would not show)."""
+
+    __slots__ = ("program", "transformer")
+
+    def __init__(self, program, transformer: "Transformer"):
+        self.program = program
+        self.transformer = transformer
+
+    def __call__(self, X):
+        from keystone_tpu.utils.metrics import program_counters
+
+        program_counters.bump("argument_bytes", sum(
+            int(leaf.nbytes)
+            for leaf in jax.tree_util.tree_leaves(self.transformer)
+        ))
+        return self.program(self.transformer, X)
+
+    def lower(self, X):
+        return self.program.lower(self.transformer, X)
+
+    def _cache_size(self) -> int:
+        return self.program._cache_size()
+
+
+# ---------------------------------------------------------------------------
 # Transformer
 # ---------------------------------------------------------------------------
 
@@ -75,6 +137,64 @@ class Transformer:
     # CenterCornerPatcher) set False, and the bucketed serving path refuses
     # them with serving.RowDependenceError.
     row_independent: bool = True
+
+    # The attributes that hold this transformer's arrays, fitted or drawn
+    # (or, for a chain, its stages): the children of the pytree every
+    # transformer is. A transformer that names them is traced with those
+    # arrays as arguments of its program (``_program``), not as constants
+    # in it: the program does not grow with them, need not be compiled
+    # again for other values, and is shared by every transformer that
+    # differs only in them. The other fields are the static part and have
+    # to hash; where they do not (an array that is not named here), or
+    # where nothing is named, ``apply_batch`` is jitted as the closure it
+    # is.
+    array_fields: tuple = ()
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        jax.tree_util.register_pytree_node(
+            cls, cls._tree_flatten, functools.partial(cls._tree_unflatten, cls)
+        )
+
+    def _tree_flatten(self):
+        """(the ``array_fields``' values; what ``apply_batch`` reads beside
+        them: the other fields, each with its type, so that 1, 1.0 and True
+        are three programs). ``_sig`` is an identity, not an input."""
+        skip = self.array_fields + _JIT_CACHES + ("_sig",)
+        static = tuple(sorted(
+            ((k, v, type(v)) for k, v in vars(self).items() if k not in skip),
+            key=lambda field: field[0],
+        ))
+        return tuple(getattr(self, k) for k in self.array_fields), static
+
+    @staticmethod
+    def _tree_unflatten(cls, static, children) -> "Transformer":
+        """A transformer of ``cls`` around ``children`` (inside a trace:
+        tracers), with no signature and no program of its own."""
+        made = object.__new__(cls)
+        made.__dict__.update((k, v) for k, v, _type in static)
+        made.__dict__.update(zip(cls.array_fields, children))
+        return made
+
+    def takes_arrays(self) -> bool:
+        """Does this transformer's program take its arrays as arguments?
+        Then its structure finds the executable again, not its identity,
+        and nothing need keep it alive for that."""
+        leaves, treedef = jax.tree_util.tree_flatten(self)
+        nodes = [treedef] if leaves else []
+        try:
+            while nodes:  # PyTreeDef's own hash leaves the static parts out
+                node = nodes.pop()
+                kind, static = node.node_data() or (None, None)
+                if isinstance(kind, type) and issubclass(kind, Transformer):
+                    hash(static)
+                nodes.extend(node.children())
+        except TypeError:
+            return False
+        return bool(leaves)
+
+    def _program_name(self) -> str:
+        return type(self).__name__
 
     def apply(self, x: Any) -> Any:
         if _is_array(x) or jnp.isscalar(x):
@@ -128,6 +248,8 @@ class Transformer:
         return self.apply_batch(X)
 
     def _jitted(self) -> Callable:
+        if self.takes_arrays():
+            return _Bound(_program(self._program_name()), self)
         fn = getattr(self, "_jit_cache", None)
         if fn is None:
             fn = jax.jit(self.apply_batch)
@@ -152,6 +274,9 @@ class Transformer:
         out) — memoized per (transformer, layout, donate) like
         ``_jitted``. The donated variant aliases the staged input buffer
         into the chain's output (``SpecLayout.jit`` donation)."""
+        if self.takes_arrays():
+            program = _program(self._program_name(), layout, donate)
+            return _Bound(program, self)
         cache = getattr(self, "_shard_jit_cache", None)
         if cache is None:
             cache = {}
@@ -259,9 +384,8 @@ class Transformer:
         unpicklable; they rebuild lazily after load). Non-mutating, so
         persisting a live fitted transformer keeps its warm compilation."""
         state = dict(self.__dict__)
-        state.pop("_jit_cache", None)
-        state.pop("_shard_jit_cache", None)
-        state.pop("_donate_ok_cache", None)  # keys carry the (live) mesh
+        for name in _JIT_CACHES:
+            state.pop(name, None)
         return state
 
     def signature(self) -> Any:
@@ -330,6 +454,9 @@ class FusedTransformer(Transformer):
     single jitted program XLA can fuse end-to-end.
     """
 
+    # The chain's arrays are its stages', handed through as one pytree.
+    array_fields = ("stages",)
+
     def __init__(self, stages: Sequence[Transformer]):
         flat: List[Transformer] = []
         for s in stages:
@@ -357,6 +484,9 @@ class FusedTransformer(Transformer):
         for s in self.stages:
             X = s.apply_sharded(X, layout)
         return X
+
+    def _program_name(self):
+        return "_".join(s._program_name() for s in self.stages)
 
     def signature(self):
         return ("fused",) + tuple(s.signature() for s in self.stages)
