@@ -68,10 +68,11 @@ def _local_gram_inv(a_b, aw, lam, precision, axis, width):
     The inverse — not the Cholesky factor — is the cached quantity: XLA
     lowers triangular solves to a sequential substitution that dominates
     BCD wall-clock on TPU, while multiplying by a precomputed inverse is
-    one MXU gemm. Forming the inverse costs a one-time pair of triangular
-    solves per block; the λ-regularized SPD gram keeps it well-conditioned,
-    and later epochs re-solve against the residual, so per-epoch solve
-    error self-corrects instead of accumulating."""
+    one MXU gemm. Forming the inverse costs, once per block, a Cholesky and
+    the blocked triangular inverse and product of ``_batched_spd_inv``;
+    the λ-regularized SPD gram keeps it well-conditioned, and later epochs
+    re-solve against the residual, so per-epoch solve error self-corrects
+    instead of accumulating."""
     return _batched_spd_inv(
         _local_ridge_gram(a_b, aw, lam, precision, axis, width)
     )
@@ -131,77 +132,83 @@ def _gram_only_fn(mesh: Mesh, axis: str, precision, weighted: bool,
     return jax.jit(sm)
 
 
-def _trsm_rhs_chunk(b: int, batch: int, itemsize: int) -> int:
-    """Column-chunk width for the identity-RHS triangular solves below.
-
-    XLA:TPU expands TriangularSolve into an UNROLLED 128-row panel chain
-    that materializes one (batch, rows_left, rhs_w) HLO temp per panel —
-    about batch·b²·w·itemsize/128 bytes across the chain. Against the
-    full b-wide identity at (batch=2, b=8192) that is ~17 GB and fails
-    v5e buffer assignment outright (measured via the deviceless AOT
-    compile: "Used 16.23G of 15.75G hbm"). Chunking the RHS columns and
-    scanning the chunks (scan = real while loop, temps REUSED per
-    iteration) caps the chain at ~2 GB while each panel step stays at
-    least one full 128-lane MXU tile wide. The floor is the 128 lane
-    width, NOT larger: a bigger floor would silently override the budget
-    right where it matters most (ring-path d_loc ≥ 16k). At the floor the
-    chain still grows as batch·b²·itemsize — but there the b×b operands
-    themselves approach HBM capacity and the caller must shard d
-    further."""
-    budget = 2 << 30
-    w = budget * 128 // max(1, batch * b * b * itemsize)
-    if w >= b:
-        return b
-    return max(128, 1 << int(np.floor(np.log2(max(w, 1)))))
+# Widest triangle the blocked inverse hands to XLA's own triangular solve
+# and to one dense YᵀY. Chosen on a v5e among 512, 1024 and 2048: the
+# inverse of (2, 8192, 8192) in 83.1, 81.7, 81.9 ms and of (8, 4096, 4096)
+# in 54.9, 53.5, 62.0 ms, where the dense solve and product took 196.4 and
+# 108.8 (chip run, PR 29, call 81: PERF.md section 6). A constant, not a
+# knob.
+_INV_LEAF = 1024
 
 
-def _batched_spd_inv(grams, rhs_chunk: Optional[int] = None):
+def _mm(x, y):
+    return jnp.matmul(x, y, precision=lax.Precision.HIGHEST)
+
+
+def _inv_split(b: int) -> int:
+    """Where a (b, b) triangle is cut in two: the multiple of the 128-lane
+    tile nearest b/2, so that the tiles start on a tile boundary; the plain
+    half where b is too small for that."""
+    h = max(128, (b + 128) // 256 * 128)
+    return h if h < b else b // 2
+
+
+def _inv_levels(b: int, leaf: int = _INV_LEAF) -> int:
+    """How many times the blocked inverse cuts a (b, b) block before every
+    piece is at most ``leaf`` wide: 0 is one leaf, the unblocked path."""
+    if b <= leaf:
+        return 0
+    h = _inv_split(b)
+    return 1 + max(_inv_levels(h, leaf), _inv_levels(b - h, leaf))
+
+
+def _tri_inv(chol, leaf: int):
+    """Y = L⁻¹ of a (batched) lower-triangular L, LAPACK's ``trtri`` by
+    halves: Y11 = inv(L11), Y22 = inv(L22), Y21 = −Y22 (L21 Y11). The
+    upper-right tile is zero and never computed, and all but the leaves'
+    2·leaf³ is two MXU gemms a level: 2b³/3 FLOP where a solve against the
+    whole identity is 2b³ in XLA's unrolled 128-row panel chain."""
+    b = chol.shape[-1]
+    if b <= leaf:
+        eye = jnp.broadcast_to(jnp.eye(b, dtype=chol.dtype), chol.shape)
+        return solve_triangular(chol, eye, lower=True)
+    h = _inv_split(b)
+    y11 = _tri_inv(chol[..., :h, :h], leaf)
+    y22 = _tri_inv(chol[..., h:, h:], leaf)
+    y21 = -_mm(y22, _mm(chol[..., h:, :h], y11))
+    return jnp.block([[y11, jnp.zeros_like(y21.mT)], [y21, y22]])
+
+
+def _tri_gram(y, leaf: int):
+    """YᵀY of a (batched) lower-triangular Y, LAPACK's ``lauum`` by halves
+    (the same cut as ``_tri_inv``): S11 = gram(Y11) + Y21ᵀY21,
+    S21 = Y22ᵀY21, S22 = gram(Y22), S12 = S21ᵀ. 2b³/3 FLOP where the dense
+    product multiplies 2b³, two thirds of them by zeros."""
+    b = y.shape[-1]
+    if b <= leaf:
+        return _mm(y.mT, y)
+    h = _inv_split(b)
+    y21, y22 = y[..., h:, :h], y[..., h:, h:]
+    s11 = _tri_gram(y[..., :h, :h], leaf) + _mm(y21.mT, y21)
+    s21 = _mm(y22.mT, y21)
+    return jnp.block([[s11, s21.mT], [s21, _tri_gram(y22, leaf)]])
+
+
+def _batched_spd_inv(grams, leaf: int = _INV_LEAF):
     """(Batched) SPD inverse — THE single source for the factor-phase
-    inverse, batched (leading block axis) or not.
+    inverse, batched (leading block axis) or not: LAPACK's ``potri`` shape,
+    A = LLᵀ, Y = L⁻¹ (``_tri_inv``), A⁻¹ = YᵀY (``_tri_gram``), every
+    product float32 at ``HIGHEST``.
 
-    Two TPU-shaped choices:
-    - ONE triangular solve, not two. A⁻¹ = (L⁻¹)ᵀ(L⁻¹), so only
-      Y = L⁻¹ is computed by substitution; the second "solve" is an MXU
-      gemm (YᵀY, HIGHEST precision). XLA lowers trsm as a sequential
-      panel loop — halving the trsm count halves the sequential tail of
-      every factor phase, and the batch dimension amortizes what's left.
-    - The identity RHS is column-chunked per ``_trsm_rhs_chunk``
-      (``rhs_chunk`` overrides, for tests) so the unrolled trsm expansion
-      can't blow the HBM temp budget at large b."""
-    chol = jnp.linalg.cholesky(grams)
-    b = grams.shape[-1]
-    batch = int(np.prod(grams.shape[:-2])) if grams.ndim > 2 else 1
-    # `is None`, not truthiness: an explicit rhs_chunk=0 must error, not
-    # silently fall back to the policy (ADVICE r5).
-    if rhs_chunk is None:
-        w = _trsm_rhs_chunk(b, batch, jnp.dtype(grams.dtype).itemsize)
-    else:
-        assert rhs_chunk >= 1, f"rhs_chunk must be >= 1, got {rhs_chunk}"
-        w = rhs_chunk
-    eye = jnp.eye(b, dtype=grams.dtype)
-    if w >= b:
-        eyeb = jnp.broadcast_to(eye, grams.shape)
-        y = solve_triangular(chol, eyeb, lower=True)
-    else:
-        nc = -(-b // w)
-        eye_pad = jnp.pad(eye, ((0, 0), (0, nc * w - b)))
-
-        def chunk_cols(_, c0):
-            rhs = jnp.broadcast_to(
-                lax.dynamic_slice(eye_pad, (0, c0), (b, w)),
-                grams.shape[:-2] + (b, w),
-            )
-            return None, solve_triangular(chol, rhs, lower=True)
-
-        _, cols = lax.scan(
-            chunk_cols, None, jnp.arange(0, nc * w, w, dtype=jnp.int32)
-        )
-        # cols: (nc, *batch, b, w) → (*batch, b, nc·w), drop padding.
-        cols = jnp.moveaxis(cols, 0, -2)
-        y = cols.reshape(grams.shape[:-1] + (nc * w,))[..., :b]
-    return jnp.matmul(
-        jnp.swapaxes(y, -1, -2), y, precision=lax.Precision.HIGHEST
-    )
+    Both steps skip the zeros of the triangle, 4b³/3 FLOP a block where a
+    dense solve against the identity and a dense YᵀY run 4b³. It adapts on
+    b alone: a block at most ``_INV_LEAF`` wide (every toy width) is one
+    leaf, one ``solve_triangular`` and one gemm. The leaves also bound the
+    unrolled trsm expansion's temporaries (batch·leaf³·itemsize/128: 0.27 GB
+    at batch 8, leaf 1024), which at b = 8192 against the whole identity
+    failed v5e buffer assignment. ``leaf`` is for tests."""
+    assert leaf >= 1, f"leaf must be >= 1, got {leaf}"
+    return _tri_gram(_tri_inv(jnp.linalg.cholesky(grams), leaf), leaf)
 
 
 @lru_cache(maxsize=None)
@@ -409,13 +416,15 @@ def _factor_chunk(block_size: Optional[int] = None) -> int:
     independent per-block programs on the CPU backend — there, per-block.
     An explicit config.factor_batch forces that chunk on any backend.
 
-    The auto chunk is additionally MEMORY-capped: XLA's batched
-    triangular-solve lowering holds a handful of (chunk, b, b) HLO temps,
-    so an uncapped chunk·b² OOMs HBM at large blocks — the deviceless v5e
-    AOT compile of the ImageNet bench shape (chunk 8 · b 8192) demanded
-    >16 GiB of temps. Capping chunk·b² at 128M f32 elements (512 MB per
-    temp) keeps the factor transient ~1-2 GiB: b=8192 gets chunk 2
-    (128M // 8192² = 2), b≤2896 keeps the full batch of 16."""
+    The auto chunk is additionally MEMORY-capped: the factor program holds
+    a handful of (chunk, b, b) temps (the gram, its Cholesky factor, the
+    halves of the blocked inverse and product), so an uncapped chunk·b²
+    OOMs HBM at large blocks. Capping chunk·b² at 128M f32 elements
+    (512 MB per temp) keeps the factor transient under 2 GiB: b=8192 gets
+    chunk 2 (128M // 8192² = 2) and b=4096 chunk 8, whose fused factor
+    programs hold 1.76 and 1.57 GiB of temps by the deviceless v5e compile
+    (2.79 and 2.60 before the inverse was blocked: PERF.md section 6,
+    PR 29); b≤2896 keeps the full batch of 16."""
     if config.factor_batch is not None:
         return max(1, int(config.factor_batch))
     if jax.default_backend() == "cpu":
@@ -638,8 +647,12 @@ def _solve_fused(
     if cache_grams:
         # Chunked like _factor_blocks (shared _factor_chunk policy): bounds
         # the factor transient to chunk·b² buffers instead of nb·b².
-        chunk = _factor_chunk(blocks[0][1] - blocks[0][0])
-        with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk):
+        b = blocks[0][1] - blocks[0][0]
+        chunk = _factor_chunk(b)
+        # leaf and levels say whether the blocked inverse engaged: 0 levels
+        # is one leaf, the unblocked path.
+        with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk,
+                     leaf=_INV_LEAF, levels=_inv_levels(b)):
             factor = _fused_factor_fn(
                 mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
             )

@@ -71,9 +71,9 @@ def _ring_solve_fn(mesh: Mesh, model_axis: str, data_axis, precision):
         # relative, inside solver tolerance.
         eye = jnp.eye(d_loc, dtype=gram.dtype)
         jitter = 1e-6 * (jnp.trace(gram) / d_loc)
-        # Shared chunked-RHS inverse (bcd._batched_spd_inv): the naive
-        # full-identity trsm pair blows XLA:TPU's unrolled-panel temp
-        # budget at large d_loc.
+        # Shared blocked inverse (bcd._batched_spd_inv): a trsm against
+        # the full identity blows XLA:TPU's unrolled-panel temp budget at
+        # large d_loc, and two thirds of it is work on zeros.
         inv = _batched_spd_inv(gram + (lam + jitter) * eye)
         idx = lax.axis_index(model_axis)
         # Solver state in the accumulation dtype even when A stores bf16.

@@ -176,6 +176,14 @@ def test_batched_factor_phase_compiles_for_v5e(mesh):
     batched = _batched_ridge_inv_fn(mesh)
     c2 = batched.lower(_sds((g, b, b), mesh, P())).compile()
     assert _compiled_ok(c2)
+    # Above the leaf the inverse is blocked: 1152 = 640 + 512, halves
+    # that differ and of which one is no power of two.
+    from keystone_tpu.linalg.bcd import _inv_levels
+
+    assert _inv_levels(1152) == 1
+    c3 = batched.lower(_sds((2, 1152, 1152), mesh, P())).compile()
+    assert _compiled_ok(c3)
+    assert "Cholesky" in c3.as_text()  # factor_ms tells the phase by it
 
 
 def test_ring_bcd_step_compiles_for_v5e(mesh):
@@ -378,6 +386,39 @@ def test_two_branch_imagenet_featurizer_compiles_for_v5e(mesh):
     assert wall < 600.0, f"featurizer compile took {wall:.0f}s"
 
 
+def _compile_fused_factor(mesh, n, b, expected_chunk):
+    """The fused factor program at the chunk the TPU policy gives block b,
+    compiled for one v5e chip; returns (one-chip mesh, chunk). Its
+    temporaries must stay inside what the chunk policy budgets (1.76 GiB
+    at chunk 2 of b 8192 and 1.57 at chunk 8 of b 4096 with the blocked
+    inverse; 2.79 and 2.60 with the dense solve and product it replaced)."""
+    from unittest import mock
+
+    from keystone_tpu.linalg.bcd import _factor_chunk, _fused_factor_fn
+    from keystone_tpu.linalg.row_matrix import _precision
+
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        chunk = _factor_chunk(b)  # the TPU policy, not this CPU host's
+    assert chunk == expected_chunk
+    one = Mesh(np.array(mesh.devices.flat[:1]), (AXIS,))
+    factor = _fused_factor_fn(one, AXIS, _precision(), False, _fold(one))
+    compiled = factor.lower(
+        _sds((chunk, n, b), one, P(None, AXIS)),
+        _sds((), one, P()),
+        _sds((n,), one, P(AXIS)),
+    ).compile()
+    assert _compiled_ok(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    return one, chunk
+
+
+@pytest.mark.slow
+def test_fused_factor_compiles_at_timit_fit_shape(mesh):
+    """The benchmark's ``timit-fit`` factor program: 8 blocks of 4096 a
+    program, 4096 rows."""
+    _compile_fused_factor(mesh, n=4096, b=4096, expected_chunk=8)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "scale_key,expected_chunk",
@@ -392,36 +433,21 @@ def test_fused_solver_compiles_at_bench_shapes(mesh, scale_key, expected_chunk):
     first XLA:TPU compile on the chip, and must fit v5e buffer
     assignment."""
     import bench as bench_mod
-    from keystone_tpu.linalg.bcd import (
-        _factor_chunk,
-        _fused_epochs_fn,
-        _fused_factor_fn,
-    )
+    from keystone_tpu.linalg.bcd import _fused_epochs_fn
     from keystone_tpu.linalg.row_matrix import _precision
 
     p = bench_mod.SCALE[scale_key]
     n, d, k, b = p["n"], p["d"], p["k"], p["block"]
     nb = d // b
-    one = Mesh(np.array(mesh.devices.flat[:1]), (AXIS,))
     # The production factor phase chunks the stack (_solve_fused): the
     # UNCHUNKED (nb, n, b) factor program at this shape demands ~5 stacked
     # (nb, b, b) temps ≈ 10+ GB of HLO temp and fails v5e buffer
     # assignment — which is exactly why the chunk policy exists. Compile
-    # the shape production actually runs.
-    from unittest import mock
-
-    with mock.patch("jax.default_backend", return_value="tpu"):
-        chunk = _factor_chunk(b)  # the TPU policy, not this CPU host's
-    # Pin the policy output per scale so cap rot is detected where the
-    # cap binds (imagenet) and batch-default drift where it doesn't (xl).
-    assert chunk == expected_chunk and chunk < nb
-    factor = _fused_factor_fn(one, AXIS, _precision(), False, _fold(one))
-    c1 = factor.lower(
-        _sds((chunk, n, b), one, P(None, AXIS)),
-        _sds((), one, P()),
-        _sds((n,), one, P(AXIS)),
-    ).compile()
-    assert _compiled_ok(c1)
+    # the shape production actually runs. The policy output is pinned per
+    # scale so cap rot is detected where the cap binds (imagenet) and
+    # batch-default drift where it doesn't (xl).
+    one, chunk = _compile_fused_factor(mesh, n, b, expected_chunk)
+    assert chunk < nb
     epochs = _fused_epochs_fn(
         one, AXIS, _precision(), False, p["iters"], True, _fold(one)
     )

@@ -202,27 +202,81 @@ def test_bcd_batched_factor_ragged_and_chunked(rng):
     )
 
 
-def test_spd_inv_rhs_chunked_matches_full(rng):
-    """The column-chunked identity-RHS inverse (the v5e HBM fix for the
-    unrolled trsm expansion) must equal the one-shot inverse — including a
-    ragged final chunk and the batched leading axis."""
+@pytest.mark.parametrize(
+    "b,leaf,levels,batch",
+    [
+        (13, 1024, 0, (3,)),  # one leaf: the unblocked path, at the default
+        (13, 5, 2, (3,)),  # leaves narrower than a tile: the plain half
+        (300, 128, 2, (2,)),  # 128 + (128 + 44): b no multiple of the leaf
+        (300, 128, 2, ()),  # unbatched
+        (1100, 300, 3, (2,)),  # 512 + 588, 588 = 256 + 332, 332 = 128 + 204
+        (1100, 300, 3, ()),
+    ],
+)
+def test_spd_inv_blocked_matches_one_leaf_and_oracle(rng, b, leaf, levels, batch):
+    """The blocked inverse (a triangular inverse and a triangular product
+    by halves) must equal the one-leaf inverse (one triangular solve, one
+    dense product: what every toy width runs) and the float64 oracle, with
+    ragged halves and with or without the batched leading axis."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg.bcd import _batched_spd_inv, _inv_levels
+
+    assert _inv_levels(b, leaf) == levels
+    X = rng.normal(size=batch + (b, b)).astype(np.float32)
+    grams = X @ np.swapaxes(X, -1, -2) / b + 2.0 * np.eye(b, dtype=np.float32)
+    one = np.asarray(_batched_spd_inv(jnp.asarray(grams), leaf=b))
+    blocked = np.asarray(_batched_spd_inv(jnp.asarray(grams), leaf=leaf))
+    assert blocked.shape == grams.shape
+    np.testing.assert_allclose(blocked, one, rtol=1e-5, atol=1e-5)
+    oracle = np.linalg.inv(grams.astype(np.float64))
+    np.testing.assert_allclose(blocked, oracle, rtol=1e-3, atol=1e-3)
+    # S12 is S21's mirror: the inverse is symmetric to the bit.
+    np.testing.assert_array_equal(blocked, np.swapaxes(blocked, -1, -2))
+
+
+def test_spd_inv_blocked_holds_the_residual_at_condition_1e6(rng):
+    """On a float32 ridge gram of condition 1e6 (the cells' blocks read
+    4e5) the blocked path's ‖A·inv − I‖ is no worse than twice the
+    one-leaf path's."""
     import jax.numpy as jnp
 
     from keystone_tpu.linalg.bcd import _batched_spd_inv
 
-    b = 13
-    X = rng.normal(size=(3, b, b)).astype(np.float32)
-    grams = X @ np.swapaxes(X, 1, 2) / b + 2.0 * np.eye(b, dtype=np.float32)
-    full = np.asarray(_batched_spd_inv(jnp.asarray(grams)))
-    chunked = np.asarray(_batched_spd_inv(jnp.asarray(grams), rhs_chunk=5))
-    np.testing.assert_allclose(chunked, full, rtol=1e-5, atol=1e-5)
-    oracle = np.linalg.inv(grams.astype(np.float64))
-    np.testing.assert_allclose(chunked, oracle, rtol=1e-3, atol=1e-3)
-    # Unbatched path with an exact-multiple chunk.
-    one = np.asarray(_batched_spd_inv(jnp.asarray(grams[0]), rhs_chunk=13))
-    np.testing.assert_allclose(one, oracle[0], rtol=1e-3, atol=1e-3)
-    two = np.asarray(_batched_spd_inv(jnp.asarray(grams[0]), rhs_chunk=4))
-    np.testing.assert_allclose(two, oracle[0], rtol=1e-3, atol=1e-3)
+    b = 512
+    q, _ = np.linalg.qr(rng.normal(size=(b, b)))
+    gram = ((q * np.logspace(0, -6, b)) @ q.T).astype(np.float32)
+    gram = (gram + gram.T) / 2
+    assert 5e5 < np.linalg.cond(gram.astype(np.float64)) < 2e6
+
+    def residual(leaf):
+        inv = np.asarray(_batched_spd_inv(jnp.asarray(gram), leaf=leaf), np.float64)
+        return np.linalg.norm(gram.astype(np.float64) @ inv - np.eye(b))
+
+    assert residual(64) <= 2.0 * residual(b)
+
+
+def test_spd_inv_blocked_skips_the_zeros():
+    """A count, not a speed: at b = 1024 the blocked path's compiled
+    program multiplies 4b³/3 and a leaf's share where one leaf's dense YᵀY
+    alone is 2b³. (The CPU's count leaves the triangular solve out, a LAPACK
+    call, so the ratio here cannot pass under 2/3; with the solve's 2b³
+    counted on the one-leaf side it is a third.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg.bcd import _batched_spd_inv
+
+    b = 1024
+    gram = jax.ShapeDtypeStruct((b, b), jnp.float32)
+
+    def flops(leaf):
+        fn = jax.jit(lambda g: _batched_spd_inv(g, leaf=leaf))
+        return fn.lower(gram).compile().cost_analysis()["flops"]
+
+    one, blocked = flops(b), flops(128)
+    assert one >= 2 * b**3
+    assert blocked < 1.45 * b**3 and blocked < 0.72 * one
 
 
 def test_bcd_cached_grams_weighted(rng):

@@ -878,6 +878,24 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
             for m, e in zip(mirrors["ks:fisher.sample"], expected)] == expected
 
 
+def test_solver_factor_span_says_whether_the_blocked_inverse_engaged(traced, rng):
+    """``solver.factor`` carries ``leaf`` and ``levels``: 0 levels is one
+    leaf, the unblocked path every toy width runs; the two cells' blocks
+    (4096, 8192) are cut two and three times."""
+    from keystone_tpu.linalg import RowMatrix, bcd
+
+    tr = traced(True)
+    A = rng.normal(size=(64, 16)).astype(np.float32)
+    B = rng.normal(size=(64, 3)).astype(np.float32)
+    bcd.block_coordinate_descent(
+        RowMatrix.from_array(A), RowMatrix.from_array(B), block_size=8,
+        num_iters=2, lam=0.1, cache_grams=True)
+    (factor,) = [s for s in tr.spans() if s["name"] == "solver.factor"]
+    assert factor["args"] == {"blocks": 2, "chunk": bcd._factor_chunk(8),
+                              "leaf": bcd._INV_LEAF, "levels": 0}
+    assert [bcd._inv_levels(b) for b in (1024, 4096, 8192)] == [0, 2, 3]
+
+
 def test_solver_programs_keep_the_names_the_benchmark_filters_on():
     """``benchmark/metrics/{solver_roofline,factor_ms,featurize_ms}.json``
     tell the solver's device time by the HLO module names ``jit_local`` and
