@@ -201,24 +201,6 @@ class Config:
             "KEYSTONE_ELASTIC_MESH", ""
         ).lower() not in ("0", "false", "no")
     )
-    # Feature blocks whose gram ridge inverses are factorized together in
-    # ONE batched XLA program (batched Cholesky + triangular solves over a
-    # leading block axis). TPU lowers a single b×b factorization to a
-    # sequential panel loop; batching amortizes that loop across blocks —
-    # the dominant cost of many-block solves (d ≫ block). Transient memory
-    # per batched call: factor_batch · b² · 4B on top of the inverse cache.
-    # None = auto: 16 on accelerators; per-block (fused gram+factor) on CPU,
-    # where batched decompositions measured 2.3× SLOWER than independent
-    # per-block programs. An explicit int forces that chunk on any backend.
-    factor_batch: int | None = None
-    # Scan-fused BCD epochs: when feature blocks tile d exactly, the solver
-    # runs the whole factor phase + epoch loop as three XLA programs (stack,
-    # batched factor, scanned epochs) instead of one dispatch per (block,
-    # epoch): nb·epochs host dispatches become three, and XLA schedules
-    # the scan body's gemms back to back. What a dispatch costs on a TPU
-    # is not measured. None/True = on; False = force the legacy per-block
-    # loop.
-    fused_epochs: bool | None = None
     # Depth of the bounded host-side prefetch queue in front of the chunked
     # solvers and streamed pipeline application (loaders/stream.py
     # PrefetchIterator): the upstream producer — CSV parse, JPEG decode,

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
-from jax.scipy.linalg import cho_factor, cho_solve, solve_triangular
+from jax.scipy.linalg import solve_triangular
 from jax.sharding import Mesh, PartitionSpec as P
 
 from keystone_tpu.config import config
@@ -49,21 +49,11 @@ def _local_weighted(a_b, w_rows, weighted: bool):
     return a_b * w_rows[:, None] if weighted else a_b
 
 
-def _local_ridge_gram(a_b, aw, lam, precision, axis, width):
-    """Ridge gram AᵀA + λI for one block, reduced over the sharded rows in
-    the canonical width-independent fold (``sharded_rowsum`` — the
-    elastic-mesh bit-identity contract) — THE single source for the gram
-    expression across every shard_map body (fused, batched, uncached)."""
-    gram = sharded_rowsum(
-        lambda awb, ab: solver_matmul(awb.T, ab, precision),
-        axis, width, (aw, a_b),
-    )
-    b = a_b.shape[1]
-    return gram + lam * jnp.eye(b, dtype=gram.dtype)
-
-
 def _local_gram_inv(a_b, aw, lam, precision, axis, width):
-    """Explicit ridge resolvent (AᵀA + λI)⁻¹ for the block.
+    """Explicit ridge resolvent (AᵀA + λI)⁻¹ for the block, the gram
+    reduced over the sharded rows in the canonical width-independent fold
+    (``sharded_rowsum`` — the elastic-mesh bit-identity contract). ``lam``
+    is a scalar, or the block's (1, b) ridge diagonal (``_pad_ridge``).
 
     The inverse — not the Cholesky factor — is the cached quantity: XLA
     lowers triangular solves to a sequential substitution that dominates
@@ -73,9 +63,12 @@ def _local_gram_inv(a_b, aw, lam, precision, axis, width):
     the λ-regularized SPD gram keeps it well-conditioned, and later epochs
     re-solve against the residual, so per-epoch solve error self-corrects
     instead of accumulating."""
-    return _batched_spd_inv(
-        _local_ridge_gram(a_b, aw, lam, precision, axis, width)
+    gram = sharded_rowsum(
+        lambda awb, ab: solver_matmul(awb.T, ab, precision),
+        axis, width, (aw, a_b),
     )
+    b = a_b.shape[1]
+    return _batched_spd_inv(gram + lam * jnp.eye(b, dtype=gram.dtype))
 
 
 def _local_solve_update(a_b, aw, inv, r, w_b, precision, axis, width):
@@ -87,49 +80,6 @@ def _local_solve_update(a_b, aw, inv, r, w_b, precision, axis, width):
     w_b_new = solver_matmul(inv, rhs, precision)
     r_new = r_plus - solver_matmul(a_b, w_b_new, precision)
     return r_new, w_b_new
-
-
-@lru_cache(maxsize=None)
-def _gram_inv_fn(mesh: Mesh, axis: str, precision, weighted: bool,
-                 fold: int):
-    """Per-block gram + ridge inverse, computed once per block
-    (epoch-invariant)."""
-    width = mesh.shape[axis]
-
-    def local(a_b, lam, w_rows):
-        aw = _local_weighted(a_b, w_rows, weighted)
-        return _local_gram_inv(a_b, aw, lam, precision, axis, width)
-
-    sm = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(axis), P(), P(axis)),
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(sm)
-
-
-@lru_cache(maxsize=None)
-def _gram_only_fn(mesh: Mesh, axis: str, precision, weighted: bool,
-                  fold: int):
-    """Per-block ridge gram (no factorization) — the gemm half of
-    the factor phase. Kept per-block: block grams are already large MXU
-    gemms; it is only the FACTORIZATION that wants batching."""
-    width = mesh.shape[axis]
-
-    def local(a_b, lam, w_rows):
-        aw = _local_weighted(a_b, w_rows, weighted)
-        return _local_ridge_gram(a_b, aw, lam, precision, axis, width)
-
-    sm = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(axis), P(), P(axis)),
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(sm)
 
 
 # Widest triangle the blocked inverse hands to XLA's own triangular solve
@@ -211,22 +161,27 @@ def _batched_spd_inv(grams, leaf: int = _INV_LEAF):
     return _tri_gram(_tri_inv(jnp.linalg.cholesky(grams), leaf), leaf)
 
 
-@lru_cache(maxsize=None)
-def _batched_ridge_inv_fn(mesh: Mesh):
-    """One XLA program factorizing `factor_batch` stacked grams at once."""
-    # Donate the gram stack — dead once the inverses exist; caps the factor
-    # phase's transient at one stack instead of two.
-    return jax.jit(_batched_spd_inv, donate_argnums=_donate(mesh, 0))
+def _pad_ridge(lam, nb: int, b: int, pad: int):
+    """The ridge diagonals of ``nb`` stacked blocks whose last ends in
+    ``pad`` zero columns: λ on every real column and 1 on the pad, so the
+    padded gram stays positive definite at λ = 0 and, being block diagonal,
+    its inverse holds the real gram's inverse and the pad columns' weights
+    stay exactly 0. (nb, 1, b): times ``eye(b)`` it is each block's
+    diagonal, as the scalar λ is."""
+    return jnp.full((nb, 1, b), lam).at[-1, 0, b - pad:].set(1)
 
 
 @lru_cache(maxsize=None)
-def _stack_blocks_fn(mesh: Mesh, axis: str, nb: int):
-    """(rows, d) → (nb, rows, d/nb) stacked equal-size column blocks, in one
-    program. This is the fused path's analog of the a_blocks partition (same
-    one-extra-copy-of-A aggregate cost), laid out so a `lax.scan` can carry
-    the epoch loop over the leading block axis."""
+def _stack_blocks_fn(mesh: Mesh, axis: str, nb: int, pad: int = 0):
+    """(rows, d) → (nb, rows, (d + pad)/nb) stacked equal-size column
+    blocks, in one program: the one extra copy of A the solve makes, laid
+    out so a `lax.scan` can carry the epoch loop over the leading block
+    axis. ``pad`` zero columns fill a ragged last block in the same copy
+    (0, and the program without them, where the blocks tile d)."""
 
     def local(a):
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)))
         r, d = a.shape
         return jnp.moveaxis(a.reshape(r, nb, d // nb), 1, 0)
 
@@ -242,11 +197,12 @@ def _stack_blocks_fn(mesh: Mesh, axis: str, nb: int):
 
 @lru_cache(maxsize=None)
 def _fused_factor_fn(mesh: Mesh, axis: str, precision, weighted: bool,
-                     fold: int):
-    """All blocks' ridge inverses in ONE program: batched canonical-fold
-    grams (one big MXU batch-gemm per row block) into batched Cholesky +
-    triangular solves: one dispatch per chunk of blocks in place of one
-    per block."""
+                     fold: int, pad: int = 0):
+    """A chunk of blocks' ridge inverses in ONE program: batched
+    canonical-fold grams (one big MXU batch-gemm per row block) into
+    ``_batched_spd_inv``: one dispatch per chunk of blocks in place of one
+    per block. ``pad``: the chunk's last block ends in that many zero
+    columns (``_pad_ridge``)."""
     width = mesh.shape[axis]
 
     def local(a3, lam, w_rows):  # a3: (chunk, rows_shard, b)
@@ -258,6 +214,8 @@ def _fused_factor_fn(mesh: Mesh, axis: str, precision, weighted: bool,
             axis, width, (aw, a3), row_axes=(1, 1),
         )
         b = a3.shape[2]
+        if pad:
+            lam = _pad_ridge(lam, a3.shape[0], b, pad)
         return _batched_spd_inv(gram + lam * jnp.eye(b, dtype=gram.dtype))
 
     sm = shard_map(
@@ -273,31 +231,32 @@ def _fused_factor_fn(mesh: Mesh, axis: str, precision, weighted: bool,
 @lru_cache(maxsize=None)
 def _fused_epochs_fn(
     mesh: Mesh, axis: str, precision, weighted: bool, num_epochs: int,
-    cached: bool, fold: int,
+    cached: bool, fold: int, pad: int = 0,
 ):
     """The whole multi-epoch BCD sweep as ONE XLA program: scan over blocks
-    inside scan over epochs, per-shard under shard_map.
-
-    The legacy loop launches one program per (block, epoch), nb·epochs
-    host dispatches wrapped around skinny per-epoch gemms. Fused, the
-    solve is a single launch regardless of nb·epochs, XLA pipelines the
-    scan body's gemms back-to-back on the MXU, and the collective
-    schedule is fixed at compile time (also immune to the CPU in-process
-    rendezvous deadlock that forces the legacy loop to throttle). What
-    the dispatches cost on a TPU is not measured.
+    inside scan over epochs, per-shard under shard_map. The solve is a
+    single launch whatever nb·epochs, XLA pipelines the scan body's gemms
+    back-to-back on the MXU, and the collective schedule is fixed at
+    compile time (so the CPU's in-process rendezvous cannot deadlock on
+    it). What one dispatch a block visit would cost on a TPU is not
+    measured.
 
     ``cached=True`` consumes precomputed ridge inverses (xs carries them);
-    ``cached=False`` re-derives gram+Cholesky per block visit — the
-    single-epoch / factor-cache-disabled mode."""
+    ``cached=False`` forms gram and inverse again at every block visit: the
+    single-epoch mode, and the one where the inverses do not fit. Only the
+    uncached body reads ``pad``, the zero columns that end the last block
+    (``_pad_ridge``)."""
     width = mesh.shape[axis]
 
     def local(a3, invs, r, w3, lam, w_rows):
+        ridge = (_pad_ridge(lam, a3.shape[0], a3.shape[2], pad),) if pad else ()
+
         def block_step(rc, xs):
-            a_b, inv, w_b = xs
+            a_b, inv, w_b = xs[:3]
             aw = _local_weighted(a_b, w_rows, weighted)
             if not cached:
                 inv = _local_gram_inv(
-                    a_b, aw, lam, precision, axis, width
+                    a_b, aw, xs[3] if pad else lam, precision, axis, width
                 )
             r_new, w_new = _local_solve_update(
                 a_b, aw, inv, rc, w_b, precision, axis, width
@@ -306,7 +265,7 @@ def _fused_epochs_fn(
 
         def epoch_step(carry, _):
             rc, w3c = carry
-            rc, w3c = lax.scan(block_step, rc, (a3, invs, w3c))
+            rc, w3c = lax.scan(block_step, rc, (a3, invs, w3c) + ridge)
             return (rc, w3c), None
 
         (r, w3), _ = lax.scan(epoch_step, (r, w3), None, length=num_epochs)
@@ -372,119 +331,26 @@ def _first_epoch_update_fn(mesh: Mesh, axis: str, precision,
     return jax.jit(sm, donate_argnums=_donate(mesh, 1, 2))
 
 
-@lru_cache(maxsize=None)
-def _block_update_fn(mesh: Mesh, axis: str, precision, weighted: bool,
-                     fold: int):
-    """One BCD block update, jitted once per (mesh, shapes) and reused for
-    every block and epoch — the hot loop of the whole framework."""
-    width = mesh.shape[axis]
-
-    def local(a_b, r, w_b, lam, w_rows):
-        # r is the current residual B - A W (row-sharded).
-        r_plus = r + solver_matmul(a_b, w_b, precision)
-        if weighted:
-            aw = a_b * w_rows[:, None]
-        else:
-            aw = a_b
-        gram, rhs = sharded_rowsum(
-            lambda awb, ab, rb: (
-                solver_matmul(awb.T, ab, precision),
-                solver_matmul(awb.T, rb, precision),
-            ),
-            axis, width, (aw, a_b, r_plus),
-        )
-        b = a_b.shape[1]
-        c, low = cho_factor(gram + lam * jnp.eye(b, dtype=gram.dtype))
-        w_b_new = cho_solve((c, low), rhs)
-        r_new = r_plus - solver_matmul(a_b, w_b_new, precision)
-        return r_new, w_b_new
-
-    sm = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis), P(), P(), P(axis)),
-        out_specs=(P(axis), P()),
-        check_vma=False,
-    )
-    return jax.jit(sm, donate_argnums=_donate(mesh, 1, 2))
-
-
 def _factor_chunk(block_size: Optional[int] = None) -> int:
-    """Blocks factorized per batched XLA program — THE single chunk policy
-    for both the legacy and fused factor phases. Auto: batching amortizes
-    TPU's sequential factorization lowering, but measured 2.3× slower than
+    """Blocks factorized per batched XLA program. Batching amortizes TPU's
+    sequential factorization lowering, but measured 2.3× slower than
     independent per-block programs on the CPU backend — there, per-block.
-    An explicit config.factor_batch forces that chunk on any backend.
 
-    The auto chunk is additionally MEMORY-capped: the factor program holds
+    The chunk is additionally MEMORY-capped: the factor program holds
     a handful of (chunk, b, b) temps (the gram, its Cholesky factor, the
     halves of the blocked inverse and product), so an uncapped chunk·b²
     OOMs HBM at large blocks. Capping chunk·b² at 128M f32 elements
     (512 MB per temp) keeps the factor transient under 2 GiB: b=8192 gets
-    chunk 2 (128M // 8192² = 2) and b=4096 chunk 8, whose fused factor
+    chunk 2 (128M // 8192² = 2) and b=4096 chunk 8, whose factor
     programs hold 1.76 and 1.57 GiB of temps by the deviceless v5e compile
     (2.79 and 2.60 before the inverse was blocked: PERF.md section 6,
     PR 29); b≤2896 keeps the full batch of 16."""
-    if config.factor_batch is not None:
-        return max(1, int(config.factor_batch))
     if jax.default_backend() == "cpu":
         return 1
     chunk = 16
     if block_size:
         chunk = min(chunk, max(1, (128 << 20) // (block_size * block_size)))
     return chunk
-
-
-def _factor_blocks(
-    a_blocks, blocks, lam_arr, w_rows, mesh, axis, weighted, throttle
-) -> list:
-    """Gram ridge inverses for every block, factorized in batched chunks.
-
-    Grams stay per-block (each is one large psum'd MXU gemm); the
-    Cholesky + triangular solves — TPU's sequentially-lowered tail — run
-    batched over up to ``config.factor_batch`` equal-size blocks per XLA
-    program. A ragged final block (d % block_size != 0) keeps the fused
-    per-block path. Transient memory per chunk: chunk · b² in accum dtype,
-    donated into the inverse stack."""
-    precision = _precision()
-    n_eq = len(blocks)
-    if n_eq > 1 and blocks[-1][1] - blocks[-1][0] != blocks[0][1] - blocks[0][0]:
-        n_eq -= 1  # ragged tail handled per-block below
-    chunk = _factor_chunk(blocks[0][1] - blocks[0][0])
-    invs: list = []
-    # A singleton final chunk would pay a fresh (1,b,b) batched compile and
-    # lose gram/factor fusion; leave it to the fused per-block path below.
-    if n_eq % chunk == 1:
-        n_eq -= 1
-    if n_eq > 1 and chunk > 1:
-        gram_only = _gram_only_fn(
-            mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
-        )
-        batched_inv = _batched_ridge_inv_fn(mesh)
-        for c0 in range(0, n_eq, chunk):
-            part = a_blocks[c0 : min(c0 + chunk, n_eq)]
-            grams = []
-            for a_b in part:
-                g = gram_only(a_b, lam_arr, w_rows)
-                if throttle:
-                    # Independent collective programs in an un-serialized
-                    # burst deadlock the CPU in-process rendezvous.
-                    g.block_until_ready()
-                grams.append(g)
-            stacked = batched_inv(jnp.stack(grams, axis=0))
-            if throttle:
-                stacked.block_until_ready()
-            # Unstacked views keep the epoch-loop interface unchanged.
-            invs.extend(stacked[i] for i in range(stacked.shape[0]))
-    gram_inv = _gram_inv_fn(
-        mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
-    )
-    for a_b in a_blocks[len(invs) :]:
-        c = gram_inv(a_b, lam_arr, w_rows)
-        if throttle:
-            c.block_until_ready()
-        invs.append(c)
-    return invs
 
 
 def block_coordinate_descent(
@@ -501,7 +367,9 @@ def block_coordinate_descent(
 
     Returns (per-block weight matrices, block column ranges). The caller
     (BlockLinearMapper) keeps the blocks — applying block-by-block is how
-    the reference streams 256k-dim models through memory.
+    the reference streams 256k-dim models through memory. Every shape runs
+    the one body (``_solve_fused``): a ``block_size`` of d or more is one
+    block d wide, and a ragged last block comes back at its true width.
 
     With ``checkpoint_dir``, solver state (W blocks + residual) is written
     after every epoch via orbax and the solve resumes from the latest epoch
@@ -543,13 +411,11 @@ def block_coordinate_descent(
 
     if cache_grams is None:
         itemsize = jnp.dtype(cdtype).itemsize
-        factor_bytes = sum((e - s) ** 2 for s, e in blocks) * itemsize
+        width = blocks[0][1] - blocks[0][0]  # a ragged last block is padded
+        factor_bytes = len(blocks) * width * width * itemsize
         from keystone_tpu.utils.metrics import device_hbm_bytes
 
         cache_grams = num_iters > 1 and factor_bytes < device_hbm_bytes() // 4
-    update = _block_update_fn(
-        mesh, axis, _precision(), weighted, fold_blocks(mesh.shape[axis])
-    )
     lam_arr = jnp.asarray(lam, dtype=cdtype)
 
     W = [jnp.zeros((e - s, k), dtype=cdtype) for s, e in blocks]
@@ -568,72 +434,26 @@ def block_coordinate_descent(
     start_epoch, W, R = _resume_or_default(
         checkpoint_dir, fingerprint, W, R, sharding
     )
-    # Slice each column block once, not once per epoch: the blocks partition
-    # A (one extra A-sized copy in aggregate) and every epoch then reads them
-    # without re-materializing slices in the hot loop. When feature blocks
-    # stop fitting in HBM the estimator layer streams them from host instead.
-    # The CPU-emulated mesh's in-process all-reduce rendezvous can deadlock
-    # when many small collective programs are in flight concurrently (7/8
-    # threads arrive -> 40s timeout -> abort). Throttle dispatch per epoch
-    # on CPU only; TPU keeps full async pipelining.
-    throttle = jax.default_backend() == "cpu"
-
-    # Fused scan path: when the blocks tile d exactly, the entire solve —
-    # factor phase and every (block, epoch) update — runs in three XLA
-    # programs instead of one program per block visit (see
-    # _fused_epochs_fn).
-    # A ragged tail block (d % block_size != 0) keeps the legacy loop.
-    if (
-        config.fused_epochs is not False
-        and d % block_size == 0
-        and start_epoch < num_iters
-    ):
-        return _solve_fused(
-            A, blocks, lam_arr, w_rows, W, R, num_iters, start_epoch,
-            cache_grams, weighted, checkpoint_dir, fingerprint, mesh, axis,
-            throttle,
-        )
-
-    a_blocks = [lax.slice_in_dim(A.data, s, e, axis=1) for s, e in blocks]
-    if cache_grams and start_epoch < num_iters:
-        cached_update = _cached_block_update_fn(
-            mesh, axis, _precision(), weighted,
-            fold_blocks(mesh.shape[axis]),
-        )
-        invs = _factor_blocks(
-            a_blocks, blocks, lam_arr, w_rows, mesh, axis, weighted, throttle
-        )
-        for epoch in range(start_epoch, num_iters):
-            for i in range(len(blocks)):
-                R, W[i] = cached_update(
-                    a_blocks[i], invs[i], R, W[i], w_rows
-                )
-            if throttle:
-                R.block_until_ready()
-            if checkpoint_dir is not None:
-                _save_epoch(checkpoint_dir, epoch + 1, W, R, fingerprint)
-        if checkpoint_dir is not None:
-            wait_for_checkpoints(checkpoint_dir)
+    if start_epoch >= num_iters:
         return W, blocks
-    for epoch in range(start_epoch, num_iters):
-        for i in range(len(blocks)):
-            R, W[i] = update(a_blocks[i], R, W[i], lam_arr, w_rows)
-        if throttle:
-            R.block_until_ready()
-        if checkpoint_dir is not None:
-            _save_epoch(checkpoint_dir, epoch + 1, W, R, fingerprint)
-    if checkpoint_dir is not None:
-        wait_for_checkpoints(checkpoint_dir)
-    return W, blocks
+    return _solve_fused(
+        A, blocks, lam_arr, w_rows, W, R, num_iters, start_epoch,
+        cache_grams, weighted, checkpoint_dir, fingerprint, mesh, axis,
+    )
 
 
 def _solve_fused(
     A, blocks, lam_arr, w_rows, W, R, num_iters, start_epoch, cache_grams,
-    weighted, checkpoint_dir, fingerprint, mesh, axis, throttle,
+    weighted, checkpoint_dir, fingerprint, mesh, axis,
 ):
-    """The scan-fused solve body: stacked blocks → (optional) one batched
-    factor program → one epochs program (or one per epoch when
-    checkpointing). Returns the same (W blocks, ranges) as the legacy loop."""
+    """The solve body for a feature matrix in HBM: one stacked copy of A's
+    column blocks → with ``cache_grams`` the blocks' ridge inverses, a chunk
+    of blocks a program → one epochs program (one an epoch under
+    ``checkpoint_dir``). Three programs where the blocks tile d. A single
+    block is as wide as d. A ragged last block is padded with zero columns
+    to the blocks' width inside the stacking program, with 1 for λ on the
+    pad's diagonal (``_pad_ridge``); W is trimmed to the true widths before
+    it is saved or returned, so checkpoints and the result never see the pad."""
     from keystone_tpu.utils.metrics import active_tracer, span_of
 
     # The three spans time the host: tracing and dispatching each phase.
@@ -641,31 +461,47 @@ def _solve_fused(
     # trace each names the gap in front of its program.
     tracer = active_tracer()  # resolved once per solve
     precision = _precision()
+    fold = fold_blocks(mesh.shape[axis])
     nb = len(blocks)
+    b = blocks[0][1] - blocks[0][0]
+    pad = nb * b - blocks[-1][1]
+
+    def true_widths(W3):
+        W = [W3[i] for i in range(nb)]
+        if pad:
+            W[-1] = W[-1][: b - pad]
+        return W
+
     with span_of(tracer, "solver.stack", "solver", blocks=nb):
-        a3 = _stack_blocks_fn(mesh, axis, nb)(A.data)
+        a3 = _stack_blocks_fn(mesh, axis, nb, pad)(A.data)
     if cache_grams:
-        # Chunked like _factor_blocks (shared _factor_chunk policy): bounds
-        # the factor transient to chunk·b² buffers instead of nb·b².
-        b = blocks[0][1] - blocks[0][0]
+        # Chunked: bounds the factor transient to chunk·b² buffers instead
+        # of nb·b².
         chunk = _factor_chunk(b)
         # leaf and levels say whether the blocked inverse engaged: 0 levels
         # is one leaf, the unblocked path.
         with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk,
                      leaf=_INV_LEAF, levels=_inv_levels(b)):
-            factor = _fused_factor_fn(
-                mesh, axis, precision, weighted, fold_blocks(mesh.shape[axis])
-            )
             if chunk >= nb:
-                invs = factor(a3, lam_arr, w_rows)
+                invs = _fused_factor_fn(
+                    mesh, axis, precision, weighted, fold, pad
+                )(a3, lam_arr, w_rows)
             else:
+                # The CPU-emulated mesh's in-process all-reduce rendezvous
+                # can deadlock when independent collective programs are in
+                # flight together (7/8 threads arrive -> 40s timeout ->
+                # abort): wait for each there. The TPU keeps full async
+                # pipelining.
+                throttle = jax.default_backend() == "cpu"
                 parts = []
                 for c0 in range(0, nb, chunk):
+                    # Only the last chunk holds the padded block.
+                    factor = _fused_factor_fn(
+                        mesh, axis, precision, weighted, fold,
+                        pad if c0 + chunk >= nb else 0,
+                    )
                     part = factor(a3[c0 : c0 + chunk], lam_arr, w_rows)
                     if throttle:
-                        # An unserialized burst of independent collective
-                        # programs deadlocks the CPU in-process rendezvous
-                        # (same guard as _factor_blocks).
                         part.block_until_ready()
                     parts.append(part)
                 invs = jnp.concatenate(parts, axis=0)
@@ -675,26 +511,28 @@ def _solve_fused(
         invs = jnp.zeros((nb, 1, 1), dtype=R.dtype)
     with span_of(tracer, "solver.epochs", "solver", blocks=nb,
                  epochs=num_iters - start_epoch):
+        if pad:
+            W = W[:-1] + [jnp.pad(W[-1], ((0, pad), (0, 0)))]
         W3 = jnp.stack(W)
+        # One program for all the epochs, or one an epoch to save after
+        # each. The cached body never forms a gram: it is the program
+        # without pad.
+        step = _fused_epochs_fn(
+            mesh, axis, precision, weighted,
+            num_iters - start_epoch if checkpoint_dir is None else 1,
+            cache_grams, fold, 0 if cache_grams else pad,
+        )
         if checkpoint_dir is None:
-            step = _fused_epochs_fn(
-                mesh, axis, precision, weighted, num_iters - start_epoch,
-                cache_grams, fold_blocks(mesh.shape[axis]),
-            )
             R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
         else:
-            step = _fused_epochs_fn(
-                mesh, axis, precision, weighted, 1, cache_grams,
-                fold_blocks(mesh.shape[axis]),
-            )
             for epoch in range(start_epoch, num_iters):
                 R, W3 = step(a3, invs, R, W3, lam_arr, w_rows)
                 _save_epoch(
-                    checkpoint_dir, epoch + 1,
-                    [W3[i] for i in range(nb)], R, fingerprint,
+                    checkpoint_dir, epoch + 1, true_widths(W3), R,
+                    fingerprint,
                 )
             wait_for_checkpoints(checkpoint_dir)
-    return [W3[i] for i in range(nb)], blocks
+    return true_widths(W3), blocks
 
 
 def _make_fingerprint(

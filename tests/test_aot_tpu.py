@@ -95,23 +95,31 @@ def _compiled_ok(compiled) -> bool:
     return True
 
 
-def test_bcd_block_update_compiles_for_v5e(mesh):
-    from keystone_tpu.linalg.bcd import _block_update_fn
+@pytest.mark.parametrize("pad", [0, 40], ids=["tiled", "padded-tail"])
+def test_uncached_epochs_program_compiles_for_v5e(mesh, pad):
+    """The scan body that forms gram and inverse at every block visit (the
+    single-epoch solve), with and without a padded last block."""
+    from keystone_tpu.linalg.bcd import _fused_epochs_fn
     from keystone_tpu.linalg.row_matrix import _precision
 
-    fn = _block_update_fn(mesh, AXIS, _precision(), False, _fold(mesh))
-    n, b, k = 1024, 128, 16
+    fn = _fused_epochs_fn(
+        mesh, AXIS, _precision(), False, 1, False, _fold(mesh), pad
+    )
+    n, b, k, nb = 1024, 128, 16, 4
     args = (
-        _sds((n, b), mesh, P(AXIS)),  # a_b
+        _sds((nb, n, b), mesh, P(None, AXIS)),  # a3
+        _sds((nb, 1, 1), mesh, P()),  # the uncached body's placeholder
         _sds((n, k), mesh, P(AXIS)),  # r
-        _sds((b, k), mesh, P()),  # w_b
+        _sds((nb, b, k), mesh, P()),  # w3
         _sds((), mesh, P()),  # lam
         _sds((n,), mesh, P(AXIS)),  # w_rows
     )
     compiled = fn.lower(*args).compile()
     assert _compiled_ok(compiled)
+    text = compiled.as_text()
+    assert "Cholesky" in text and "while" in text
     # The gram reduction must be present as a TPU collective.
-    assert _reduces_across_devices(compiled.as_text())
+    assert _reduces_across_devices(text)
 
 
 @pytest.mark.parametrize(
@@ -158,32 +166,47 @@ def test_bcd_streamed_first_and_cached_updates_compile_for_v5e(
     assert _compiled_ok(c2)
 
 
-def test_batched_factor_phase_compiles_for_v5e(mesh):
-    """The batched factor phase (gram-only + batched Cholesky/trsm over a
-    leading block axis) must XLA:TPU-compile — it is the accelerator
-    default for multi-block cached solves."""
-    from keystone_tpu.linalg.bcd import _batched_ridge_inv_fn, _gram_only_fn
+def test_padded_tail_stack_and_factor_compile_for_v5e(mesh):
+    """A ragged last block: the stacking program pads it with zero columns
+    and the factor program of the chunk that holds it puts 1 on the pad's
+    diagonal; both must XLA:TPU-compile."""
+    from keystone_tpu.linalg.bcd import _fused_factor_fn, _stack_blocks_fn
     from keystone_tpu.linalg.row_matrix import _precision
 
-    n, b, g = 1024, 128, 16
-    gram_only = _gram_only_fn(mesh, AXIS, _precision(), False, _fold(mesh))
-    c1 = gram_only.lower(
-        _sds((n, b), mesh, P(AXIS)),
+    n, b, nb, pad = 1024, 128, 16, 40
+    stack = _stack_blocks_fn(mesh, AXIS, nb, pad)
+    c0 = stack.lower(_sds((n, nb * b - pad), mesh, P(AXIS))).compile()
+    assert _compiled_ok(c0)
+    factor = _fused_factor_fn(
+        mesh, AXIS, _precision(), False, _fold(mesh), pad
+    )
+    c1 = factor.lower(
+        _sds((nb, n, b), mesh, P(None, AXIS)),
         _sds((), mesh, P()),
         _sds((n,), mesh, P(AXIS)),
     ).compile()
-    assert _compiled_ok(c1)
-    batched = _batched_ridge_inv_fn(mesh)
-    c2 = batched.lower(_sds((g, b, b), mesh, P())).compile()
-    assert _compiled_ok(c2)
-    # Above the leaf the inverse is blocked: 1152 = 640 + 512, halves
-    # that differ and of which one is no power of two.
-    from keystone_tpu.linalg.bcd import _inv_levels
+    assert _reduces_across_devices(c1.as_text())
 
-    assert _inv_levels(1152) == 1
-    c3 = batched.lower(_sds((2, 1152, 1152), mesh, P())).compile()
-    assert _compiled_ok(c3)
-    assert "Cholesky" in c3.as_text()  # factor_ms tells the phase by it
+
+def test_padded_factor_compiles_above_the_leaf_for_v5e(mesh):
+    """Above the leaf the inverse is blocked: 1088 = 512 + 576, halves that
+    differ and of which one is no power of two. A last chunk that holds the
+    padded block alone."""
+    from keystone_tpu.linalg.bcd import _fused_factor_fn, _inv_levels
+    from keystone_tpu.linalg.row_matrix import _precision
+
+    n, b, pad = 1024, 1088, 40
+    assert _inv_levels(b) == 1
+    factor = _fused_factor_fn(
+        mesh, AXIS, _precision(), False, _fold(mesh), pad
+    )
+    compiled = factor.lower(
+        _sds((1, n, b), mesh, P(None, AXIS)),
+        _sds((), mesh, P()),
+        _sds((n,), mesh, P(AXIS)),
+    ).compile()
+    assert _compiled_ok(compiled)
+    assert "Cholesky" in compiled.as_text()  # factor_ms tells the phase by it
 
 
 def test_ring_bcd_step_compiles_for_v5e(mesh):
@@ -302,8 +325,8 @@ def test_convolver_compiles_for_v5e(mesh):
 
 
 def test_fused_solver_programs_compile_for_v5e(mesh):
-    """The r4 scan-fused solve (stack → batched factor → scanned epochs)
-    — the three programs the bench now times — must XLA:TPU-compile."""
+    """The in-HBM solve (stack → batched factor → scanned epochs): its
+    three programs must XLA:TPU-compile."""
     from keystone_tpu.linalg.bcd import (
         _fused_epochs_fn,
         _fused_factor_fn,

@@ -32,6 +32,36 @@ def _ridge_oracle(A, B, lam):
     )
 
 
+def _bcd_oracle(A, B, block_size, num_iters, lam, w=None):
+    """Plain block coordinate descent in float64: blocks in order, one
+    ``np.linalg.solve`` a visit, optional row weights. The reference the
+    solver's tests compare against; it shares nothing with ``bcd.py``."""
+    A, B = A.astype(np.float64), B.astype(np.float64)
+    d = A.shape[1]
+    Aw = A if w is None else A * w.astype(np.float64)[:, None]
+    blocks = [(s, min(s + block_size, d)) for s in range(0, d, block_size)]
+    W, R = np.zeros((d, B.shape[1])), B.copy()
+    for _ in range(num_iters):
+        for s, e in blocks:
+            R += A[:, s:e] @ W[s:e]
+            gram = Aw[:, s:e].T @ A[:, s:e] + lam * np.eye(e - s)
+            W[s:e] = np.linalg.solve(gram, Aw[:, s:e].T @ R)
+            R -= A[:, s:e] @ W[s:e]
+    return W, blocks
+
+
+def _assert_matches_oracle(W_blocks, blocks, A, B, block_size, num_iters, lam,
+                           w=None):
+    """Weights, block ranges and block widths against ``_bcd_oracle``: the
+    widths are the true ones, so no pad column reaches the caller."""
+    W_oracle, blocks_oracle = _bcd_oracle(A, B, block_size, num_iters, lam, w)
+    assert blocks == blocks_oracle
+    assert [w_b.shape[0] for w_b in W_blocks] == [e - s for s, e in blocks]
+    np.testing.assert_allclose(
+        assemble_blocks(W_blocks), W_oracle, rtol=1e-4, atol=1e-4
+    )
+
+
 def test_from_array_pads_and_collects(rng):
     A = rng.normal(size=(13, 4)).astype(np.float32)
     M = RowMatrix.from_array(A)
@@ -179,27 +209,21 @@ def test_bcd_cached_grams_matches_uncached(rng):
     )
 
 
-def test_bcd_batched_factor_ragged_and_chunked(rng):
-    """Batched factor phase: ragged tail block + factor_batch smaller than
-    the block count must still match the uncached solve digit-for-digit."""
-    from keystone_tpu.config import config
+@pytest.mark.parametrize("chunk", [2, 3])
+def test_bcd_batched_factor_ragged_and_chunked(rng, monkeypatch, chunk):
+    """A ragged last block under a factor chunk smaller than the block count
+    (what the chip runs; the CPU's own chunk is 1): 26 columns in blocks of
+    8 are 3 whole blocks and a padded one of 2, which chunk 2 factors beside
+    a whole block and chunk 3 alone."""
+    from keystone_tpu.linalg import bcd
 
-    A, B, _ = _problem(rng, d=26)  # blocks of 8 -> 3 equal + ragged 2-wide
-    Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
-    old = config.factor_batch
-    config.factor_batch = 2  # forces two batched chunks + tail path
-    try:
-        W_c, _ = block_coordinate_descent(
-            Ma, Mb, block_size=8, num_iters=4, lam=0.2, cache_grams=True
-        )
-    finally:
-        config.factor_batch = old
-    W_p, _ = block_coordinate_descent(
-        Ma, Mb, block_size=8, num_iters=4, lam=0.2, cache_grams=False
+    A, B, _ = _problem(rng, d=26)
+    monkeypatch.setattr(bcd, "_factor_chunk", lambda b: chunk)
+    W_c, blocks = block_coordinate_descent(
+        RowMatrix.from_array(A), RowMatrix.from_array(B),
+        block_size=8, num_iters=4, lam=0.2, cache_grams=True,
     )
-    np.testing.assert_allclose(
-        assemble_blocks(W_c), assemble_blocks(W_p), rtol=1e-4, atol=1e-4
-    )
+    _assert_matches_oracle(W_c, blocks, A, B, 8, 4, 0.2)
 
 
 @pytest.mark.parametrize(
@@ -442,127 +466,187 @@ def test_gram_and_atb_fused(rng):
     np.testing.assert_allclose(ab, A.T @ B, rtol=1e-5, atol=1e-4)
 
 
-# -- fused scan path vs legacy per-block loop --------------------------------
+# -- the in-HBM solve body against the numpy oracle --------------------------
 
 
-def _both_paths(rng, **kwargs):
-    from keystone_tpu.config import config
-
+@pytest.mark.parametrize(
+    "kwargs,weighted",
+    [
+        (dict(block_size=8, num_iters=4, lam=0.15, cache_grams=True), False),
+        (dict(block_size=16, num_iters=2, lam=0.3, cache_grams=False), False),
+        (dict(block_size=8, num_iters=3, lam=0.2), True),
+    ],
+    ids=["cached", "uncached", "weighted"],
+)
+def test_solve_matches_oracle(rng, kwargs, weighted):
     A, B, _ = _problem(rng, n=240, d=32)
-    Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
-    prior = config.fused_epochs  # restore whatever the caller had set
-    try:
-        config.fused_epochs = None  # auto: fused (blocks tile d)
-        W_f, blocks = block_coordinate_descent(Ma, Mb, **kwargs)
-        config.fused_epochs = False
-        W_l, _ = block_coordinate_descent(Ma, Mb, **kwargs)
-    finally:
-        config.fused_epochs = prior
-    return A, B, W_f, W_l, blocks
-
-
-def test_fused_matches_legacy_cached(rng):
-    A, B, W_f, W_l, blocks = _both_paths(
-        rng, block_size=8, num_iters=4, lam=0.15, cache_grams=True
+    w = (1.0 + rng.uniform(size=(240,))).astype(np.float32) if weighted else None
+    W, blocks = block_coordinate_descent(
+        RowMatrix.from_array(A), RowMatrix.from_array(B), row_weights=w,
+        **kwargs,
     )
-    assert len(blocks) == 4
-    np.testing.assert_allclose(
-        assemble_blocks(W_f), assemble_blocks(W_l), rtol=1e-4, atol=1e-4
-    )
-    # And both agree with the direct ridge oracle after enough epochs.
-    W_oracle = _ridge_oracle(A, B, 0.15)
-    np.testing.assert_allclose(
-        assemble_blocks(W_f), W_oracle, rtol=5e-2, atol=5e-2
+    _assert_matches_oracle(
+        W, blocks, A, B, kwargs["block_size"], kwargs["num_iters"],
+        kwargs["lam"], w,
     )
 
 
-def test_fused_matches_legacy_uncached(rng):
-    _, _, W_f, W_l, _ = _both_paths(
-        rng, block_size=16, num_iters=2, lam=0.3, cache_grams=False
+@pytest.mark.parametrize("lam", [0.0, 0.1])
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
+@pytest.mark.parametrize("cache_grams", [True, False], ids=["cached", "uncached"])
+@pytest.mark.parametrize(
+    "d,block_size,widths",
+    [
+        (24, 128, [24]),  # one block wider than d: it is d wide, no pad
+        (20, 12, [12, 8]),  # a ragged tail, padded to 12 inside the solve
+        (26, 8, [8, 8, 8, 2]),
+    ],
+)
+def test_single_block_and_ragged_tail_run_the_one_body(
+    rng, d, block_size, widths, cache_grams, weighted, lam
+):
+    """A block wider than d and a ragged last block run the body every
+    other shape runs, λ = 0 included (the pad's diagonal is 1, not λ), and
+    the pad columns never reach the returned W."""
+    A, B, _ = _problem(rng, n=120, d=d)
+    w = rng.uniform(0.5, 2.0, size=120).astype(np.float32) if weighted else None
+    W, blocks = block_coordinate_descent(
+        RowMatrix.from_array(A), RowMatrix.from_array(B),
+        block_size=block_size, num_iters=3, lam=lam, row_weights=w,
+        cache_grams=cache_grams,
     )
-    np.testing.assert_allclose(
-        assemble_blocks(W_f), assemble_blocks(W_l), rtol=1e-4, atol=1e-4
-    )
+    assert [w_b.shape[0] for w_b in W] == widths
+    _assert_matches_oracle(W, blocks, A, B, block_size, 3, lam, w)
 
 
-def test_fused_matches_legacy_weighted(rng):
+@pytest.mark.parametrize("cached", [True, False], ids=["cached", "uncached"])
+def test_pad_columns_keep_zero_weights(rng, cached):
+    """Inside the solve the padded block's pad rows of W stay exactly 0 at
+    λ = 0: the padded gram is block diagonal with 1 on the pad's diagonal,
+    and so is its inverse."""
+    import jax.numpy as jnp
+
     from keystone_tpu.config import config
+    from keystone_tpu.linalg import bcd
 
-    A, B, _ = _problem(rng, n=160, d=16)
-    w = (1.0 + rng.uniform(size=(160,))).astype(np.float32)
-    Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
-    kwargs = dict(block_size=8, num_iters=3, lam=0.2, row_weights=w)
-    W_f, _ = block_coordinate_descent(Ma, Mb, **kwargs)
-    config.fused_epochs = False
-    try:
-        W_l, _ = block_coordinate_descent(Ma, Mb, **kwargs)
-    finally:
-        config.fused_epochs = None
-    np.testing.assert_allclose(
-        assemble_blocks(W_f), assemble_blocks(W_l), rtol=1e-4, atol=1e-4
-    )
-
-
-def test_fused_single_block_and_ragged_fallback(rng):
-    # nb=1 exercises the scan's degenerate length; ragged d falls back to
-    # the legacy loop (same answer either way).
     A, B, _ = _problem(rng, n=120, d=20)
     Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
-    W1, blocks1 = block_coordinate_descent(
-        Ma, Mb, block_size=20, num_iters=2, lam=0.1
-    )
-    assert len(blocks1) == 1
-    W2, blocks2 = block_coordinate_descent(
-        Ma, Mb, block_size=12, num_iters=6, lam=0.1  # ragged: 12 + 8
-    )
-    assert [e - s for s, e in blocks2] == [12, 8]
-    W_oracle = _ridge_oracle(A, B, 0.1)
+    mesh, axis = Ma.mesh, config.data_axis
+    nb, b, pad, k = 2, 12, 4, B.shape[1]
+    precision, fold = bcd._precision(), bcd.fold_blocks(mesh.shape[axis])
+    lam = jnp.zeros((), jnp.float32)
+    w_rows = jnp.zeros((Ma.padded_rows,), jnp.float32)
+    a3 = bcd._stack_blocks_fn(mesh, axis, nb, pad)(Ma.data)
+    assert a3.shape == (nb, Ma.padded_rows, b)
+    np.testing.assert_array_equal(np.asarray(a3[1, :, b - pad:]), 0.0)
+    if cached:
+        invs = bcd._fused_factor_fn(mesh, axis, precision, False, fold, pad)(
+            a3, lam, w_rows)
+        np.testing.assert_array_equal(
+            np.asarray(invs[1, b - pad:, b - pad:]), np.eye(pad))
+        np.testing.assert_array_equal(np.asarray(invs[1, : b - pad, b - pad:]), 0.0)
+    else:
+        invs = jnp.zeros((nb, 1, 1), jnp.float32)
+    step = bcd._fused_epochs_fn(
+        mesh, axis, precision, False, 3, cached, fold, 0 if cached else pad)
+    _, W3 = step(a3, invs, jnp.array(Mb.data), jnp.zeros((nb, b, k)), lam, w_rows)
+    np.testing.assert_array_equal(np.asarray(W3[1, b - pad:]), 0.0)
+    W_oracle, _ = _bcd_oracle(A, B, b, 3, 0.0)
     np.testing.assert_allclose(
-        assemble_blocks(W2), W_oracle, rtol=5e-2, atol=5e-2
-    )
+        np.asarray(W3).reshape(nb * b, k)[:20], W_oracle, rtol=1e-4, atol=1e-4)
 
 
-def test_fused_checkpoint_resume_across_paths(rng, tmp_path):
-    """A fused solve checkpoints per epoch with the same fingerprint as the
-    legacy loop: 2 epochs fused + resume to 4 == 4 epochs straight (legacy),
-    in either direction."""
-    from keystone_tpu.config import config
+@pytest.mark.parametrize(
+    "d,block_size,widths", [(16, 8, [8, 8]), (20, 12, [12, 8])],
+    ids=["tiled", "ragged"],
+)
+def test_checkpoint_resume(rng, tmp_path, d, block_size, widths):
+    """2 epochs under ``checkpoint_dir`` + resume to 4 == 4 epochs straight
+    == the oracle's 4; the checkpoint holds every block at its true width
+    (what a solve before the padding wrote, and resumes from); a solve
+    resumed at its last epoch returns what it restored."""
+    import orbax.checkpoint as ocp
 
-    A, B, _ = _problem(rng, n=120, d=16)
+    A, B, _ = _problem(rng, n=120, d=d)
     Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
-    kwargs = dict(block_size=8, lam=0.1)
+    kwargs = dict(block_size=block_size, lam=0.1)
     W_ref, _ = block_coordinate_descent(Ma, Mb, num_iters=4, **kwargs)
 
     ck = str(tmp_path / "ck")
-    block_coordinate_descent(
-        Ma, Mb, num_iters=2, checkpoint_dir=ck, **kwargs
+    block_coordinate_descent(Ma, Mb, num_iters=2, checkpoint_dir=ck, **kwargs)
+    saved = ocp.PyTreeCheckpointer().restore(str(tmp_path / "ck" / "epoch_2"))
+    assert [w_b.shape for w_b in saved["W"]] == [(n, B.shape[1]) for n in widths]
+    W_res, blocks = block_coordinate_descent(
+        Ma, Mb, num_iters=4, checkpoint_dir=ck, **kwargs
     )
-    config.fused_epochs = False  # resume the fused checkpoint on the legacy path
-    try:
-        W_res, _ = block_coordinate_descent(
-            Ma, Mb, num_iters=4, checkpoint_dir=ck, **kwargs
-        )
-    finally:
-        config.fused_epochs = None
     np.testing.assert_allclose(
         assemble_blocks(W_res), assemble_blocks(W_ref), rtol=1e-4, atol=1e-4
     )
+    _assert_matches_oracle(W_res, blocks, A, B, block_size, 4, 0.1)
+    W_again, _ = block_coordinate_descent(
+        Ma, Mb, num_iters=4, checkpoint_dir=ck, **kwargs
+    )
+    np.testing.assert_array_equal(
+        assemble_blocks(W_again), assemble_blocks(W_res)
+    )
 
 
-def test_fused_factor_chunking_matches_whole_batch(rng):
-    """config.factor_batch bounds the fused factor phase's transient (and
-    forces per-block factorization on request) without changing results."""
-    from keystone_tpu.config import config
+def test_factor_chunking_matches_whole_batch(rng, monkeypatch):
+    """The factor chunk bounds the factor phase's transient without
+    changing results: four blocks in one program, and in two programs of
+    two whose inverses are concatenated."""
+    from keystone_tpu.linalg import bcd
 
     A, B, _ = _problem(rng, n=200, d=32)
     Ma, Mb = RowMatrix.from_array(A), RowMatrix.from_array(B)
     kwargs = dict(block_size=8, num_iters=3, lam=0.2, cache_grams=True)
-    W_whole, _ = block_coordinate_descent(Ma, Mb, **kwargs)  # auto chunk
-    config.factor_batch = 2  # 4 blocks → two chunked factor programs
-    try:
-        W_chunk, _ = block_coordinate_descent(Ma, Mb, **kwargs)
-    finally:
-        config.factor_batch = None
+    monkeypatch.setattr(bcd, "_factor_chunk", lambda b: 16)
+    W_whole, blocks = block_coordinate_descent(Ma, Mb, **kwargs)
+    monkeypatch.setattr(bcd, "_factor_chunk", lambda b: 2)
+    W_chunk, _ = block_coordinate_descent(Ma, Mb, **kwargs)
     np.testing.assert_allclose(
         assemble_blocks(W_whole), assemble_blocks(W_chunk), rtol=1e-5, atol=1e-5
     )
+    _assert_matches_oracle(W_chunk, blocks, A, B, 8, 3, 0.2)
+
+
+@pytest.mark.parametrize(
+    "nb,b,k,n,weighted,epochs,chunk",
+    [
+        # imagenet-fit: 8 blocks, weighted, 3 epochs, chunk 2: four factor
+        # programs and the concatenate.
+        (8, 8, 10, 128, True, 3, 2),
+        # timit-fit: 40 blocks, n the block's width, 5 epochs, chunk 8.
+        (40, 8, 5, 8, False, 5, 8),
+    ],
+    ids=["imagenet-fit", "timit-fit"],
+)
+def test_solve_at_the_cells_block_structure(
+    rng, monkeypatch, nb, b, k, n, weighted, epochs, chunk
+):
+    """The path both benchmark cells run (blocks tile d, cached inverses,
+    a chunked factor phase, one epochs program), at the cells' block counts,
+    epochs and chunks and tiny widths, through the rule that picks
+    ``cache_grams`` itself."""
+    from keystone_tpu.linalg import bcd
+
+    A, B, _ = _problem(rng, n=n, d=nb * b, k=k)
+    w = rng.uniform(0.5, 2.0, size=n).astype(np.float32) if weighted else None
+    monkeypatch.setattr(bcd, "_factor_chunk", lambda _: chunk)
+    dispatched = []  # blocks in each factor program the solve dispatches
+    factor_fn = bcd._fused_factor_fn
+
+    def counting_factor_fn(*key):
+        def factor(a3, lam, w_rows):
+            dispatched.append(a3.shape[0])
+            return factor_fn(*key)(a3, lam, w_rows)
+
+        return factor
+
+    monkeypatch.setattr(bcd, "_fused_factor_fn", counting_factor_fn)
+    W, blocks = block_coordinate_descent(
+        RowMatrix.from_array(A), RowMatrix.from_array(B),
+        block_size=b, num_iters=epochs, lam=0.1, row_weights=w,
+    )
+    assert dispatched == [chunk] * (nb // chunk)
+    _assert_matches_oracle(W, blocks, A, B, b, epochs, 0.1, w)

@@ -898,8 +898,8 @@ def test_solver_factor_span_says_whether_the_blocked_inverse_engaged(traced, rng
 
 def test_solver_programs_keep_the_names_the_benchmark_filters_on():
     """``benchmark/metrics/{solver_roofline,factor_ms,featurize_ms}.json``
-    tell the solver's device time by the HLO module names ``jit_local`` and
-    ``jit__batched_spd_inv``: a rename needs their readers changed first."""
+    tell the solver's device time by the HLO module name ``jit_local``: a
+    rename needs their readers changed first."""
     import jax
     import jax.numpy as jnp
 
@@ -922,5 +922,3 @@ def test_solver_programs_keep_the_names_the_benchmark_filters_on():
     }
     for phase, low in lowered.items():
         assert "module @jit_local " in low.as_text(), phase
-    inv = bcd._batched_ridge_inv_fn(mesh).lower(shape((nb, b, b), f32))
-    assert "module @jit__batched_spd_inv " in inv.as_text()
