@@ -37,6 +37,10 @@ class BlockLinearMapper(Transformer):
     gemm+accumulate chain.
     """
 
+    # The weights are arguments of the program; the column ranges are its
+    # static part (a tuple of tuples, so that it hashes).
+    array_fields = ("W_blocks", "b")
+
     def __init__(
         self,
         W_blocks: Sequence[jax.Array],
@@ -44,7 +48,7 @@ class BlockLinearMapper(Transformer):
         b: Optional[jax.Array] = None,
     ):
         self.W_blocks = [jnp.asarray(w) for w in W_blocks]
-        self.blocks = list(blocks)
+        self.blocks = tuple((int(s), int(e)) for s, e in blocks)
         self.b = None if b is None else jnp.asarray(b)
 
     def apply_batch(self, X):

@@ -43,6 +43,8 @@ class GaussianMixtureModel(Transformer):
     """Fitted GMM. As a transformer it emits per-component soft assignments
     (responsibilities) — the quantity Fisher-vector encoding consumes."""
 
+    array_fields = ("weights", "means", "variances")
+
     def __init__(self, weights, means, variances):
         self.weights = jnp.asarray(weights)  # (k,)
         self.means = jnp.asarray(means)  # (k, d)

@@ -22,6 +22,10 @@ from keystone_tpu.workflow import Estimator, Transformer
 
 
 class PCATransformer(Transformer):
+    # The fitted arrays, arguments of the program (``mean`` may be None:
+    # then it is no argument, and another program).
+    array_fields = ("components", "mean")
+
     def __init__(self, components: jax.Array, mean: jax.Array | None = None):
         # components: (d, dims) — columns are principal directions.
         self.components = jnp.asarray(components)

@@ -17,6 +17,9 @@ from keystone_tpu.workflow import Transformer
 class ClassLabelIndicators(Transformer):
     def __init__(self, num_classes: int):
         self.num_classes = num_classes
+        # Resolved here, not at trace time: the program is shared by every
+        # node of these two fields and would keep its first trace's dtype.
+        self.dtype = config.default_dtype
 
     def signature(self):
         return self.stable_signature(self.num_classes)
@@ -24,7 +27,7 @@ class ClassLabelIndicators(Transformer):
     def apply_batch(self, y):
         y = jnp.asarray(y).astype(jnp.int32)
         onehot = jnp.zeros(
-            (y.shape[0], self.num_classes), dtype=config.default_dtype
+            (y.shape[0], self.num_classes), dtype=self.dtype
         )
         onehot = onehot.at[jnp.arange(y.shape[0]), y].set(1.0)
         return 2.0 * onehot - 1.0

@@ -144,7 +144,7 @@ def _fv_pallas(X, w, mu, var, tile_m: int, interpret: bool):
     out = jnp.concatenate(
         [(gmu * cm).reshape(B, -1), (gvar * cv).reshape(B, -1)], axis=-1
     )
-    return out.astype(config.default_dtype)
+    return out
 
 
 def fisher_vectors_pallas(
@@ -154,8 +154,10 @@ def fisher_vectors_pallas(
     variances,
     tile_m: int = 256,
     interpret: bool | None = None,
+    dtype=None,
 ) -> jax.Array:
-    """(B, m, d) descriptor sets → (B, 2·k·d) raw Fisher vectors.
+    """(B, m, d) descriptor sets → (B, 2·k·d) raw Fisher vectors, in
+    ``dtype`` (None: ``config.default_dtype`` as this call finds it).
 
     ``interpret`` defaults to the Pallas interpreter on the ``cpu`` backend
     (tests run the kernel logic there) and to Mosaic everywhere else: a
@@ -179,4 +181,4 @@ def fisher_vectors_pallas(
         jnp.asarray(variances, dtype=jnp.float32),
         tile_m=min(tile_m, X.shape[1]),
         interpret=interpret,
-    )
+    ).astype(dtype or config.default_dtype)

@@ -40,7 +40,7 @@ from keystone_tpu.nodes.images.external.fisher_vector import (
 from keystone_tpu.nodes.images.lcs import LCSExtractor
 from keystone_tpu.nodes.learning import BlockWeightedLeastSquaresEstimator
 from keystone_tpu.nodes.util import ClassLabelIndicators, TopKClassifier
-from keystone_tpu.utils.metrics import active_tracer, span_of
+from keystone_tpu.utils.metrics import active_tracer, program_counters, span_of
 from keystone_tpu.workflow import Pipeline
 
 
@@ -364,9 +364,13 @@ def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
     # The root span of one whole fit: both branches' eager fits
     # (build_featurizer) and the graph's, so every span below shares its
     # root_id. It closes when the solver's programs are dispatched, not
-    # when the device has run them.
+    # when the device has run them. It carries how the fit's transformer
+    # programs were found (``program_counters``): a closure call is a
+    # program traced for this fit alone.
     with span_of(active_tracer(), "fit", "pipeline",
-                 pipeline="ImageNetSiftLcsFV", rows=int(len(train.data))):
+                 pipeline="ImageNetSiftLcsFV",
+                 rows=int(len(train.data))) as attrs:
+        calls = program_counters.calls()
         featurizer = build_featurizer(conf, train.data)
         targets = ClassLabelIndicators(num_classes)(train.labels)
         solver = BlockWeightedLeastSquaresEstimator(
@@ -377,6 +381,8 @@ def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
             checkpoint_dir=conf.checkpoint_dir,
         )
         fitted = featurizer.and_then(solver, train.data, targets).fit()
+        if attrs is not None:
+            attrs.update(program_counters.since(calls))
     return featurizer, fitted
 
 

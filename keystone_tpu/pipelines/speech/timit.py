@@ -81,8 +81,12 @@ def fit(conf: TimitConfig, train: LabeledData,
     rows, dim_in = (int(s) for s in train.data.shape)
     tracer = active_tracer()
     # The root span of one whole fit. It closes when the solver's programs
-    # are dispatched, not when the device has run them.
-    with span_of(tracer, "fit", "pipeline", pipeline="timit", rows=rows):
+    # are dispatched, not when the device has run them. It carries how the
+    # fit's transformer programs were found (``program_counters``): a
+    # closure call is a program traced for this fit alone.
+    with span_of(tracer, "fit", "pipeline", pipeline="timit",
+                 rows=rows) as root:
+        calls = program_counters.calls()
         featurizer = build_featurizer(conf, train.data)
         # The features are computed once, here, and handed to the solver as
         # data. Nothing waits for them: the span covers the chain's
@@ -101,7 +105,10 @@ def fit(conf: TimitConfig, train: LabeledData,
             num_iters=conf.num_iters,
             lam=conf.lam,
         ).with_data(features, targets)
-        return featurizer.and_then(head).and_then(MaxClassifier()).fit()
+        fitted = featurizer.and_then(head).and_then(MaxClassifier()).fit()
+        if root is not None:
+            root.update(program_counters.since(calls))
+        return fitted
 
 
 def run(conf: TimitConfig) -> dict:

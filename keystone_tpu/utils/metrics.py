@@ -1288,11 +1288,11 @@ def node_cost_analysis(transformer, X) -> Optional[Dict[str, float]]:
             "bytes_accessed": cost["bytes_accessed"],
         }
         est.update(_memory_analysis(compiled))
-        # ``argument_bytes`` is the batch's: a program that takes its
+        # ``argument_bytes`` is the batch's: a shared program takes its
         # transformer's arrays as arguments (``Transformer.array_fields``)
-        # counts them too, and the planner that prices a row would bill
-        # the weights to every row.
-        if "argument_bytes" in est and getattr(transformer, "takes_arrays", bool)():
+        # and counts them too, and the planner that prices a row would
+        # bill the weights to every row.
+        if "argument_bytes" in est and getattr(transformer, "shares_program", bool)():
             own = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(transformer))
             est["argument_bytes"] = max(est["argument_bytes"] - own, 0.0)
     except Exception:  # lint: broad-ok the cost model is best-effort; any lowering/compile failure means 'no estimate', never a failed fit
@@ -1913,15 +1913,32 @@ metrics_registry.register("sharding", sharding_counters)
 
 
 class ProgramCounters(CounterSet):
-    """What the jitted transformer programs were handed as arguments
-    (``workflow/pipeline.py``: a transformer that names its
-    ``array_fields``). Thread-safe (CounterSet).
+    """How the jitted transformer programs were found and what they were
+    handed as arguments (``workflow/pipeline.py``). Thread-safe
+    (CounterSet).
 
     - ``argument_bytes``: the size of the arrays a call handed its
       program, summed over the calls; a chain whose arrays are constants
       of its program adds nothing, so a span that reads the counter around
       a call tells the two apart
+    - ``shared_program_calls``: calls of a program found by the
+      transformer's structure (``Transformer.shares_program``): a second
+      fit meets the first fit's executable
+    - ``closure_program_calls``: calls of a transformer's own jitted
+      closure, traced anew for every new transformer; a fit's root span
+      carries both (``since``), and a fit that builds only transformers
+      with value-hashed static parts reads 0 here
     """
+
+    _CALLS = ("shared_program_calls", "closure_program_calls")
+
+    def calls(self) -> Dict[str, int]:
+        """The two call counts as they stand: a mark for ``since``."""
+        return {key: self.get(key) for key in self._CALLS}
+
+    def since(self, mark: Dict[str, int]) -> Dict[str, int]:
+        """The two call counts since ``mark`` (an earlier ``calls()``)."""
+        return {key: self.get(key) - mark[key] for key in self._CALLS}
 
 
 program_counters = ProgramCounters()

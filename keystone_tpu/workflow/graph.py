@@ -188,21 +188,22 @@ def structural_hash(
     TPU-rebuild analog of the reference's fitted-prefix memoization key
     (Ref: workflow/Prefix.scala [unverified]).
     """
+    # Recursion by name, with the memo handed down: an inner function that
+    # called itself would be a reference cycle holding ``graph`` (and a
+    # dataset node's device arrays) until the cycle collector next runs.
     memo: Dict[GraphId, int] = {} if _memo is None else _memo
-
-    def rec(gid: GraphId) -> int:
-        if gid in memo:
-            return memo[gid]
-        if isinstance(gid, SourceId):
-            h = hash(("source", source_key(gid)))
-        else:
-            op = graph.operators[gid]
-            dep_h = tuple(rec(d) for d in graph.dependencies[gid])
-            h = op.prefix_hash(dep_h)
-        memo[gid] = h
-        return h
-
-    return rec(target)
+    if target in memo:
+        return memo[target]
+    if isinstance(target, SourceId):
+        h = hash(("source", source_key(target)))
+    else:
+        dep_h = tuple(
+            structural_hash(graph, d, source_key, memo)
+            for d in graph.dependencies[target]
+        )
+        h = graph.operators[target].prefix_hash(dep_h)
+    memo[target] = h
+    return h
 
 
 def structural_digest(
@@ -216,23 +217,22 @@ def structural_digest(
     prefix reaches a free source (an unbound input has no content) — unless
     ``source_token`` names the free input, for digesting pipeline TEMPLATES
     (e.g. an unfitted featurizer front) rather than bound executions."""
+    # Recursion by name, as in ``structural_hash`` and for its reason.
     memo: Dict[GraphId, Any] = {} if _memo is None else _memo
+    if target in memo:
+        return memo[target]
+    if isinstance(target, SourceId):
+        if source_token is not None:
+            from keystone_tpu.workflow.fingerprint import digest_tree
 
-    def rec(gid: GraphId):
-        if gid in memo:
-            return memo[gid]
-        if isinstance(gid, SourceId):
-            if source_token is not None:
-                from keystone_tpu.workflow.fingerprint import digest_tree
-
-                d = digest_tree(("source", source_token))
-            else:
-                d = None
+            d = digest_tree(("source", source_token))
         else:
-            op = graph.operators[gid]
-            dep_d = tuple(rec(x) for x in graph.dependencies[gid])
-            d = op.prefix_digest(dep_d)
-        memo[gid] = d
-        return d
-
-    return rec(target)
+            d = None
+    else:
+        dep_d = tuple(
+            structural_digest(graph, x, memo, source_token)
+            for x in graph.dependencies[target]
+        )
+        d = graph.operators[target].prefix_digest(dep_d)
+    memo[target] = d
+    return d

@@ -104,10 +104,11 @@ class ChainFusionRule(Rule):
                 # cascade); dropping one cold entry degrades gracefully.
                 self._fuse_cache.pop(next(iter(self._fuse_cache)))
             fused = FusedTransformer(stages)
-            # A chain whose program is found by its structure needs no memo
-            # to keep its executable, and the memo would pin its arrays
-            # (0.36 GB a TIMIT fit) for the life of the process.
-            if not fused.takes_arrays():
+            # The memo holds closure chains only: one whose program is found
+            # by its structure (``Transformer.shares_program``) needs no
+            # memo to meet its executable again, and the memo would pin its
+            # arrays (0.36 GB a TIMIT fit) for the life of the process.
+            if not fused.shares_program():
                 self._fuse_cache[key] = fused
         return fused
 

@@ -65,10 +65,12 @@ def _program(name: str, layout=None, donate: bool = False):
     ``Transformer`` is a pytree (its ``array_fields`` the children, its
     other fields the static part), so ``jax.jit`` keys on the tree's
     structure and the arrays' shapes and dtypes: the compiled program holds
-    none of the arrays, and transformers that differ only in them share it.
-    One callable a ``name`` because the HLO module is named after the
-    function, and the device trace is read by module. With ``layout`` it is
-    the sharded lowering (arrays replicated, rows sharded in and out)."""
+    none of the arrays, and every transformer of one structure shares it,
+    with arrays or with none (which take this path:
+    ``Transformer.shares_program``). One callable a ``name`` because the
+    HLO module is named after the function, and the device trace is read by
+    module. With ``layout`` it is the sharded lowering (arrays replicated,
+    rows sharded in and out)."""
 
     def apply(transformer, X):
         if layout is None:
@@ -85,8 +87,8 @@ class _Bound:
     """``_program`` with its transformer filled in: what ``_jitted()``
     hands out, with the parts of a jitted callable its callers use. Made
     anew at each call (a cached one would tie the transformer into a
-    cycle), and it counts the bytes it hands over (``program_counters``:
-    what a constant would not show)."""
+    cycle), and it counts its calls and the bytes it hands over
+    (``program_counters``: what a constant would not show)."""
 
     __slots__ = ("program", "transformer")
 
@@ -97,6 +99,7 @@ class _Bound:
     def __call__(self, X):
         from keystone_tpu.utils.metrics import program_counters
 
+        program_counters.bump("shared_program_calls")
         program_counters.bump("argument_bytes", sum(
             int(leaf.nbytes)
             for leaf in jax.tree_util.tree_leaves(self.transformer)
@@ -108,6 +111,49 @@ class _Bound:
 
     def _cache_size(self) -> int:
         return self.program._cache_size()
+
+
+class _Closure:
+    """The jitted ``apply_batch`` of a transformer that shares no program:
+    the same parts as ``_Bound``, and its calls counted beside
+    ``_Bound``'s. Kept on the transformer, which is what finds it again."""
+
+    __slots__ = ("program",)
+
+    def __init__(self, program):
+        self.program = program
+
+    def __call__(self, X):
+        from keystone_tpu.utils.metrics import program_counters
+
+        program_counters.bump("closure_program_calls")
+        return self.program(X)
+
+    def lower(self, X):
+        return self.program.lower(X)
+
+    def _cache_size(self) -> int:
+        return self.program._cache_size()
+
+
+def _hashes_by_value(value: Any) -> bool:
+    """Is ``hash(value)`` a function of what ``value`` holds? Not where it
+    does not hash (a list, an array), and not where it hashes by identity
+    (a function, a bound method, an object with the default ``__hash__``):
+    a shared program outlives the transformer, and such a key would stay
+    in jit's cache for the life of the process and never be met again. A
+    class counts as its own value."""
+    if isinstance(value, (tuple, frozenset)):
+        return all(_hashes_by_value(v) for v in value)
+    if isinstance(value, type):
+        return True
+    if callable(value) or type(value).__hash__ in (None, object.__hash__):
+        return False
+    try:
+        hash(value)
+    except TypeError:
+        return False
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -140,14 +186,18 @@ class Transformer:
 
     # The attributes that hold this transformer's arrays, fitted or drawn
     # (or, for a chain, its stages): the children of the pytree every
-    # transformer is. A transformer that names them is traced with those
-    # arrays as arguments of its program (``_program``), not as constants
-    # in it: the program does not grow with them, need not be compiled
-    # again for other values, and is shared by every transformer that
-    # differs only in them. The other fields are the static part and have
-    # to hash; where they do not (an array that is not named here), or
-    # where nothing is named, ``apply_batch`` is jitted as the closure it
-    # is.
+    # transformer is, and the arguments of its program (``_program``), not
+    # constants in it: the program does not grow with them and need not be
+    # compiled again for other values. The other fields are the static
+    # part. The rule (``shares_program``): where every one of them hashes
+    # by value, arrays or none, the program is found by the transformer's
+    # structure (class, static fields, the arrays' shapes and dtypes) and
+    # shared by every transformer of that structure, in this fit and the
+    # next; where one does not (an array not named here, a callable, an
+    # object hashed by identity), ``apply_batch`` is jitted as the closure
+    # it is and kept on the transformer. What ``apply_batch`` reads has to
+    # be a field: a value read from elsewhere at trace time (``config``)
+    # is resolved in ``__init__``.
     array_fields: tuple = ()
 
     def __init_subclass__(cls, **kwargs):
@@ -176,22 +226,22 @@ class Transformer:
         made.__dict__.update(zip(cls.array_fields, children))
         return made
 
-    def takes_arrays(self) -> bool:
-        """Does this transformer's program take its arrays as arguments?
-        Then its structure finds the executable again, not its identity,
-        and nothing need keep it alive for that."""
-        leaves, treedef = jax.tree_util.tree_flatten(self)
-        nodes = [treedef] if leaves else []
-        try:
-            while nodes:  # PyTreeDef's own hash leaves the static parts out
-                node = nodes.pop()
-                kind, static = node.node_data() or (None, None)
-                if isinstance(kind, type) and issubclass(kind, Transformer):
-                    hash(static)
-                nodes.extend(node.children())
-        except TypeError:
-            return False
-        return bool(leaves)
+    def shares_program(self) -> bool:
+        """Is this transformer's program found by its structure? Where
+        every static field, of it and of the transformers among its
+        children, hashes by value (``array_fields``' comment has the
+        rule). Then its identity finds nothing, and nothing need keep it
+        alive."""
+        _leaves, treedef = jax.tree_util.tree_flatten(self)
+        nodes = [treedef]
+        while nodes:  # PyTreeDef's own hash leaves the static parts out
+            node = nodes.pop()
+            kind, static = node.node_data() or (None, None)
+            if isinstance(kind, type) and issubclass(kind, Transformer):
+                if not all(_hashes_by_value(v) for _k, v, _type in static):
+                    return False
+            nodes.extend(node.children())
+        return True
 
     def _program_name(self) -> str:
         return type(self).__name__
@@ -248,11 +298,11 @@ class Transformer:
         return self.apply_batch(X)
 
     def _jitted(self) -> Callable:
-        if self.takes_arrays():
+        if self.shares_program():
             return _Bound(_program(self._program_name()), self)
         fn = getattr(self, "_jit_cache", None)
         if fn is None:
-            fn = jax.jit(self.apply_batch)
+            fn = _Closure(jax.jit(self.apply_batch))
             object.__setattr__(self, "_jit_cache", fn)
         return fn
 
@@ -274,7 +324,7 @@ class Transformer:
         out) — memoized per (transformer, layout, donate) like
         ``_jitted``. The donated variant aliases the staged input buffer
         into the chain's output (``SpecLayout.jit`` donation)."""
-        if self.takes_arrays():
+        if self.shares_program():
             program = _program(self._program_name(), layout, donate)
             return _Bound(program, self)
         cache = getattr(self, "_shard_jit_cache", None)
@@ -285,9 +335,9 @@ class Transformer:
         fn = cache.get(key)
         if fn is None:
             body = lambda X: self.apply_sharded(X, layout)  # noqa: E731
-            fn = cache[key] = layout.jit(
+            fn = cache[key] = _Closure(layout.jit(
                 body, donate_argnums=(0,) if donate else ()
-            )
+            ))
         return fn
 
     def _donation_eligible(self, X, layout) -> bool:

@@ -185,8 +185,12 @@ class TestSessionCacheCrossInstance:
     def test_identical_pipelines_fit_once(self):
         X, Y = _data()
         CountingEstimator.fits = 0
-        p1 = CountingEstimator(lam=1e-3).with_data(X.copy(), Y.copy()).fit()
-        p2 = CountingEstimator(lam=1e-3).with_data(X.copy(), Y.copy()).fit()
+        # The entry lives as long as the estimator that made it
+        # (``_cache_fit``): held here, where it used to outlive the
+        # statement only as cyclic garbage under ``structural_hash``.
+        e1, e2 = CountingEstimator(lam=1e-3), CountingEstimator(lam=1e-3)
+        p1 = e1.with_data(X.copy(), Y.copy()).fit()
+        p2 = e2.with_data(X.copy(), Y.copy()).fit()
         assert CountingEstimator.fits == 1
         out1 = np.asarray(p1.apply(X).get())
         out2 = np.asarray(p2.apply(X).get())

@@ -320,8 +320,14 @@ def test_pallas_fv_sharded_bit_identical_and_counted():
     c = sharding_counters.snapshot()
     assert c.get("pallas_sharded_calls", 0) >= 1
     assert c.get("sharded_chain_calls", 0) >= 1
-    plain = np.asarray(jax.jit(fv.apply_batch)(X))
+    # The single-device walk is the same program unsharded, the mixture
+    # its argument (a closure that folds it in as constants rounds the
+    # last bit otherwise).
+    config.shard_data_batches = False
+    plain = np.asarray(fv.batch_call(X))
     assert sharded.tobytes() == plain.tobytes()
+    np.testing.assert_allclose(
+        plain, np.asarray(jax.jit(fv.apply_batch)(X)), rtol=1e-6, atol=1e-6)
     # FV widens (B, m, d) → (B, 2kd): its donation is refused, counted.
     assert c.get("donation_refused", 0) >= 1
 

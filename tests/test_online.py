@@ -338,7 +338,7 @@ def test_block_least_squares_partial_fit():
     m_ref = ref.solve_online(ref.partial_fit(X, Y))
     assert np.array_equal(np.asarray(m.W), np.asarray(m_ref.W))
     assert np.array_equal(np.asarray(m.b), np.asarray(m_ref.b))
-    assert m.blocks == [(0, D_IN)]
+    assert m.blocks == ((0, D_IN),)
     # fit_intercept=False drops the correction AND the bias.
     est0 = BlockLeastSquaresEstimator(lam=1e-3, fit_intercept=False)
     m0 = est0.solve_online(est0.partial_fit(X, Y))

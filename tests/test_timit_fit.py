@@ -133,13 +133,26 @@ def test_the_chains_program_holds_no_constant_of_the_projections_size():
     assert all(n <= 1 for n in _constants(sharded))
 
 
-def test_a_chain_that_names_no_arrays_is_jitted_as_before():
-    """The closure path: constants in the program, the jitted callable the
-    transformer's own."""
-    chain = FusedTransformer([L2Normalizer()])
-    assert jax.tree_util.tree_leaves(chain) == [] and not chain.takes_arrays()
-    assert chain._jitted() is chain._jitted()
-    assert "jit_apply_batch" in chain._jitted().lower(_frames()).as_text()
+def test_a_chain_that_names_no_arrays_shares_one_program():
+    """A chain with no array at all takes the shared path too: its static
+    part hashes by value, so two instances are one program, named after
+    the chain."""
+    X = _frames()
+    one, two = (FusedTransformer([L2Normalizer()]) for _ in range(2))
+    assert jax.tree_util.tree_leaves(one) == [] and one.shares_program()
+    assert "jit_apply_L2Normalizer" in one._jitted().lower(X).as_text()
+    a = np.asarray(one.batch_call(X))
+    before = COMPILES.count
+    np.testing.assert_array_equal(np.asarray(two.batch_call(X)), a)
+    assert COMPILES.count == before
+    assert one._jitted().program is two._jitted().program
+    np.testing.assert_allclose(a, X / np.linalg.norm(X, axis=1, keepdims=True),
+                               rtol=1e-6)
+    # Another value of the field is another program of the same callable.
+    loose = FusedTransformer([L2Normalizer(eps=1e-3)])
+    assert loose._jitted().program is one._jitted().program
+    loose.batch_call(X)
+    assert COMPILES.count > before
 
 
 def test_two_seeds_share_one_executable():
@@ -213,7 +226,8 @@ def test_a_scaler_without_a_deviation_has_one_array():
 def test_a_stage_with_undeclared_arrays_keeps_its_own_program():
     """A chain with a stage whose arrays are not named has a static part
     that does not hash: it is jitted as the closure it is (every array a
-    constant), and the optimizer's memo keeps it alive as before."""
+    constant, the module ``jit_apply_batch``), and the optimizer's memo
+    keeps it alive as before."""
     X = _frames()
     W = np.random.default_rng(1).normal(size=(128, 3)).astype(np.float32)
 
@@ -225,11 +239,12 @@ def test_a_stage_with_undeclared_arrays_keeps_its_own_program():
         return FusedTransformer(c.stages + [Undeclared(W)])
 
     one, two = chain(), chain()
-    assert jax.tree_util.tree_leaves(one) and not one.takes_arrays()
+    assert jax.tree_util.tree_leaves(one) and not one.shares_program()
     assert one._jitted() is one._jitted() is not two._jitted()
+    assert "jit_apply_batch" in one._jitted().lower(X).as_text()
     np.testing.assert_allclose(np.asarray(one.batch_call(X)),
                                np.asarray(two.batch_call(X)), atol=1e-6)
-    assert _chain(1, X=X).takes_arrays()
+    assert _chain(1, X=X).shares_program()
 
 
 def test_argument_bytes_are_counted_a_call():

@@ -270,22 +270,29 @@ def test_fit_cache_pins_objects_and_evicts_with_estimator():
     assert env.fit_cache == {}
 
 
-def test_repeated_apply_reuses_fused_jit():
-    p = Plus(1.0).and_then(Times(2.0)).and_then(Plus(0.5))
+@pytest.mark.parametrize("shared", [True, False])
+def test_repeated_apply_reuses_fused_jit(shared):
+    """Every ``apply`` optimizes a fresh graph copy, and the copies of one
+    logical chain meet one jitted program: a chain whose fields hash by
+    value by its structure (new fused objects, nothing memoised), a
+    closure chain (an array it does not name) through the optimizer's memo
+    of fused objects."""
+    first = Plus(1.0 if shared else jnp.ones(2))
+    p = first.and_then(Times(2.0)).and_then(Plus(0.5))
     X = np.ones((2, 2))
-    fused_objs = set()
+    fused = []
     from keystone_tpu.workflow import PipelineEnv
     from keystone_tpu.workflow.operators import TransformerOperator
 
     for _ in range(3):
         ds = p(X)
         g = PipelineEnv.get().optimizer.execute(ds.graph, [ds.sink])
-        for op in g.operators.values():
-            if isinstance(op, TransformerOperator):
-                fused_objs.add(id(op.transformer))
+        fused += [op.transformer for op in g.operators.values()
+                  if isinstance(op, TransformerOperator)]
         ds.get()
-    # Same FusedTransformer object across graph copies => one jit cache.
-    assert len(fused_objs) == 1
+    assert len(fused) == 3 and all(f.shares_program() == shared for f in fused)
+    assert len({id(f) for f in fused}) == (3 if shared else 1)
+    assert len({id(f._jitted().program) for f in fused}) == 1
 
 
 def test_apply_datum_respects_batch_contract():
