@@ -6,10 +6,45 @@ CenterCornerPatcher}.scala (SURVEY.md §2.5) [unverified].
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from keystone_tpu.workflow import Transformer
+
+
+def windows(X, fh: int, fw: int, stride: int = 1):
+    """(n, oh, ow, fh·fw·c): every fh x fw window of ``X`` (n, h, w, c) at
+    ``stride``, flattened (row, column, channel): fh·fw strided slices side
+    by side (im2col with no gather)."""
+    _n, h, w, _c = X.shape
+    oh, ow = (h - fh) // stride + 1, (w - fw) // stride + 1
+    return jnp.concatenate([
+        X[:, i:i + (oh - 1) * stride + 1:stride,
+          j:j + (ow - 1) * stride + 1:stride, :]
+        for i in range(fh) for j in range(fw)
+    ], axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames="size")
+def _take_patches(X, image, top, left, size: int):
+    """The (size, size, c) windows of ``X`` (n, h, w, c) at ``top``,
+    ``left`` of the images ``image``: (patches, size, size, c). Each is one
+    slice of the (n, h, w·c) view, size rows by size·c contiguous values:
+    a gather whose rows are c values wide (three for colour images) takes
+    the TPU's compiler eight minutes at 100,000 patches, this one seconds
+    (it runs as a loop of slices, 3 us a patch: PERF.md, PR 32). The
+    indices are arguments: one program for every draw."""
+    n, h, w, c = X.shape
+    rows = X.reshape(n, h, w * c)
+
+    def window(i, t, l):
+        return lax.dynamic_slice(rows, (i, t, l * c), (1, size, size * c))[0]
+
+    return jax.vmap(window)(image, top, left).reshape(-1, size, size, c)
 
 
 class RandomPatcher(Transformer):
@@ -35,10 +70,9 @@ class RandomPatcher(Transformer):
         img_idx = rng.integers(0, n, size=self.num_patches)
         tops = rng.integers(0, h - p + 1, size=self.num_patches)
         lefts = rng.integers(0, w - p + 1, size=self.num_patches)
-        rows = tops[:, None] + np.arange(p)[None, :]  # (np, p)
-        cols = lefts[:, None] + np.arange(p)[None, :]
-        # Advanced-indexing gather: (num_patches, p, p, c).
-        return X[img_idx[:, None, None], rows[:, :, None], cols[:, None, :], :]
+        return _take_patches(
+            X, *(a.astype(np.int32) for a in (img_idx, tops, lefts)), size=p
+        )
 
 
 class Windower(Transformer):

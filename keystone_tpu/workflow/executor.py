@@ -107,6 +107,10 @@ def _observed_execute(op, deps, tracer, profile, worker=None,
         ) as attrs:
             out = op.execute(deps)
             attrs["shape"] = _span_shape(out)
+            tiling = getattr(getattr(op, "transformer", None), "row_tiling", None)
+            tiled = tiling(deps[0]) if tiling is not None and deps else None
+            if tiled is not None:  # a fused chain that ran in row tiles
+                attrs["tile_rows"], attrs["tiles"] = tiled
         return out
 
     import jax

@@ -136,6 +136,49 @@ class _Closure:
         return self.program._cache_size()
 
 
+# A fused chain's largest intermediate may take this share of the device's
+# memory (``device_hbm_bytes()``); a chain whose largest would take more
+# runs in row tiles that take at most this (``FusedTransformer.row_tiling``).
+# The first value tried, and not a swept optimum: on a v5e the one chain that
+# tiles (cifar-fit's, 6,250 rows) ran 1.405 s at an eighth (36-row tiles),
+# 1.298 s at a quarter (72) and 2.798 s at a sixteenth (18): PERF.md, PR 32.
+_TILE_HBM_SHARE = 8
+
+
+def _intermediates(chain, X):
+    """The outputs of every stage of ``chain`` but the last (which is no
+    intermediate: tiles or none, it is written whole)."""
+    out = []
+    for stage in chain.stages[:-1]:
+        X = stage.apply_batch(X)
+        out.append(X)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _row_tiling(treedef, leaves, x, budget: int):
+    """``(tile_rows, tiles)`` for the chain that ``treedef`` and ``leaves``
+    (its arrays' shapes) describe, applied to a batch of ``x``'s shape and
+    dtype, or None where every intermediate fits ``budget`` bytes. Shapes
+    only: the stages' outputs are propagated with ``jax.eval_shape``, as
+    ``workflow/analysis.py`` prices a chain; found again by structure, as
+    the chain's program is."""
+    chain = jax.tree_util.tree_unflatten(treedef, leaves)
+    n, row_bytes = int(x.shape[0]), 0
+    for spec in jax.eval_shape(_intermediates, chain, x):
+        out = jax.tree_util.tree_leaves(spec)
+        if any(o.ndim < 1 or o.shape[0] != n for o in out):
+            return None  # rows do not stay rows: nothing to cut along
+        row_bytes = max(row_bytes, sum(
+            int(np.prod(o.shape[1:], dtype=np.int64)) * o.dtype.itemsize
+            for o in out
+        ))
+    if n * row_bytes <= budget:
+        return None
+    tiles = -(-n // max(1, budget // row_bytes))
+    return -(-n // tiles), tiles
+
+
 def _hashes_by_value(value: Any) -> bool:
     """Is ``hash(value)`` a function of what ``value`` holds? Not where it
     does not hash (a list, an array), and not where it hashes by identity
@@ -524,9 +567,52 @@ class FusedTransformer(Transformer):
         )
 
     def apply_batch(self, X):
+        tiling = self.row_tiling(X)
+        if tiling is None:
+            return self._apply_stages(X)
+        # Tile by tile inside the one program: rows padded to a whole
+        # number of tiles (pad rows are inert, the stages being
+        # row-independent) and trimmed again.
+        tile_rows, tiles = tiling
+        n = X.shape[0]
+        padded = jnp.pad(
+            X, ((0, tiles * tile_rows - n),) + ((0, 0),) * (X.ndim - 1)
+        )
+        out = jax.lax.map(
+            self._apply_stages,
+            padded.reshape((tiles, tile_rows) + X.shape[1:]),
+        )
+        return jax.tree_util.tree_map(
+            lambda o: o.reshape((tiles * tile_rows,) + o.shape[2:])[:n], out
+        )
+
+    def _apply_stages(self, X):
         for s in self.stages:
             X = s.apply_batch(X)
         return X
+
+    def row_tiling(self, X):
+        """``(tile_rows, tiles)`` where ``apply_batch`` runs ``X`` in row
+        tiles, else None: a chain of jittable, row-independent stages one
+        of whose intermediates, over all of ``X``'s rows, would take more
+        than an eighth of the device's memory. From sizes the code can
+        see (the stages' propagated shapes, ``device_hbm_bytes()``), at
+        trace time and, for a span, on the host. Only a chain that shares
+        its program is asked: its tiling is found by its structure too,
+        and one that keeps the closure path is jitted as it always was."""
+        if not (self.jittable and self.row_independent and len(self.stages) > 1
+                and getattr(X, "ndim", 0) >= 1 and self.shares_program()):
+            return None
+        from keystone_tpu.utils.metrics import device_hbm_bytes
+
+        leaves, treedef = jax.tree_util.tree_flatten(self)
+        return _row_tiling(
+            treedef,
+            tuple(jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a))
+                  for a in leaves),
+            jax.ShapeDtypeStruct(X.shape, X.dtype),
+            device_hbm_bytes() // _TILE_HBM_SHARE,
+        )
 
     def apply_sharded(self, X, layout):
         # Thread the layout so stages with a sharded kernel strategy

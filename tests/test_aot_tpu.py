@@ -311,8 +311,8 @@ def test_dense_sift_xla_compiles_for_v5e(mesh):
 
 
 def test_convolver_compiles_for_v5e(mesh):
-    """The image-pipeline hot op (conv_general_dilated in bf16 compute) on
-    the v5e target."""
+    """The image-pipeline hot op (explicit patches times the flattened
+    bank, which XLA:TPU lowers to its convolution op) on the v5e target."""
     from keystone_tpu.nodes.images.convolver import Convolver
 
     conv = Convolver(np.zeros((64, 6, 6, 3), dtype=np.float32))

@@ -22,6 +22,7 @@ from keystone_tpu.nodes.learning.gmm import GaussianMixtureModel
 from keystone_tpu.nodes.learning.pca import PCATransformer
 from keystone_tpu.nodes.util import ClassLabelIndicators
 from keystone_tpu.pipelines.images import imagenet_sift_lcs_fv as imagenet
+from keystone_tpu.pipelines.images import random_patch_cifar as cifar
 from keystone_tpu.pipelines.speech import timit
 from keystone_tpu.utils.metrics import (
     CompileEventCounter,
@@ -325,6 +326,19 @@ TIMIT = timit.TimitConfig(num_features=32, num_cosines=4, block_size=32,
                           num_iters=2, num_phones=5)
 
 
+CIFAR = cifar.RandomPatchCifarConfig(
+    num_filters=16, patch_sample=500, patch_norm=10.0, pool_size=14, pool_stride=13,
+    num_iters=1, lam=30.0, block_size=48, num_classes=4)
+
+
+def _fit_cifar(seed):
+    """New rows and a new seed: new patches, a new whitener, new filters."""
+    import dataclasses
+
+    train = _images(seed)
+    return cifar.fit(dataclasses.replace(CIFAR, seed=seed), train.data, train.labels)
+
+
 def _fit_imagenet(seed):
     _featurizer, fitted = imagenet.fit(IMAGENET, _images(seed), 4)
     return fitted
@@ -352,8 +366,8 @@ def _traced(fit, seed):
 
 
 @pytest.mark.parametrize("fit, data, shared", [
-    (_fit_imagenet, _images, 7), (_fit_timit, _frames, 2),
-], ids=["imagenet", "timit"])
+    (_fit_imagenet, _images, 7), (_fit_timit, _frames, 2), (_fit_cifar, _images, 4),
+], ids=["imagenet", "timit", "cifar"])
 def test_a_second_fit_on_other_rows_compiles_nothing(fit, data, shared):
     first, _requests, _attrs = _traced(fit, 1)
     second, requests, attrs = _traced(fit, 2)

@@ -4,7 +4,7 @@ Ref: src/main/scala/pipelines/images/cifar/RandomPatchCifar.scala's
 featurization stage (Convolver + SymmetricRectifier + Pooler; SURVEY.md
 §3.1) [unverified] — the reference runs this as per-image im2col+gemm
 `mapPartitions` over EC2 CPU cores; here the whole chain is ONE fused XLA
-program on the MXU (`lax.conv_general_dilated` + vector rectify +
+program on the MXU (patches times filter bank + vector rectify +
 `reduce_window` pool), measured in images/sec and conv TFLOPS/chip.
 
 Timing discipline mirrors bench.py: a warm-up compile rep, then a timed
