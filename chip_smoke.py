@@ -4,10 +4,11 @@ One process fits ImageNetSiftLcsFV at the widths the repo documents as its
 full-scale configuration (PCA 64, GMM k=256 → 65,536-d features, 1000
 classes, 3 epochs, block "auto") on synthetic images, serves the fitted
 pipeline (image in, top-5 out) from a ``ServingDaemon`` over HTTP and the
-framed socket, compiles the Pallas Fisher-vector kernel with Mosaic, and
-compares features and class scores with the same ``jnp`` code on the CPU
-device. Every phase goes through the entry points a user calls; the widths
-are fixed, depth (rows, image side) is cut. Any phase that fails raises.
+framed socket, compiles the two Pallas kernels (Fisher vectors; convolution,
+rectifier and pooling) with Mosaic, and compares features and class scores
+with the same ``jnp`` code on the CPU device. Every phase goes through the
+entry points a user calls; the widths are fixed, depth (rows, image side) is
+cut. Any phase that fails raises.
 
     python chip_smoke.py
 
@@ -53,6 +54,11 @@ class SmokeConfig:
     # is what the pipeline's SIFT grid produces: 169 for 64×64, so
     # tile_m = 169, not a multiple of 8).
     kernel_m: int = 2048
+    # The fused convolution kernel's check: CIFAR's 32 x 32 x 3 images, the
+    # random-patch pipeline's 6 x 6 filters at its published count and its
+    # 14 / 13 pooling, on a few rows (the kernel's grid walks rows).
+    conv_filters: int = 10000
+    conv_rows: int = 37
     # Rows per request, sent once over each wire.
     request_rows: tuple = (1, 3, 37)
     # Chip-vs-CPU comparison: images, and the largest |chip − cpu| allowed
@@ -256,10 +262,45 @@ def phase_serve(conf: SmokeConfig, fitted: dict, compiles) -> dict:
         shutil.rmtree(art_dir, ignore_errors=True)
 
 
+def _conv_kernel_error(conf: SmokeConfig, rng) -> float:
+    """The convolver's kernel (product, rectifier and pooling in one,
+    ``ops/conv_pool_pallas.py``) against its stage walk, the three nodes
+    applied one by one, at the random-patch pipeline's shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.images import (
+        Convolver,
+        Pooler,
+        SymmetricRectifier,
+    )
+
+    filters = rng.standard_normal((conf.conv_filters, 6, 6, 3))
+    filters /= np.linalg.norm(filters.reshape(len(filters), -1), axis=1)[
+        :, None, None, None]
+    conv = Convolver(filters.astype(np.float32), normalize_patches=10.0)
+    conv.bias = jnp.asarray(
+        0.1 * rng.standard_normal(conf.conv_filters).astype(np.float32))
+    taken = [SymmetricRectifier(alpha=0.25), Pooler(13, 14, mode="sum")]
+    X = jnp.asarray(rng.integers(
+        0, 256, size=(conf.conv_rows, 32, 32, 3)).astype(np.float32))
+    if conv.takes(taken) != len(taken):
+        raise AssertionError("the convolver did not take its two stages")
+
+    @jax.jit
+    def walk(X):
+        for stage in [conv] + taken:
+            X = stage.apply_batch(X)
+        return X
+
+    return _rel_err(conv.apply_with(taken, X), walk(X))
+
+
 def phase_kernel(conf: SmokeConfig, fitted: dict) -> dict:
     """The Pallas Fisher-vector kernel against the XLA einsum path, at the
     descriptor count the pipeline produces and at ``kernel_m``, then once
-    through ``apply_sharded`` so its ``shard_map`` branch runs."""
+    through ``apply_sharded`` so its ``shard_map`` branch runs; then the
+    convolver's kernel against its stage walk."""
     import functools
 
     import jax
@@ -308,6 +349,7 @@ def phase_kernel(conf: SmokeConfig, fitted: dict) -> dict:
     if _span(sharded) != layout.num_shards:
         raise AssertionError("apply_sharded output does not span the mesh")
     errors["apply_sharded"] = _rel_err(sharded, _fv_tpu(X, w, mu, var))
+    errors["conv_rectify_pool"] = _conv_kernel_error(conf, rng)
     bad = {k: v for k, v in errors.items() if not v <= conf.tolerance}
     if bad:
         raise AssertionError(

@@ -6,6 +6,7 @@ logic is exercised by the CPU-mesh test suite; on TPU the same code lowers
 through Mosaic.
 """
 
+from keystone_tpu.ops.conv_pool_pallas import conv_rectify_pool
 from keystone_tpu.ops.fisher_vector_pallas import fisher_vectors_pallas
 
-__all__ = ["fisher_vectors_pallas"]
+__all__ = ["conv_rectify_pool", "fisher_vectors_pallas"]

@@ -107,10 +107,14 @@ def _observed_execute(op, deps, tracer, profile, worker=None,
         ) as attrs:
             out = op.execute(deps)
             attrs["shape"] = _span_shape(out)
-            tiling = getattr(getattr(op, "transformer", None), "row_tiling", None)
+            chain = getattr(op, "transformer", None)
+            tiling = getattr(chain, "row_tiling", None)
             tiled = tiling(deps[0]) if tiling is not None and deps else None
             if tiled is not None:  # a fused chain that ran in row tiles
                 attrs["tile_rows"], attrs["tiles"] = tiled
+            fused = getattr(chain, "fused_stages", 0)
+            if fused:  # a stage of the chain took the ones behind it
+                attrs["fused_stages"] = fused
         return out
 
     import jax

@@ -145,14 +145,31 @@ class _Closure:
 _TILE_HBM_SHARE = 8
 
 
-def _intermediates(chain, X):
-    """The outputs of every stage of ``chain`` but the last (which is no
-    intermediate: tiles or none, it is written whole)."""
+def _steps(stages):
+    """A chain's walk: ``(stage, taken)`` pairs in order, ``taken`` the
+    stages right behind ``stage`` that it runs itself (``Transformer.takes``;
+    mostly none). What applies a chain and what prices it take these steps."""
+    steps, i = [], 0
+    while i < len(stages):
+        taken = stages[i + 1:i + 1 + stages[i].takes(stages[i + 1:])]
+        steps.append((stages[i], taken))
+        i += 1 + len(taken)
+    return steps
+
+
+def _walk(stages, X):
+    """``X`` after every step of the chain, in order."""
     out = []
-    for stage in chain.stages[:-1]:
-        X = stage.apply_batch(X)
+    for stage, taken in _steps(stages):
+        X = stage.apply_with(taken, X) if taken else stage.apply_batch(X)
         out.append(X)
     return out
+
+
+def _intermediates(chain, X):
+    """The outputs of every step of ``chain`` but the last (which is no
+    intermediate: tiles or none, it is written whole)."""
+    return _walk(chain.stages, X)[:-1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -294,6 +311,20 @@ class Transformer:
             return self.batch_call(jnp.asarray(x)[None, ...])[0]
         raise NotImplementedError(
             f"{type(self).__name__} must override apply() for non-array data"
+        )
+
+    def takes(self, following: Sequence["Transformer"]) -> int:
+        """How many of ``following``, the stages right behind this one in a
+        fused chain, this one runs itself (``apply_with``): none, unless a
+        transformer has one program for itself and its neighbours that
+        keeps what lies between them off the device's memory. Asked of the
+        stages' types and static fields, never of a value."""
+        return 0
+
+    def apply_with(self, taken: Sequence["Transformer"], X: Any) -> Any:
+        """``X`` through this stage and the ``taken`` ones behind it."""
+        raise NotImplementedError(
+            f"{type(self).__name__} takes no stage behind it"
         )
 
     def apply_batch(self, X: Any) -> Any:
@@ -562,8 +593,17 @@ class FusedTransformer(Transformer):
         self.row_independent = all(
             getattr(s, "row_independent", True) for s in flat
         )
-        self.uses_pallas = any(
+        self.uses_pallas = self.fused_stages > 0 or any(
             getattr(s, "uses_pallas", False) for s in flat
+        )
+
+    @property
+    def fused_stages(self) -> int:
+        """How many of the stages run inside another's kernel or with one
+        behind them (``Transformer.takes``): 3 for a convolver that took
+        its rectifier and pooler, 0 for a chain walked stage by stage."""
+        return sum(
+            1 + len(taken) for _stage, taken in _steps(self.stages) if taken
         )
 
     def apply_batch(self, X):
@@ -587,9 +627,7 @@ class FusedTransformer(Transformer):
         )
 
     def _apply_stages(self, X):
-        for s in self.stages:
-            X = s.apply_batch(X)
-        return X
+        return _walk(self.stages, X)[-1]
 
     def row_tiling(self, X):
         """``(tile_rows, tiles)`` where ``apply_batch`` runs ``X`` in row

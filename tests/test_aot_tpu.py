@@ -292,6 +292,35 @@ def test_pallas_fv_mosaic_compiles_at_imagenet_config(mesh):
     assert _compiled_ok(compiled)
 
 
+def test_conv_rectify_pool_mosaic_compiles_at_cifar_fit_config(mesh):
+    """The convolver's kernel at ``cifar-fit``'s sizes (6,250 images, 729
+    positions, 10,000 filters, 14 / 13 pooling) through the real Mosaic
+    lowering: its VMEM tiles (eight images by 1,280 filters a step) and
+    the program's HBM (the one-hot patches, their reordering, the pooled
+    sums) are settled for v5e here. As 36 slices of the 3-channel image
+    the patches alone asked for 20 GB."""
+    from keystone_tpu.ops.conv_pool_pallas import conv_rectify_pool
+
+    one = Mesh(np.array(mesh.devices.flat[:1]), ("d",))
+    repl = NamedSharding(one, P())
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=repl)
+
+    kernel = functools.partial(
+        conv_rectify_pool, window=(6, 6), alpha=0.25, max_val=0.0,
+        pool_size=14, pool_stride=13, interpret=False)
+    compiled = jax.jit(kernel).lower(
+        sds((6250, 32, 32, 3)), sds((108, 10000)), sds((6250, 27, 27, 1)),
+        sds((10000,)),
+    ).compile()
+    assert "custom-call" in compiled.as_text()  # the Mosaic kernel call
+    memory = compiled.memory_analysis()
+    # 2.0 GB of pooled sums (tiled layout: a little over 6250 x 80000 x 4).
+    assert 6250 * 80000 * 4 <= memory.output_size_in_bytes < 2.05e9
+    assert memory.temp_size_in_bytes < 7 * 2**30
+
+
 def test_dense_sift_xla_compiles_for_v5e(mesh):
     """The on-chip dense SIFT (grouped 1-D convs) must XLA:TPU-compile —
     it is the --sift-backend xla path that moves the last host-side

@@ -64,6 +64,7 @@ def test_smoke_phases_at_tiny_widths(monkeypatch):
     monkeypatch.setattr(config, "serve_devices", 2)
     conf = chip_smoke.SmokeConfig(
         pca_dims=8, gmm_k=4, classes=8, epochs=2, images=128, kernel_m=64,
+        conv_filters=16, conv_rows=5,
     )
     report = chip_smoke.smoke(conf)
     assert report["widths"]["feature_dim"] == 2 * (2 * 4 * 8)
@@ -75,7 +76,9 @@ def test_smoke_phases_at_tiny_widths(monkeypatch):
     assert serving["requests"] == 6  # (1, 3, 37) rows over each wire
     # On the CPU the kernel runs in the Pallas interpreter, and says so.
     counters = report["sharding_counters"]
-    assert counters["pallas_interpret_calls"] == 3
+    # (the Fisher-vector kernel three times, the convolver's once)
+    assert counters["pallas_interpret_calls"] == 4
+    assert report["kernel"]["rel_err_vs_xla"]["conv_rectify_pool"] < 1e-5
     assert "pallas_mosaic_calls" not in counters
     assert set(report["numerics"]["rel_err_vs_cpu"]) == {"features", "scores"}
     # What main() prints last: the report, then a verdict of exactly "ok"

@@ -1,7 +1,9 @@
 """``random_patch_cifar.fit`` and the mechanisms it forced: a convolver whose
-folded, patch-normalising filters are arguments of the chain's program, and
-a fused chain that runs row tile by row tile inside its one program where
-its intermediates would not fit.
+folded, patch-normalising filters are arguments of the chain's program, a
+fused chain that runs row tile by row tile inside its one program where
+its intermediates would not fit, and a convolver that takes the rectifier
+and the pooler behind it into one kernel, so that there is no such
+intermediate.
 
 Tiny widths on the CPU (64 filters, 8 x 8 patch positions). The plain
 reference is this file's own: numpy float64, explicit patches, the
@@ -253,11 +255,14 @@ def test_pooling_14_by_13_on_27_is_two_by_two_explicit_slices(rng):
 # ----------------------------------------------------- a chain in row tiles
 
 
-def _conv_chain(rng, filters=16):
+def _conv_chain(rng, filters=16, mode="max"):
+    """Max pooling: the stages run one by one and the rectifier's output is
+    an intermediate (sum pooling runs inside the convolver's kernel: the
+    chains further down)."""
     f = rng.normal(size=(filters, 6, 6, 3)).astype(np.float32)
     return FusedTransformer([
         Convolver(f, normalize_patches=10.0), SymmetricRectifier(alpha=0.25),
-        Pooler(4, 4, mode="sum"), ImageVectorizer()])
+        Pooler(4, 4, mode=mode), ImageVectorizer()])
 
 
 def _budget(monkeypatch, nbytes):
@@ -270,6 +275,7 @@ def _budget(monkeypatch, nbytes):
 def test_a_tiled_chain_equals_the_untiled_one(rng, monkeypatch):
     x = jnp.asarray(rng.uniform(0, 255, size=(37, 13, 13, 3)).astype(np.float32))
     chain = _conv_chain(rng)
+    assert chain.fused_stages == 0 and not chain.uses_pallas
     assert chain.row_tiling(x) is None
     untiled = np.asarray(chain._apply_stages(x))  # stage by stage, no program
     # The rectifier's output is 8 x 8 x 32 floats a row: room for 5 rows.
@@ -295,6 +301,7 @@ def test_a_tiled_chain_equals_the_untiled_one(rng, monkeypatch):
         reset_tracer()
     (node,) = [s for s in spans if s["name"].startswith("node:Fused(Convolver")]
     assert (node["args"]["tile_rows"], node["args"]["tiles"]) == (5, 8)
+    assert "fused_stages" not in node["args"]  # no stage took another
 
 
 def test_the_tile_rule(rng, monkeypatch):
@@ -361,17 +368,143 @@ def _cifar_chain():
     (_timit_chain, (4096, 440),
      "jit_apply_StandardScalerModel_CosineRandomFeatures", None),
     (_cifar_chain, (6250, 32, 32, 3),
-     "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer", (36, 174)),
+     "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer", None),
 ], ids=["imagenet-fit", "timit-fit", "cifar-fit"])
 def test_the_cells_chains_at_their_sizes(chain, rows, module, tiling, monkeypatch):
-    """At the benchmark's cell sizes on a v5e's memory the chains of
-    ``imagenet-fit`` and ``timit-fit`` stay whole and ``cifar-fit``'s is
-    tiled; the benchmark's metric files filter on the module names."""
+    """At the benchmark's cell sizes on a v5e's memory the three cells'
+    chains stay whole: ``cifar-fit``'s responses (58 MB a row, 36-row tiles
+    before) stay inside the convolver's kernel, and what is left between
+    its steps is the pooled sums, 2.0 GB of the 2.11 an intermediate may
+    take. The benchmark's metric files filter on the module names."""
     _budget(monkeypatch, V5E_HBM)
     chain = chain()
     assert chain.row_tiling(jax.ShapeDtypeStruct(rows, jnp.float32)) == tiling
     assert pipeline_module._program(chain._program_name()).__wrapped__.__name__ == (
         module[len("jit_"):])
+
+
+def test_the_cifar_chain_is_tiled_one_step_down(monkeypatch):
+    """The pooled sums are what the tile rule prices in ``cifar-fit``'s
+    chain: 320 KB a row. On a device an eighth smaller they would not fit."""
+    chain = _cifar_chain()
+    assert chain.fused_stages == 3
+    x = jax.ShapeDtypeStruct((6250, 32, 32, 3), jnp.float32)
+    _budget(monkeypatch, 8 * 6250 * 2 * 2 * 20000 * 4)
+    assert chain.row_tiling(x) is None
+    _budget(monkeypatch, 8 * 6250 * 2 * 2 * 20000 * 4 - 8)
+    assert chain.row_tiling(x) == (3125, 2)
+
+
+# ---------------------- a convolver that takes its rectifier and its pooler
+
+
+def _walked(stages, x):
+    for stage in stages:
+        x = stage.apply_batch(x)
+    return x
+
+
+def _lowered_for_tpu(chain, x, monkeypatch):
+    """The chain's program as it is lowered for a TPU (no device needed to
+    lower, and none to see whether a Mosaic kernel is in it), at a row
+    count no test ran: a trace made on the CPU holds the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    program = pipeline_module._program(chain._program_name())
+    x = jax.ShapeDtypeStruct((3,) + x.shape[1:], x.dtype)
+    return program.trace(chain, x).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("mode", ["sum", "mean"])
+def test_a_chain_with_the_triple_equals_its_stage_walk(rng, mode, monkeypatch):
+    from keystone_tpu.utils.metrics import sharding_counters
+
+    x = jnp.asarray(rng.uniform(0, 255, size=(21, 13, 13, 3)).astype(np.float32))
+    chain = _conv_chain(rng, filters=32, mode=mode)
+    assert chain.fused_stages == 3 and chain.uses_pallas
+    # The stage list is what it was: the program's name, the signature and
+    # the hashes of a chain say nothing of who runs what.
+    assert [type(s).__name__ for s in chain.stages] == [
+        "Convolver", "SymmetricRectifier", "Pooler", "ImageVectorizer"]
+    assert chain._program_name() == "Convolver_SymmetricRectifier_Pooler_ImageVectorizer"
+    assert chain.signature() == ("fused",) + tuple(s.signature() for s in chain.stages)
+    h = 12345
+    for stage in chain.stages:
+        h = stage.chain_hash(h)
+    assert chain.chain_hash(12345) == h
+    unfused = chain.stages[0].and_then(chain.stages[1]).and_then(
+        chain.stages[2]).and_then(chain.stages[3])
+    want = np.asarray(_walked(chain.stages, x))
+    before = sharding_counters.snapshot().get("pallas_interpret_calls", 0)
+    got = np.asarray(chain.batch_call(x))
+    # Counted where it is traced: by the tile rule's pricing and the program.
+    assert sharding_counters.snapshot()["pallas_interpret_calls"] > before
+    assert got.shape == want.shape == (21, 2 * 2 * 64)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    # The pooled sums are the one intermediate; the filters and the bias
+    # are still arguments, and the kernel is a call of the chain's module.
+    (pooled,) = jax.eval_shape(pipeline_module._intermediates, chain, x)
+    assert pooled.shape == (21, 2, 2, 64)
+    text = _lowered_for_tpu(chain, x, monkeypatch)
+    assert "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer" in text
+    assert text.count("tpu_custom_call") == 1
+    assert "tensor<32x6x6x3xf32>" in re.search(r"func\.func public @main\(([^\n]*)", text).group(1)
+    # The walk's span of such a chain says so.
+    monkeypatch.undo()
+    prior = config.trace
+    config.trace = True
+    reset_tracer()
+    try:
+        np.testing.assert_array_equal(np.asarray(unfused(x).get()), got)
+        spans = recorded_tracer().spans()
+    finally:
+        config.trace = prior
+        reset_tracer()
+    (node,) = [s for s in spans if s["name"].startswith("node:Fused(Convolver")]
+    assert node["args"]["fused_stages"] == 3
+    assert "tiles" not in node["args"]
+
+
+def _between(rng):
+    chain = _conv_chain(rng, mode="sum")
+    return FusedTransformer(
+        chain.stages[:2] + [SymmetricRectifier(alpha=0.0)] + chain.stages[2:])
+
+
+@pytest.mark.parametrize("chain", [
+    lambda rng: _conv_chain(rng, mode="max"),  # a pooling the kernel has not
+    _between,  # a stage between the rectifier and the pooler
+    lambda rng: FusedTransformer(_conv_chain(rng, mode="sum").stages[:2]),  # no pooler
+    lambda rng: FusedTransformer(  # a lone convolver in front of another stage
+        _conv_chain(rng, mode="sum").stages[:1] + [ImageVectorizer()]),
+], ids=["max-pooling", "a-stage-between", "no-pooler", "lone-convolver"])
+def test_any_other_neighbourhood_walks_stage_by_stage(rng, chain, monkeypatch):
+    from keystone_tpu.utils.metrics import sharding_counters
+
+    chain = chain(rng)
+    x = jnp.asarray(rng.uniform(0, 255, size=(6, 13, 13, 3)).astype(np.float32))
+    assert chain.fused_stages == 0 and not chain.uses_pallas
+    before = sharding_counters.snapshot()
+    got = np.asarray(chain.batch_call(x))
+    assert sharding_counters.snapshot() == before  # no kernel was traced
+    # Today's arithmetic, bit for bit.
+    np.testing.assert_array_equal(got, np.asarray(jax.jit(
+        lambda x: _walked(chain.stages, x))(x)))
+    assert "tpu_custom_call" not in _lowered_for_tpu(chain, x, monkeypatch)
+
+
+def test_on_a_mesh_the_stages_run_one_by_one(rng):
+    """``apply_sharded`` keeps the stage walk (no cell has a mesh)."""
+    from keystone_tpu.utils.mesh import SpecLayout
+    from keystone_tpu.utils.metrics import sharding_counters
+
+    chain = _conv_chain(rng, mode="sum")
+    layout = SpecLayout.for_mesh()
+    x = rng.uniform(0, 255, size=(2 * layout.num_shards, 13, 13, 3)).astype(np.float32)
+    before = sharding_counters.snapshot().get("pallas_interpret_calls", 0)
+    got = layout.jit(lambda x: chain.apply_sharded(x, layout))(layout.put(x))
+    assert sharding_counters.snapshot().get("pallas_interpret_calls", 0) == before
+    want = np.asarray(_walked(chain.stages, jnp.asarray(x)))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-4)
 
 
 # ------------------------------------------- one epoch, a ragged last block
