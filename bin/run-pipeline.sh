@@ -21,7 +21,7 @@ if [[ $# -lt 1 ]]; then
   echo "usage: $0 <Pipeline> [args...]" >&2
   echo "pipelines: MnistRandomFFT LinearPixels RandomPatchCifar" >&2
   echo "           NewsgroupsPipeline AmazonReviewsPipeline TimitPipeline" >&2
-  echo "           VOCSIFTFisher ImageNetSiftLcsFV" >&2
+  echo "           VOCSIFTFisher ImageNetSiftLcsFV RandomPatchCifarKernel" >&2
   exit 64
 fi
 
@@ -31,6 +31,7 @@ case "$PIPELINE" in
   MnistRandomFFT)        MOD=keystone_tpu.pipelines.images.mnist_random_fft ;;
   LinearPixels)          MOD=keystone_tpu.pipelines.images.linear_pixels ;;
   RandomPatchCifar)      MOD=keystone_tpu.pipelines.images.random_patch_cifar ;;
+  RandomPatchCifarKernel) MOD=keystone_tpu.pipelines.images.random_patch_cifar_kernel ;;
   NewsgroupsPipeline)    MOD=keystone_tpu.pipelines.text.newsgroups ;;
   AmazonReviewsPipeline) MOD=keystone_tpu.pipelines.text.amazon_reviews ;;
   TimitPipeline)         MOD=keystone_tpu.pipelines.speech.timit ;;

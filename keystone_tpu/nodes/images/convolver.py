@@ -19,8 +19,10 @@ Where a fused chain puts a `SymmetricRectifier` and a sum or mean `Pooler`
 right behind the convolution, the three run as one Pallas kernel
 (`Convolver.takes`, `ops/conv_pool_pallas.py`): the responses are rectified
 and pooled in VMEM and never written (58 MB a row at 10,000 filters, for
-320 KB of pooled features). Alone, or in front of anything else, the
-convolution is the product above.
+320 KB of pooled features). What such a kernel wants laid out in front of
+it, the explicit patches, is 750 KB a row whatever the filter count
+(`scratch_with`): the chain's row-tile rule prices it with the outputs.
+Alone, or in front of anything else, the convolution is the product above.
 """
 
 from __future__ import annotations
@@ -33,7 +35,7 @@ from jax import lax
 
 from keystone_tpu.nodes.images.patches import windows
 from keystone_tpu.nodes.images.pooling import Pooler, SymmetricRectifier
-from keystone_tpu.ops.conv_pool_pallas import conv_rectify_pool
+from keystone_tpu.ops.conv_pool_pallas import conv_rectify_pool, patch_arrays
 from keystone_tpu.workflow import Transformer
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -155,6 +157,14 @@ class Convolver(Transformer):
                 and following[1].mode in ("sum", "mean")):
             return 2
         return 0
+
+    def scratch_with(self, taken, x):
+        _rectifier, pooler = taken
+        return patch_arrays(
+            x, self.fh * self.fw * self.c, window=(self.fh, self.fw),
+            stride=self.stride, pool_size=pooler.pool_size,
+            pool_stride=pooler.stride, compute_dtype=self.compute_dtype,
+        )
 
     def apply_with(self, taken, X):
         rectifier, pooler = taken

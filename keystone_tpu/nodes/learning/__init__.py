@@ -47,6 +47,7 @@ from keystone_tpu.nodes.learning.kernel_ridge import (
     KernelBlockLinearMapper,
     KernelRidgeRegression,
 )
+from keystone_tpu.nodes.learning.kernel_ridge_cg import KernelRidgeCG
 
 __all__ = [
     "LinearMapper",
@@ -76,5 +77,6 @@ __all__ = [
     "GaussianKernelGenerator",
     "LinearKernelGenerator",
     "KernelRidgeRegression",
+    "KernelRidgeCG",
     "KernelBlockLinearMapper",
 ]

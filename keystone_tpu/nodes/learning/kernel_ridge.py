@@ -1,328 +1,219 @@
-"""Kernel ridge regression via distributed matrix-free conjugate gradient.
+"""Kernel ridge regression by block Gauss-Seidel over kernel column blocks.
 
-Ref: src/main/scala/nodes/learning/KernelRidgeRegression.scala +
-KernelBlockLinearMapper — blocked kernel-matrix generation and a block
-solver over Spark (SURVEY.md §2.4) [unverified].
+Ref: src/main/scala/nodes/learning/KernelRidgeRegression.scala,
+KernelGenerator.scala, KernelBlockLinearMapper.scala [unverified]: the solver
+of Tu et al., "Large Scale Kernel Learning using Block Coordinate Descent"
+(arXiv:1602.05310). With α = 0 (n x k), for each epoch and each block B of
+``block_size`` consecutive training rows in natural order:
 
-TPU-first design: instead of staging kernel blocks through an RDD-style
-cache, the regularized system (K + λI)α = Y is solved by conjugate
-gradient where each matvec computes its kernel rows on the fly inside a
-shard_map — every chip holds a row shard of the training data, builds its
-(n_local, n) kernel block on the MXU, multiplies, and the CG scalars reduce
-with psum. K is never materialized; HBM holds only data + one block per
-step. The whole CG loop is one XLA while_loop.
+    K_B   = k(X, X_B)                          (n x b)
+    K_BB  = K_B[B, :]                          (b x b)
+    R     = Y_B − K_Bᵀ α + K_BB α_B            (b x k)
+    α_B  <- solve(K_BB + λ I, R)               (Cholesky)
+
+TPU lowering: the block operand is a function of the n x d features, not an
+array. K (n x n: 10 GB at CIFAR-10's 50,000 rows) is never stored; each
+visit generates its n x b block from the row-sharded X, uses it and drops
+it, inside one program whose loop over all the epochs' visits is a
+``lax.scan``: no host round trip a block, and no more of K than one block
+ever live. X_B is replicated for the visit (each shard's rows of the block,
+``psum``), K_Bᵀ α reduces over the sharded rows (``sharded_rowsum``), and
+the b x b solve runs replicated on every chip. A ragged last block and the
+pad rows of X are masked out of K; the pad columns get 1 on the diagonal, so
+their α stays 0. X, Y, λ, the row count, the visit order and the kernel's
+parameters are arguments of the program: a second fit compiles nothing.
+
+The matrix-free conjugate-gradient solver that bore this name is
+``KernelRidgeCG`` (``kernel_ridge_cg.py``).
 """
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.scipy.linalg import cho_solve
+from jax.sharding import Mesh, PartitionSpec as P
 
 from keystone_tpu.config import config
-from keystone_tpu.linalg.row_matrix import RowMatrix
-from keystone_tpu.nodes.learning.kernels import GaussianKernelGenerator, KernelGenerator
+from keystone_tpu.linalg.row_matrix import (
+    RowMatrix,
+    _precision,
+    sharded_rowsum,
+    solver_matmul,
+)
+from keystone_tpu.nodes.learning.kernels import KernelGenerator
+from keystone_tpu.utils.mesh import fold_blocks
+from keystone_tpu.utils.metrics import active_tracer, span_of
 from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
 class KernelBlockLinearMapper(Transformer):
-    """scores(x) = k(x, X_train) @ α, computed in training-row blocks so the
-    test-kernel block never exceeds (batch, block) in memory."""
+    """scores(x) = k(x, X_train) @ α, a block of training rows at a time
+    inside one program, so the test-kernel block never exceeds
+    (batch, block) in memory. The last block is the last ``block`` rows,
+    the ones an earlier block held masked out: no padded copy of X_train."""
+
+    # The training rows, the dual weights and the kernel's parameters are
+    # arguments of the program; the block size is its static part.
+    array_fields = ("kernel", "X_train", "alpha")
 
     def __init__(self, kernel: KernelGenerator, X_train, alpha, block_size: int = 4096):
         self.kernel = kernel
         self.X_train = jnp.asarray(X_train)
         self.alpha = jnp.asarray(alpha)
-        self.block_size = block_size
+        self.block_size = min(int(block_size), int(self.X_train.shape[0]))
 
     def apply_batch(self, X):
-        n = self.X_train.shape[0]
-        out = None
-        for s in range(0, n, self.block_size):
-            e = min(s + self.block_size, n)
-            kb = self.kernel.block(X, self.X_train[s:e])
-            contrib = kb @ self.alpha[s:e]
-            out = contrib if out is None else out + contrib
-        return out
+        n, b = self.X_train.shape[0], self.block_size
+
+        def add_block(i, out):
+            start = jnp.minimum(i * b, n - b)
+            z = lax.dynamic_slice_in_dim(self.X_train, start, b)
+            a = lax.dynamic_slice_in_dim(self.alpha, start, b)
+            fresh = start + jnp.arange(b) >= i * b
+            kb = jnp.where(fresh[None, :], self.kernel.block(X, z), 0.0)
+            return out + jnp.matmul(kb, a, precision=lax.Precision.HIGHEST)
+
+        out = jnp.zeros((X.shape[0], self.alpha.shape[1]), self.alpha.dtype)
+        return lax.fori_loop(0, -(-n // b), add_block, out)
+
+    def batch_call(self, X):
+        n = int(self.X_train.shape[0])
+        with span_of(active_tracer(), "krr.apply", "solver", rows=int(np.shape(X)[0]),
+                     train_rows=n, blocks=-(-n // self.block_size)):
+            return super().batch_call(X)
 
 
-def _kernel_matvec(mesh: Mesh, axis: str, gamma: float):
-    """Row-sharded (K + λI) v with on-the-fly kernel rows and padded
-    rows/cols masked out of K — the ONE operator both CG variants iterate
-    on (a drift between them would silently solve different systems)."""
-
-    from keystone_tpu.nodes.learning.kernels import pairwise_sq_dists
-
-    def matvec(x_sharded, x_full, mask, v, lam):
-        def local(xl, ml, v):
-            kl = jnp.exp(-gamma * pairwise_sq_dists(xl, x_full))
-            kl = kl * mask[None, :] * ml[:, None]
-            return kl @ v
-
-        out = shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(P(axis), P(axis), P()),
-            out_specs=P(axis),
-            check_vma=False,
-        )(x_sharded, mask, v)
-        return out + lam * v
-
-    return matvec
+# ------------------------------------------------------- the block solver
+#
+# The three pieces of a visit that a planted fault replaces
+# (benchmark/configs/cifar-random-patch-kernel-control.py): the order of the
+# visits, the right-hand side, the diagonal.
 
 
-@lru_cache(maxsize=None)
-def _cg_fn(mesh: Mesh, axis: str, gamma: float, max_iters: int, tol: float):
-    """CG solve of (K_gauss + λI)α = Y with on-the-fly kernel rows."""
+def _visit_order(num_blocks: int, num_epochs: int) -> np.ndarray:
+    """The block of every visit of the solve: natural order, every epoch."""
+    return np.tile(np.arange(num_blocks, dtype=np.int32), num_epochs)
 
-    matvec = _kernel_matvec(mesh, axis, gamma)
 
-    @jax.jit
-    def solve(x_sharded, x_full, mask, Y, lam):
-        b = Y
-        x0 = jnp.zeros_like(b)
-        r0 = b  # since x0 = 0
-        p0 = r0
-        rs0 = jnp.sum(r0 * r0)
+def _block_residual(y_b, kt_alpha, k_bb, alpha_b, precision):
+    """R = Y_B − K_Bᵀ α + K_BB α_B: the targets less what the other blocks
+    explain."""
+    return y_b - kt_alpha + solver_matmul(k_bb, alpha_b, precision)
 
-        def cond(carry):
-            _x, _r, _p, rs, i = carry
-            return (rs > tol * tol) & (i < max_iters)
 
-        def body(carry):
-            x, r, p, rs, i = carry
-            Ap = matvec(x_sharded, x_full, mask, p, lam)
-            alpha = rs / jnp.maximum(jnp.sum(p * Ap), 1e-30)
-            x = x + alpha * p
-            r = r - alpha * Ap
-            rs_new = jnp.sum(r * r)
-            p = r + (rs_new / jnp.maximum(rs, 1e-30)) * p
-            return x, r, p, rs_new, i + 1
-
-        x, _r, _p, rs, iters = lax.while_loop(
-            cond, body, (x0, r0, p0, rs0, jnp.int32(0))
-        )
-        return x, rs, iters
-
-    return solve
+def _ridge_diagonal(col_live, lam):
+    """λ on the block's real columns, 1 on a ragged block's pad: the padded
+    K_BB + diag is block diagonal, so the pad's α stays exactly 0 and the
+    system is positive definite at λ = 0 too."""
+    return jnp.where(col_live, lam, 1.0)
 
 
 @lru_cache(maxsize=None)
-def _pcg_fn(mesh: Mesh, axis: str, gamma: float, max_iters: int, tol: float):
-    """Nyström-preconditioned CG (the Falkon-family idea, PAPERS.md):
-    landmarks L give the rank-m surrogate K̂ = C W⁻¹ Cᵀ with C = k(X, L),
-    W = k(L, L); Woodbury turns (K̂ + λI)⁻¹ into
-        (1/λ)·(I − C (λW + CᵀC)⁻¹ Cᵀ),
-    two (n, m) MXU gemms + one replicated (m, m) Cholesky solve per
-    application. RBF spectra decay fast, so M⁻¹(K + λI) clusters near 1 and
-    CG converges in a fraction of the iterations — same matvec, same
-    stopping rule, strictly fewer steps."""
+def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
+    """The whole solve as ONE program: a scan over the visits, per shard
+    under shard_map. ``starts`` (the first row of each visit's block) is
+    an argument, so epochs and order are data; so are λ, the row count and
+    the kernel's parameters."""
+    width = mesh.shape[axis]
 
-    from jax.scipy.linalg import cho_factor, cho_solve
+    def local(xl, yl, lam, n, starts, kernel):
+        rows = xl.shape[0]
+        first = lax.axis_index(axis) * rows
+        row_id = first + jnp.arange(rows)
+        row_live = row_id < n  # X's pad rows
 
-    from keystone_tpu.nodes.learning.kernels import pairwise_sq_dists
+        def take_block(v, start):
+            """Rows [start, start + block) of the row-sharded ``v``,
+            replicated: each shard puts in the rows it holds, zeros where
+            the block runs past the last row."""
+            at = start + jnp.arange(block) - first
+            mine = (at >= 0) & (at < rows)
+            held = v.at[jnp.clip(at, 0, rows - 1)].get(
+                mode="promise_in_bounds", indices_are_sorted=True)
+            return lax.psum(jnp.where(mine[:, None], held, 0.0), axis)
 
-    matvec = _kernel_matvec(mesh, axis, gamma)
+        def visit(alpha, start):
+            col_live = start + jnp.arange(block) < n
+            x_b = take_block(xl, start)
+            k_b = jnp.where(row_live[:, None] & col_live[None, :],
+                            kernel.block(xl, x_b), 0.0)
+            k_bb = take_block(k_b, start)
+            kt_alpha = sharded_rowsum(
+                lambda kb, al: solver_matmul(kb.T, al, precision),
+                axis, width, (k_b, alpha),
+            )
+            r = _block_residual(take_block(yl, start), kt_alpha, k_bb,
+                                take_block(alpha, start), precision)
+            ridge = _ridge_diagonal(col_live, lam)
+            chol = jnp.linalg.cholesky(
+                k_bb + ridge[:, None] * jnp.eye(block, dtype=k_bb.dtype))
+            alpha_b = cho_solve((chol, True), r)
+            at = row_id - start
+            inside = (at >= 0) & (at < block)
+            return jnp.where(inside[:, None],
+                             alpha_b[jnp.clip(at, 0, block - 1)], alpha), None
 
-    @jax.jit
-    def solve(x_sharded, x_full, mask, Y, lam, L, W):
-        from jax.scipy.linalg import solve_triangular
+        alpha, _ = lax.scan(visit, jnp.zeros_like(yl), starts)
+        # The model's weights are replicated, as a linear map's are.
+        return lax.all_gather(alpha, axis, tiled=True)
 
-        m = W.shape[0]
-        # Whitened landmark block B = C L⁻ᵀ with W = L Lᵀ: the Woodbury
-        # inner matrix becomes λI + BᵀB, whose conditioning is floored by λ
-        # exactly — no scale-dependent jitter games (CᵀC alone can be
-        # numerically rank-deficient for wide kernels and NaN the f32
-        # Cholesky). Over-regularizing only weakens the preconditioner,
-        # never the solution (CG iterates on the exact operator).
-        Lw = jnp.linalg.cholesky(W + 1e-5 * jnp.eye(m, dtype=W.dtype))
+    sm = shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(axis), P(axis), P(), P(), P(), P()),
+        out_specs=P(),
+        check_vma=False,
+    )
+    return jax.jit(sm)
 
-        def b_local(xl, ml):
-            cl = jnp.exp(-gamma * pairwise_sq_dists(xl, L)) * ml[:, None]
-            return solve_triangular(Lw, cl.T, lower=True).T
 
-        B = shard_map(
-            b_local,
-            mesh=mesh,
-            in_specs=(P(axis), P(axis)),
-            out_specs=P(axis),
-            check_vma=False,
-        )(x_sharded, mask)
-
-        def btb_local(bl):
-            return lax.psum(bl.T @ bl, axis)
-
-        BtB = shard_map(
-            btb_local, mesh=mesh, in_specs=P(axis), out_specs=P(),
-            check_vma=False,
-        )(B)
-        trace_scale = jnp.trace(BtB) / m
-        G = BtB + (lam + 1e-6 * trace_scale) * jnp.eye(m, dtype=W.dtype)
-        # NOTE: tried the BCD-style explicit G⁻¹ here (one-time inverse,
-        # gemm per iteration) — it NaNs: the whitened Nyström G's top
-        # eigenvalue is ~||B||² with only a λ floor below, cond can exceed
-        # 1/eps_f32, and an explicit f32 inverse breaks PCG symmetry until
-        # CG diverges. The two-pass cho_solve is the numerically safe form;
-        # PCG's whole point is few iterations, so the per-iteration trsm
-        # cost stays bounded.
-        cholG = cho_factor(G)
-
-        def btr(r):
-            def local(bl, rl):
-                return lax.psum(bl.T @ rl, axis)
-
-            return shard_map(
-                local, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(),
-                check_vma=False,
-            )(B, r)
-
-        def bmul(t):
-            def local(bl, t):
-                return bl @ t
-
-            return shard_map(
-                local, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
-                check_vma=False,
-            )(B, t)
-
-        def minv(r):
-            return (r - bmul(cho_solve(cholG, btr(r)))) / lam
-
-        b = Y
-        x0 = jnp.zeros_like(b)
-        r0 = b
-        z0 = minv(r0)
-        p0 = z0
-        rz0 = jnp.sum(r0 * z0)
-        rs0 = jnp.sum(r0 * r0)
-
-        def cond(carry):
-            _x, _r, _z, _p, _rz, rs, i = carry
-            return (rs > tol * tol) & (i < max_iters)
-
-        def body(carry):
-            x, r, z, p, rz, rs, i = carry
-            Ap = matvec(x_sharded, x_full, mask, p, lam)
-            alpha = rz / jnp.maximum(jnp.sum(p * Ap), 1e-30)
-            x = x + alpha * p
-            r = r - alpha * Ap
-            z = minv(r)
-            rz_new = jnp.sum(r * z)
-            p = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
-            return x, r, z, p, rz_new, jnp.sum(r * r), i + 1
-
-        x, _r, _z, _p, _rz, rs, iters = lax.while_loop(
-            cond, body, (x0, r0, z0, p0, rz0, rs0, jnp.int32(0))
-        )
-        return x, rs, iters
-
-    return solve
+def kernel_block_gauss_seidel(
+    A: RowMatrix, B: RowMatrix, kernel: KernelGenerator, lam: float,
+    block_size: int, num_epochs: int,
+) -> jax.Array:
+    """α of (K + λI) α = Y after ``num_epochs`` sweeps of block
+    Gauss-Seidel from α = 0, for the row-sharded features ``A`` and targets
+    ``B``: (padded rows, k), replicated, pad rows 0."""
+    A._check_aligned(B)
+    mesh, axis = A.mesh, config.data_axis
+    block = min(int(block_size), A.n)
+    num_blocks = -(-A.n // block)
+    starts = _visit_order(num_blocks, num_epochs) * block
+    dtype = A.data.dtype
+    with span_of(active_tracer(), "krr.fit", "solver", rows=A.n, dim=A.shape[1],
+                 block=block, blocks=num_blocks, epochs=num_epochs,
+                 kernel_blocks=len(starts),
+                 kernel_bytes=len(starts) * A.padded_rows * block * dtype.itemsize):
+        solve = _block_solve_fn(
+            mesh, axis, _precision(), fold_blocks(mesh.shape[axis]), block)
+        return solve(A.data, B.data, jnp.asarray(lam, dtype),
+                     jnp.asarray(A.n, jnp.int32), jnp.asarray(starts), kernel)
 
 
 class KernelRidgeRegression(LabelEstimator):
-    """Gaussian-kernel ridge regression (other kernels via the un-sharded
-    fallback path of KernelBlockLinearMapper)."""
+    """Kernel ridge regression as upstream solves it: block Gauss-Seidel
+    over blocks of ``block_size`` training rows, ``num_epochs`` sweeps, each
+    kernel block generated from the features when it is visited."""
 
-    # Fit-time diagnostic, not identity (see workflow._estimator_signature).
-    _signature_exclude = ("last_cg_iters",)
-
-    def __init__(
-        self,
-        kernel: KernelGenerator | None = None,
-        lam: float = 1e-3,
-        gamma: float | None = None,
-        max_iters: int = 200,
-        tol: float = 1e-5,
-        predict_block_size: int = 4096,
-        precond_landmarks: int | None = None,
-        seed: int = 0,
-    ):
-        if kernel is not None and gamma is not None:
-            raise ValueError("pass either `kernel` or `gamma`, not both")
-        if kernel is None:
-            kernel = GaussianKernelGenerator(gamma if gamma is not None else 1.0)
+    def __init__(self, kernel: KernelGenerator, lam: float = 1.0,
+                 block_size: int = 4096, num_epochs: int = 1):
         self.kernel = kernel
         self.lam = lam
-        self.max_iters = max_iters
-        self.tol = tol
-        self.predict_block_size = predict_block_size
-        # Nyström preconditioning: number of landmark rows (None = plain
-        # CG). ~256-1024 typically cuts RBF iteration counts several-fold.
-        self.precond_landmarks = precond_landmarks
-        self.seed = seed
-        self.last_cg_iters: int | None = None
+        self.block_size = int(block_size)
+        self.num_epochs = int(num_epochs)
 
     def fit(self, data, labels) -> KernelBlockLinearMapper:
         X = jnp.asarray(data, dtype=config.default_dtype)
         Y = jnp.asarray(labels, dtype=config.default_dtype)
         if Y.ndim == 1:
             Y = Y[:, None]
-        if not isinstance(self.kernel, GaussianKernelGenerator):
-            return self._fit_dense(X, Y)
         A = RowMatrix.from_array(X)
-        n_pad = A.padded_rows
-        mask = jnp.zeros((n_pad,), X.dtype).at[: A.n].set(1.0)
-        Y_pad = jnp.pad(Y, ((0, n_pad - Y.shape[0]), (0, 0)))
-        # Replicate the kernel-column data ONCE before the CG loop; a sharded
-        # x_full closed over inside matvec would re-all-gather every iteration.
-        x_full = jax.device_put(
-            A.data, NamedSharding(A.mesh, P())
-        )
-        if self.precond_landmarks and self.lam <= 0.0:
-            raise ValueError(
-                "precond_landmarks requires lam > 0: the Woodbury "
-                "preconditioner divides by lam (plain CG handles lam=0)"
-            )
-        if self.precond_landmarks:
-            m = min(int(self.precond_landmarks), A.n)
-            rng = np.random.default_rng(self.seed)
-            idx = rng.choice(A.n, size=m, replace=False)
-            # On-device gather: only the m landmark rows move, never a full
-            # n×d device→host round trip.
-            L = jax.device_put(
-                X[jnp.asarray(np.sort(idx))], NamedSharding(A.mesh, P())
-            )
-            W = self.kernel.block(L, L)
-            solve_p = _pcg_fn(
-                A.mesh,
-                config.data_axis,
-                float(self.kernel.gamma),
-                self.max_iters,
-                float(self.tol),
-            )
-            alpha, _rs, iters = solve_p(
-                A.data, x_full, mask, Y_pad,
-                jnp.asarray(self.lam, X.dtype), L, W,
-            )
-        else:
-            solve = _cg_fn(
-                A.mesh,
-                config.data_axis,
-                float(self.kernel.gamma),
-                self.max_iters,
-                float(self.tol),
-            )
-            alpha, _rs, iters = solve(
-                A.data, x_full, mask, Y_pad, jnp.asarray(self.lam, X.dtype)
-            )
-        self.last_cg_iters = int(iters)
-        return KernelBlockLinearMapper(
-            self.kernel, X, alpha[: A.n], self.predict_block_size
-        )
-
-    def _fit_dense(self, X, Y) -> KernelBlockLinearMapper:
-        """Un-sharded fallback for non-Gaussian kernels: materialize K once
-        and solve directly (fine at the sample sizes such kernels see)."""
-        n = X.shape[0]
-        K = self.kernel.block(X, X)
-        alpha = jnp.linalg.solve(
-            K + self.lam * jnp.eye(n, dtype=X.dtype), Y
-        )
-        self.last_cg_iters = 0
-        return KernelBlockLinearMapper(
-            self.kernel, X, alpha, self.predict_block_size
-        )
+        alpha = kernel_block_gauss_seidel(
+            A, RowMatrix.from_array(Y), self.kernel, self.lam,
+            self.block_size, self.num_epochs)
+        return KernelBlockLinearMapper(self.kernel, X, alpha[: A.n], self.block_size)

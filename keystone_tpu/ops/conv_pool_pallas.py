@@ -155,6 +155,34 @@ def _tiles(n: int, positions: int, k: int, filters: int, itemsize: int):
     return min(tn, _SUBLANES, n), fc * _LANES
 
 
+def _patch_layout(side, k, window, stride, pool_size, pool_stride, dtype):
+    """``(oh, ow, positions, kp, pool layout)``: the patch positions of an
+    (h, w) image, how many the kernel is handed a row (those a window owns,
+    in whole sublane tiles: 8 rows of float32, 16 of bfloat16) and the
+    patch's K values padded to whole lanes."""
+    (h, w), (fh, fw) = side, window
+    oh, ow = (h - fh) // stride + 1, (w - fw) // stride + 1
+    layout = _pool_layout(oh, ow, pool_size, pool_stride)
+    rows = _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+    positions = _round_up(len(layout[3]) * _SUBLANES, rows)
+    return oh, ow, positions, _round_up(k, _LANES), layout
+
+
+def patch_arrays(images, k: int, *, window, stride: int, pool_size: int,
+                 pool_stride: int, compute_dtype=None) -> tuple:
+    """What XLA writes to the device's memory in front of the kernel for
+    ``images`` (a shape), as shapes: the explicit patches (n, oh, ow, kp)
+    and their copy in the kernel's order (n, positions, kp). 750 KB a row
+    at CIFAR's 27 x 27 positions of 108 values, whatever the filter count:
+    the arrays a caller that holds many rows has to cut along them for."""
+    n, h, w, _c = images.shape
+    dtype = jnp.dtype(compute_dtype or jnp.float32)
+    oh, ow, positions, kp, _layout = _patch_layout(
+        (h, w), k, tuple(window), stride, pool_size, pool_stride, dtype)
+    return (jax.ShapeDtypeStruct((n, oh, ow, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n, positions, kp), dtype))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "window", "stride", "alpha", "max_val", "pool_size", "pool_stride",
     "dtype", "interpret"))
@@ -163,13 +191,10 @@ def _conv_rectify_pool(images, bank, scale, bias, *, window, stride, alpha,
     n, h, w, c = images.shape
     fh, fw = window
     k, filters = bank.shape
-    oh, ow = (h - fh) // stride + 1, (w - fw) // stride + 1
-    ph, pw, regions, groups = _pool_layout(oh, ow, pool_size, pool_stride)
     dtype = jnp.dtype(dtype)
-    # Sublane tiles: 8 rows of float32, 16 of bfloat16.
-    rows = _SUBLANES * 4 // dtype.itemsize
-    positions = _round_up(len(groups) * _SUBLANES, rows)
-    kp, fp = _round_up(k, _LANES), _round_up(filters, _LANES)
+    oh, ow, positions, kp, (ph, pw, regions, groups) = _patch_layout(
+        (h, w), k, window, stride, pool_size, pool_stride, dtype)
+    fp = _round_up(filters, _LANES)
     # The explicit patches, (n, oh, ow, kp): `patches.windows`' values and
     # order (row, column, channel), cut by a convolution with one-hot
     # filters (at HIGHEST the three bf16 parts of a float32 add up to it

@@ -128,38 +128,47 @@ def build_featurizer(conf: RandomPatchCifarConfig, train_images) -> Pipeline:
     )
 
 
+def fit_features(conf: RandomPatchCifarConfig, train_images):
+    """(featurizer, fitted scaler, the train rows' scaled features): what
+    this pipeline and its kernel variant (``random_patch_cifar_kernel``)
+    put in front of their heads."""
+    rows = int(train_images.shape[0])
+    tracer = active_tracer()
+    featurizer = build_featurizer(conf, train_images)
+    # The features are computed once, here, and handed on as data.
+    # Nothing waits for them: the span covers the chain's dispatch.
+    with span_of(tracer, "features.conv", "pipeline", rows=rows,
+                 filters=conf.num_filters) as attrs:
+        sent = program_counters.get("argument_bytes")
+        features = featurizer(train_images).get()
+        if attrs is not None:
+            attrs["bytes"] = program_counters.get("argument_bytes") - sent
+    scaler = StandardScaler().fit(features)
+    # The scaled copy is what the solver reads.
+    return featurizer, scaler, scaler(features)
+
+
 def fit(conf: RandomPatchCifarConfig, train_images, train_labels) -> Pipeline:
     """Fit on the train images: the fitted pipeline, images in and the
     class index out (its stages: convolver, rectifier, pooler, vectorizer,
     scaler, block linear map, argmax). The one construction ``run`` (the
     CLI) and the benchmark share."""
     train_images = jnp.asarray(train_images)
-    rows = int(train_images.shape[0])
-    tracer = active_tracer()
     # The root span of one whole fit. It closes when the solver's programs
     # are dispatched, not when the device has run them. It carries how the
     # fit's transformer programs were found (``program_counters``): a
     # closure call is a program traced for this fit alone.
-    with span_of(tracer, "fit", "pipeline", pipeline="cifar",
-                 rows=rows) as root:
+    with span_of(active_tracer(), "fit", "pipeline", pipeline="cifar",
+                 rows=int(train_images.shape[0])) as root:
         calls = program_counters.calls()
-        featurizer = build_featurizer(conf, train_images)
-        # The features are computed once, here, and handed on as data.
-        # Nothing waits for them: the span covers the chain's dispatch.
-        with span_of(tracer, "features.conv", "pipeline", rows=rows,
-                     filters=conf.num_filters) as attrs:
-            sent = program_counters.get("argument_bytes")
-            features = featurizer(train_images).get()
-            if attrs is not None:
-                attrs["bytes"] = program_counters.get("argument_bytes") - sent
-        scaler = StandardScaler().fit(features)
+        featurizer, scaler, scaled = fit_features(conf, train_images)
         targets = ClassLabelIndicators(conf.num_classes)(train_labels)
         head = BlockLeastSquaresEstimator(
             block_size=conf.block_size,
             num_iters=conf.num_iters,
             lam=conf.lam,
-        ).with_data(scaler(features), targets)
-        del features  # the scaled copy is what the solver reads
+        ).with_data(scaled, targets)
+        del scaled
         fitted = (featurizer.and_then(scaler).and_then(head)
                   .and_then(MaxClassifier()).fit())
         if root is not None:
@@ -167,7 +176,9 @@ def fit(conf: RandomPatchCifarConfig, train_images, train_labels) -> Pipeline:
         return fitted
 
 
-def run(conf: RandomPatchCifarConfig) -> dict:
+def run(conf: RandomPatchCifarConfig, fit=fit) -> dict:
+    """Load or make the data, ``fit`` on the train images (this module's,
+    or the kernel variant's), predict the test images and evaluate."""
     if conf.train_path:
         if not conf.test_path:
             raise ValueError("--test is required when --train is given")

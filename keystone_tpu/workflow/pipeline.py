@@ -176,19 +176,25 @@ def _intermediates(chain, X):
 def _row_tiling(treedef, leaves, x, budget: int):
     """``(tile_rows, tiles)`` for the chain that ``treedef`` and ``leaves``
     (its arrays' shapes) describe, applied to a batch of ``x``'s shape and
-    dtype, or None where every intermediate fits ``budget`` bytes. Shapes
+    dtype, or None where what every step holds fits ``budget`` bytes. Shapes
     only: the stages' outputs are propagated with ``jax.eval_shape``, as
     ``workflow/analysis.py`` prices a chain; found again by structure, as
     the chain's program is."""
     chain = jax.tree_util.tree_unflatten(treedef, leaves)
     n, row_bytes = int(x.shape[0]), 0
-    for spec in jax.eval_shape(_intermediates, chain, x):
-        out = jax.tree_util.tree_leaves(spec)
-        if any(o.ndim < 1 or o.shape[0] != n for o in out):
+    between = list(jax.eval_shape(_intermediates, chain, x))
+    # A step holds its output (the last step's is no intermediate) and
+    # what it says it lays out for a kernel it runs (``scratch_with``).
+    for (stage, taken), given, out in zip(
+            _steps(chain.stages), [x] + between, between + [()]):
+        held = jax.tree_util.tree_leaves(out)
+        if taken:
+            held = held + list(stage.scratch_with(taken, given))
+        if any(o.ndim < 1 or o.shape[0] != n for o in held):
             return None  # rows do not stay rows: nothing to cut along
         row_bytes = max(row_bytes, sum(
             int(np.prod(o.shape[1:], dtype=np.int64)) * o.dtype.itemsize
-            for o in out
+            for o in held
         ))
     if n * row_bytes <= budget:
         return None
@@ -326,6 +332,13 @@ class Transformer:
         raise NotImplementedError(
             f"{type(self).__name__} takes no stage behind it"
         )
+
+    def scratch_with(self, taken: Sequence["Transformer"], x: Any) -> tuple:
+        """The arrays, as shapes, that ``apply_with(taken, X)`` writes to
+        the device's memory beside its output for an ``X`` of ``x``'s
+        shape: what its kernel needs laid out in front of it. A chain's
+        row-tile rule prices them as it prices the steps' outputs."""
+        return ()
 
     def apply_batch(self, X: Any) -> Any:
         # Host-side default: per-datum loop. Device transformers override.
@@ -632,8 +645,9 @@ class FusedTransformer(Transformer):
     def row_tiling(self, X):
         """``(tile_rows, tiles)`` where ``apply_batch`` runs ``X`` in row
         tiles, else None: a chain of jittable, row-independent stages one
-        of whose intermediates, over all of ``X``'s rows, would take more
-        than an eighth of the device's memory. From sizes the code can
+        of whose steps, over all of ``X``'s rows, would hold more than an
+        eighth of the device's memory (its output, and what it lays out
+        for a kernel it runs: ``Transformer.scratch_with``). From sizes the code can
         see (the stages' propagated shapes, ``device_hbm_bytes()``), at
         trace time and, for a span, on the host. Only a chain that shares
         its program is asked: its tiling is found by its structure too,

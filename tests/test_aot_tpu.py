@@ -321,6 +321,52 @@ def test_conv_rectify_pool_mosaic_compiles_at_cifar_fit_config(mesh):
     assert memory.temp_size_in_bytes < 7 * 2**30
 
 
+def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh):
+    """The kernel solver's one program at ``cifar-kernel-fit``'s sizes
+    (50,000 x 4,096 features, k = 10, blocks of 4,096 rows, 39 visits) for
+    one v5e chip: the loop over the visits is in it, and beside its
+    arguments it holds one kernel block (0.82 GB) and little else: never
+    the 10 GB kernel."""
+    from keystone_tpu.linalg.row_matrix import _precision
+    from keystone_tpu.nodes.learning import GaussianKernelGenerator
+    from keystone_tpu.nodes.learning.kernel_ridge import _block_solve_fn
+
+    one = Mesh(np.array(mesh.devices.flat[:1]), (AXIS,))
+    n, d, k, b = 50000, 4096, 10, 4096
+    kernel = GaussianKernelGenerator(2e-4)
+    kernel.gamma = _sds((), one, P())
+    compiled = _block_solve_fn(one, AXIS, _precision(), _fold(one), b).lower(
+        _sds((n, d), one, P(AXIS)), _sds((n, k), one, P(AXIS)), _sds((), one, P()),
+        _sds((), one, P(), jnp.int32), _sds((39,), one, P(), jnp.int32), kernel,
+    ).compile()
+    text = compiled.as_text()
+    assert "Cholesky" in text and "while" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 1.01 * (n * d * 4)
+    assert n * b * 4 <= memory.temp_size_in_bytes < 1.5 * n * b * 4
+
+
+def test_kernel_block_solve_compiles_sharded_for_v5e(mesh):
+    """The same program over the mesh: X_B and K_BB reach every chip by a
+    collective, K_B^T alpha reduces over the sharded rows, and the dual
+    weights come back replicated."""
+    from keystone_tpu.linalg.row_matrix import _precision
+    from keystone_tpu.nodes.learning import GaussianKernelGenerator
+    from keystone_tpu.nodes.learning.kernel_ridge import _block_solve_fn
+
+    n, d, k, b = 2048, 256, 10, 512
+    kernel = GaussianKernelGenerator(2e-4)
+    kernel.gamma = _sds((), mesh, P())
+    compiled = _block_solve_fn(mesh, AXIS, _precision(), _fold(mesh), b).lower(
+        _sds((n, d), mesh, P(AXIS)), _sds((n, k), mesh, P(AXIS)), _sds((), mesh, P()),
+        _sds((), mesh, P(), jnp.int32), _sds((8,), mesh, P(), jnp.int32), kernel,
+    ).compile()
+    text = compiled.as_text()
+    assert "Cholesky" in text and "while" in text
+    assert "all-reduce" in text and "all-gather" in text
+    assert _reduces_across_devices(text)
+
+
 def test_dense_sift_xla_compiles_for_v5e(mesh):
     """The on-chip dense SIFT (grouped 1-D convs) must XLA:TPU-compile —
     it is the --sift-backend xla path that moves the last host-side

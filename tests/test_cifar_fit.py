@@ -352,11 +352,11 @@ def _timit_chain():
         StandardScalerModel(np.zeros(440, np.float32), np.ones(440, np.float32)), cosines])
 
 
-def _cifar_chain():
+def _cifar_chain(filters=10000):
     conv = Convolver(np.zeros((8, 6, 6, 3), np.float32), normalize_patches=10.0)
-    conv.filters = jax.ShapeDtypeStruct((10000, 6, 6, 3), jnp.float32)
-    conv.bias = jax.ShapeDtypeStruct((10000,), jnp.float32)
-    conv.num_filters = 10000
+    conv.filters = jax.ShapeDtypeStruct((filters, 6, 6, 3), jnp.float32)
+    conv.bias = jax.ShapeDtypeStruct((filters,), jnp.float32)
+    conv.num_filters = filters
     return FusedTransformer([conv, SymmetricRectifier(alpha=0.25),
                              Pooler(13, 14, mode="sum"), ImageVectorizer()])
 
@@ -368,14 +368,19 @@ def _cifar_chain():
     (_timit_chain, (4096, 440),
      "jit_apply_StandardScalerModel_CosineRandomFeatures", None),
     (_cifar_chain, (6250, 32, 32, 3),
-     "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer", None),
-], ids=["imagenet-fit", "timit-fit", "cifar-fit"])
+     "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer", (1563, 4)),
+    (lambda: _cifar_chain(512), (50000, 32, 32, 3),
+     "jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer", (2632, 19)),
+], ids=["imagenet-fit", "timit-fit", "cifar-fit", "cifar-kernel-fit"])
 def test_the_cells_chains_at_their_sizes(chain, rows, module, tiling, monkeypatch):
-    """At the benchmark's cell sizes on a v5e's memory the three cells'
-    chains stay whole: ``cifar-fit``'s responses (58 MB a row, 36-row tiles
-    before) stay inside the convolver's kernel, and what is left between
-    its steps is the pooled sums, 2.0 GB of the 2.11 an intermediate may
-    take. The benchmark's metric files filter on the module names."""
+    """At the benchmark's cell sizes on a v5e's memory two cells' chains
+    stay whole. The convolver's chains run in row tiles: the responses
+    (58 MB a row at 10,000 filters) stay inside the kernel, but the explicit
+    patches it is handed are 750 KB a row at any filter count, 37.5 GB for
+    ``cifar-kernel-fit``'s 50,000 rows, and with the pooled sums (320 KB a
+    row in ``cifar-fit``, 16 KB in ``cifar-kernel-fit``) they are what the
+    step holds: 2.11 GB a tile. The benchmark's metric files filter on the
+    module names."""
     _budget(monkeypatch, V5E_HBM)
     chain = chain()
     assert chain.row_tiling(jax.ShapeDtypeStruct(rows, jnp.float32)) == tiling
@@ -383,16 +388,25 @@ def test_the_cells_chains_at_their_sizes(chain, rows, module, tiling, monkeypatc
         module[len("jit_"):])
 
 
-def test_the_cifar_chain_is_tiled_one_step_down(monkeypatch):
-    """The pooled sums are what the tile rule prices in ``cifar-fit``'s
-    chain: 320 KB a row. On a device an eighth smaller they would not fit."""
+def test_the_cifar_chain_is_priced_with_its_kernels_patches(monkeypatch):
+    """What the tile rule prices in ``cifar-fit``'s chain is what the
+    convolver's step holds, a row: the pooled sums (2 x 2 x 20,000 floats)
+    and the patches laid out for the kernel, as cut (27 x 27 positions of
+    128 lanes) and in the kernel's order (736 positions)."""
     chain = _cifar_chain()
     assert chain.fused_stages == 3
     x = jax.ShapeDtypeStruct((6250, 32, 32, 3), jnp.float32)
-    _budget(monkeypatch, 8 * 6250 * 2 * 2 * 20000 * 4)
+    row = (2 * 2 * 20000 + 27 * 27 * 128 + 736 * 128) * 4
+    _budget(monkeypatch, 8 * 6250 * row)
     assert chain.row_tiling(x) is None
-    _budget(monkeypatch, 8 * 6250 * 2 * 2 * 20000 * 4 - 8)
+    _budget(monkeypatch, 8 * 6250 * row - 8)
     assert chain.row_tiling(x) == (3125, 2)
+    # A stage that runs alone lays nothing out: the walk of the same
+    # stages one by one is priced by its outputs, as before.
+    walked = FusedTransformer(chain.stages[:1] + chain.stages[2:])  # no rectifier
+    assert walked.fused_stages == 0
+    _budget(monkeypatch, 8 * 6250 * 27 * 27 * 10000 * 4)
+    assert walked.row_tiling(x) is None
 
 
 # ---------------------- a convolver that takes its rectifier and its pooler

@@ -10,7 +10,7 @@ from keystone_tpu.nodes.learning import (
     DistributedPCAEstimator,
     GaussianKernelGenerator,
     GaussianMixtureModelEstimator,
-    KernelRidgeRegression,
+    KernelRidgeCG,
     KMeansPlusPlusEstimator,
     LeastSquaresEstimator,
     LinearDiscriminantAnalysis,
@@ -222,7 +222,7 @@ def test_kernel_ridge_matches_direct_solve(rng):
     X = rng.normal(size=(n, d)).astype(np.float32)
     Y = rng.normal(size=(n, k)).astype(np.float32)
     gamma, lam = 0.3, 0.1
-    est = KernelRidgeRegression(gamma=gamma, lam=lam, max_iters=400, tol=1e-7)
+    est = KernelRidgeCG(gamma=gamma, lam=lam, max_iters=400, tol=1e-7)
     model = est.fit(X, Y)
     # Direct dense oracle.
     sq = ((X[:, None, :] - X[None, :, :]) ** 2).sum(-1)
@@ -236,7 +236,7 @@ def test_kernel_ridge_matches_direct_solve(rng):
 def test_kernel_ridge_interpolates_nonlinear_function(rng):
     X = np.linspace(-3, 3, 200).reshape(-1, 1).astype(np.float32)
     Y = np.sin(2 * X)
-    model = KernelRidgeRegression(gamma=2.0, lam=1e-4, max_iters=500).fit(X, Y)
+    model = KernelRidgeCG(gamma=2.0, lam=1e-4, max_iters=500).fit(X, Y)
     pred = np.asarray(model(X))
     assert np.abs(pred - Y).max() < 0.05
 
@@ -246,7 +246,7 @@ def test_kernel_ridge_dense_fallback_linear_kernel(rng):
 
     X = rng.normal(size=(60, 4)).astype(np.float32)
     Y = rng.normal(size=(60, 2)).astype(np.float32)
-    model = KernelRidgeRegression(kernel=LinearKernelGenerator(), lam=0.5).fit(X, Y)
+    model = KernelRidgeCG(kernel=LinearKernelGenerator(), lam=0.5).fit(X, Y)
     K = X @ X.T
     alpha = np.linalg.solve(K + 0.5 * np.eye(60), Y.astype(np.float64))
     np.testing.assert_allclose(np.asarray(model.alpha), alpha, atol=1e-2)
@@ -254,7 +254,7 @@ def test_kernel_ridge_dense_fallback_linear_kernel(rng):
 
 def test_kernel_ridge_rejects_kernel_plus_gamma():
     with pytest.raises(ValueError, match="not both"):
-        KernelRidgeRegression(kernel=GaussianKernelGenerator(1.0), gamma=2.0)
+        KernelRidgeCG(kernel=GaussianKernelGenerator(1.0), gamma=2.0)
 
 
 def test_block_ls_model_parallel_matches_data_parallel(rng):
@@ -313,9 +313,9 @@ def test_kernel_ridge_nystrom_preconditioner(rng):
     X = rng.normal(size=(n, d)).astype(np.float32)
     Y = rng.normal(size=(n, k)).astype(np.float32)
     gamma, lam = 0.05, 1e-3
-    plain = KernelRidgeRegression(gamma=gamma, lam=lam, max_iters=500, tol=1e-4)
+    plain = KernelRidgeCG(gamma=gamma, lam=lam, max_iters=500, tol=1e-4)
     m_plain = plain.fit(X, Y)
-    pre = KernelRidgeRegression(
+    pre = KernelRidgeCG(
         gamma=gamma, lam=lam, max_iters=500, tol=1e-4, precond_landmarks=200
     )
     m_pre = pre.fit(X, Y)
@@ -338,7 +338,7 @@ def test_kernel_ridge_preconditioned_padded_rows(rng):
     X = rng.normal(size=(n, d)).astype(np.float32)
     Y = rng.normal(size=(n, 2)).astype(np.float32)
     gamma, lam = 0.3, 0.1
-    est = KernelRidgeRegression(
+    est = KernelRidgeCG(
         gamma=gamma, lam=lam, max_iters=400, tol=1e-7, precond_landmarks=64
     )
     model = est.fit(X, Y)

@@ -922,3 +922,23 @@ def test_solver_programs_keep_the_names_the_benchmark_filters_on():
     }
     for phase, low in lowered.items():
         assert "module @jit_local " in low.as_text(), phase
+
+
+def test_the_kernel_solvers_program_keeps_the_name_too():
+    """The block Gauss-Seidel of ``nodes/learning/kernel_ridge.py`` is a
+    solver's program to the same three readers (``cifar-kernel-fit``): it
+    carries ``jit_local`` until a ``benchmark`` PR renames both."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg import RowMatrix
+    from keystone_tpu.nodes.learning import GaussianKernelGenerator, kernel_ridge
+    from keystone_tpu.utils.mesh import fold_blocks
+
+    mesh, axis = RowMatrix.from_array(np.zeros((16, 4), np.float32)).mesh, config.data_axis
+    rows, f32, shape = 16 * mesh.shape[axis], jnp.float32, jax.ShapeDtypeStruct
+    lowered = kernel_ridge._block_solve_fn(
+        mesh, axis, kernel_ridge._precision(), fold_blocks(mesh.shape[axis]), 8).lower(
+        shape((rows, 4), f32), shape((rows, 3), f32), shape((), f32),
+        shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5))
+    assert "module @jit_local " in lowered.as_text()

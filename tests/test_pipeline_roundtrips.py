@@ -139,13 +139,13 @@ def test_sparse_csr_text_roundtrip(tmp_path):
 
 
 def test_kernel_pcg_model_roundtrip(tmp_path):
-    from keystone_tpu.nodes.learning import KernelRidgeRegression
+    from keystone_tpu.nodes.learning import KernelRidgeCG
 
     rng = np.random.default_rng(0)
     X = rng.normal(size=(128, 8)).astype(np.float32)
     Y = rng.normal(size=(128, 2)).astype(np.float32)
     pipe = (
-        KernelRidgeRegression(
+        KernelRidgeCG(
             gamma=0.2, lam=1e-2, max_iters=100, precond_landmarks=32
         )
         .with_data(X, Y)
