@@ -41,7 +41,7 @@ from keystone_tpu.nodes.images.lcs import LCSExtractor
 from keystone_tpu.nodes.learning import BlockWeightedLeastSquaresEstimator
 from keystone_tpu.nodes.util import ClassLabelIndicators, TopKClassifier
 from keystone_tpu.utils.metrics import active_tracer, program_counters, span_of
-from keystone_tpu.workflow import Pipeline
+from keystone_tpu.workflow import Pipeline, placed_batch
 
 
 def _scoring_engine(model, stream_batch: int):
@@ -371,16 +371,24 @@ def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
                  pipeline="ImageNetSiftLcsFV",
                  rows=int(len(train.data))) as attrs:
         calls = program_counters.calls()
-        featurizer = build_featurizer(conf, train.data)
-        targets = ClassLabelIndicators(num_classes)(train.labels)
-        solver = BlockWeightedLeastSquaresEstimator(
-            block_size=conf.block_size,
-            num_iters=conf.num_iters,
-            lam=conf.lam,
-            mixture_weight=conf.mixture_weight,
-            checkpoint_dir=conf.checkpoint_dir,
-        )
-        fitted = featurizer.and_then(solver, train.data, targets).fit()
+        # Four walks read the train images (each branch's descriptors,
+        # then each branch's whole chain): they reach the device once.
+        with placed_batch(train.data) as images:
+            featurizer = build_featurizer(conf, images)
+            # A node of the fit's graph, not an array made here: the walk
+            # then signs the labels (a few KB), where a (rows, classes)
+            # device array would come back to the host to be hashed whole.
+            targets = ClassLabelIndicators(num_classes).apply_pipeline(
+                train.labels
+            )
+            solver = BlockWeightedLeastSquaresEstimator(
+                block_size=conf.block_size,
+                num_iters=conf.num_iters,
+                lam=conf.lam,
+                mixture_weight=conf.mixture_weight,
+                checkpoint_dir=conf.checkpoint_dir,
+            )
+            fitted = featurizer.and_then(solver, images, targets).fit()
         if attrs is not None:
             attrs.update(program_counters.since(calls))
     return featurizer, fitted

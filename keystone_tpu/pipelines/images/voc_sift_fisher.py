@@ -25,7 +25,7 @@ from keystone_tpu.nodes.images.external.fisher_vector import (
     fit_fisher_featurizer,
 )
 from keystone_tpu.nodes.learning import BlockLeastSquaresEstimator
-from keystone_tpu.workflow import Pipeline
+from keystone_tpu.workflow import Pipeline, placed_batch
 
 
 @dataclass
@@ -88,16 +88,19 @@ def run(conf: VOCSIFTFisherConfig) -> dict:
         num_classes = conf.synthetic_classes
 
     t0 = time.perf_counter()
-    featurizer = build_featurizer(conf, train.data)
-    targets = (2.0 * train.labels - 1.0).astype(np.float32)
-    pipeline = featurizer.and_then(
-        BlockLeastSquaresEstimator(
-            block_size=conf.block_size, num_iters=conf.num_iters, lam=conf.lam
-        ),
-        train.data,
-        targets,
-    )
-    scores = np.asarray(pipeline(test.data).get())
+    # Two walks read the train images (the branch's descriptors, then its
+    # whole chain): they reach the device once.
+    with placed_batch(train.data) as images:
+        featurizer = build_featurizer(conf, images)
+        targets = (2.0 * train.labels - 1.0).astype(np.float32)
+        pipeline = featurizer.and_then(
+            BlockLeastSquaresEstimator(
+                block_size=conf.block_size, num_iters=conf.num_iters, lam=conf.lam
+            ),
+            images,
+            targets,
+        )
+        scores = np.asarray(pipeline(test.data).get())
     elapsed = time.perf_counter() - t0
 
     result = MeanAveragePrecisionEvaluator(num_classes).evaluate(

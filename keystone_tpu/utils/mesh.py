@@ -234,6 +234,16 @@ def layout_of_array(x) -> Optional[SpecLayout]:
     return SpecLayout(mesh, axis)
 
 
+def is_numeric_host_batch(data) -> bool:
+    """A numeric host array with rows: the only thing the graph's entry
+    ever places on a device."""
+    return (
+        isinstance(data, np.ndarray)
+        and data.ndim >= 1
+        and data.dtype.kind in "biufc"
+    )
+
+
 def host_batch_shard_class(data, shards: Optional[int] = None) -> str:
     """THE shardability classifier for a host batch entering the graph —
     one definition shared by the runtime placement (DatasetOperator), the
@@ -247,11 +257,7 @@ def host_batch_shard_class(data, shards: Optional[int] = None) -> str:
     - ``"pad"`` — rows never divide the mesh: the mask-pad class;
     - ``"shard"`` — rows divide the mesh: direct row-sharded placement.
     """
-    if (
-        not isinstance(data, np.ndarray)
-        or data.ndim < 1
-        or data.dtype.kind not in "biufc"
-    ):
+    if not is_numeric_host_batch(data):
         return "inert"
     if shards is None:
         try:

@@ -1925,20 +1925,25 @@ class ProgramCounters(CounterSet):
       transformer's structure (``Transformer.shares_program``): a second
       fit meets the first fit's executable
     - ``closure_program_calls``: calls of a transformer's own jitted
-      closure, traced anew for every new transformer; a fit's root span
-      carries both (``since``), and a fit that builds only transformers
-      with value-hashed static parts reads 0 here
+      closure, traced anew for every new transformer; a fit that builds
+      only transformers with value-hashed static parts reads 0 here
+    - ``dataset_fingerprints``: calls of ``array_fingerprint`` on an array
+      over 1 MiB (``workflow/fingerprint.py``): a dataset hashed for a
+      walk's prefix hashes or a disk-cache key. A fit's root span carries
+      this and the two call counts (``since``); a fit that places its
+      host batch once (``placed_batch``) reads 1 here
     """
 
-    _CALLS = ("shared_program_calls", "closure_program_calls")
+    _A_FIT = ("shared_program_calls", "closure_program_calls",
+              "dataset_fingerprints")
 
     def calls(self) -> Dict[str, int]:
-        """The two call counts as they stand: a mark for ``since``."""
-        return {key: self.get(key) for key in self._CALLS}
+        """The counts a fit reports as they stand: a mark for ``since``."""
+        return {key: self.get(key) for key in self._A_FIT}
 
     def since(self, mark: Dict[str, int]) -> Dict[str, int]:
-        """The two call counts since ``mark`` (an earlier ``calls()``)."""
-        return {key: self.get(key) - mark[key] for key in self._CALLS}
+        """Those counts since ``mark`` (an earlier ``calls()``)."""
+        return {key: self.get(key) - mark[key] for key in self._A_FIT}
 
 
 program_counters = ProgramCounters()

@@ -14,6 +14,7 @@ from keystone_tpu.workflow.analysis import (
     lint_graph,
 )
 from keystone_tpu.workflow.executor import GraphExecutor, PipelineEnv
+from keystone_tpu.workflow.operators import placed_batch
 from keystone_tpu.workflow.functional import fitted_forward
 from keystone_tpu.workflow.optimizer import (
     ChainFusionRule,
@@ -60,6 +61,7 @@ __all__ = [
     "PipelineEnv",
     "GraphExecutor",
     "fitted_forward",
+    "placed_batch",
     "Optimizer",
     "Rule",
     "ChainFusionRule",

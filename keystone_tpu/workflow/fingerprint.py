@@ -46,6 +46,26 @@ def _is_jax_array(v: Any) -> bool:
     return isinstance(v, jax.Array)
 
 
+#: An ``array_fingerprint`` of more bytes than this is counted
+#: (``program_counters``' ``dataset_fingerprints``): a fit's datasets, not
+#: its labels and hyperparameters.
+COUNTED_BYTES = 1 << 20
+
+#: id(placed jax.Array) -> the fingerprint of the host bytes it was placed
+#: from, for as long as ``operators.placed_batch`` holds the array.
+PLACED: dict = {}
+
+
+def batch_fingerprint(a) -> tuple:
+    """``array_fingerprint`` of a numeric batch wherever it lies. A batch
+    that ``operators.placed_batch`` put on the device answers with the
+    fingerprint of the host bytes it came from: nothing is hashed again
+    and nothing comes back from the device. Any other device array is
+    fetched, so the caller bounds its size."""
+    fp = PLACED.get(id(a))
+    return fp if fp is not None else array_fingerprint(np.asarray(a))
+
+
 def array_fingerprint(a: np.ndarray) -> tuple:
     """Content identity of a numeric array: shape, dtype, blake2b of bytes.
 
@@ -58,6 +78,10 @@ def array_fingerprint(a: np.ndarray) -> tuple:
     """
     from keystone_tpu.config import config
 
+    if a.nbytes > COUNTED_BYTES:
+        from keystone_tpu.utils.metrics import program_counters
+
+        program_counters.bump("dataset_fingerprints")
     h = hashlib.blake2b(digest_size=16)
     h.update(repr(a.shape).encode())
     h.update(str(a.dtype).encode())
@@ -87,7 +111,15 @@ def array_fingerprint(a: np.ndarray) -> tuple:
     for s in starts:
         if spent >= budget:
             break
-        chunk = np.ascontiguousarray(a[s : s + rows_per])
+        chunk = a[s : s + rows_per]
+        if not chunk.flags.c_contiguous:
+            # Gathered in the source's own memory order first (one pass
+            # forwards over its pages), put in C order once it is small and
+            # in cache: the same bytes, in a third of the time where the
+            # rows are the minor axis, as ``np.asarray`` of a TPU array
+            # hands them back.
+            chunk = chunk.copy(order="K")
+        chunk = np.ascontiguousarray(chunk)
         mv = memoryview(chunk).cast("B")[:cap]
         h.update(str(chunk.nbytes).encode())
         h.update(mv)

@@ -9,6 +9,7 @@ single datum, or a fitted Transformer.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from typing import Any, List, Sequence
 
 import jax.numpy as jnp
@@ -49,6 +50,57 @@ class Operator:
 
     def label(self) -> str:
         return type(self).__name__
+
+
+@contextmanager
+def placed_batch(data: Any):
+    """A host batch that several walks of one fit read, on the device for
+    as long as the ``with`` lasts: one upload and one fingerprint where
+    every walk's ``DatasetOperator`` would hash it and every chain's
+    program upload it again.
+
+    Yields what the walks should read. A numeric host array is placed by
+    ``DatasetOperator.execute``'s rule (rows over the default mesh where
+    they divide it, a plain ``device_put`` on one device); what that rule
+    leaves on the host for a chain to stage (a batch below
+    ``config.shard_min_rows``, rows that do not divide the mesh), what is
+    not a numeric host array and what is on a device already come back
+    untouched. The placed array is the caller's: no program takes it as a
+    donated argument, and nothing here outlives the ``with`` — a second
+    fit of the same host array uploads and hashes again, as a decoder's
+    next output would.
+
+    The upload is dispatched first and the host bytes are hashed while it
+    is in flight. ``DatasetOperator.signature`` and
+    ``fingerprint.batch_fingerprint`` read that fingerprint for the placed
+    array, so every prefix hash and disk-cache key is the host array's.
+    One ``data.place`` span (``bytes``: what crossed to the device).
+    """
+    import jax
+
+    from keystone_tpu.config import config
+    from keystone_tpu.utils.mesh import (
+        host_batch_shard_class,
+        is_numeric_host_batch,
+    )
+    from keystone_tpu.utils.metrics import active_tracer, span_of
+    from keystone_tpu.workflow import fingerprint
+
+    klass = host_batch_shard_class(data) if config.shard_data_batches else "inert"
+    if not is_numeric_host_batch(data) or klass in ("small", "pad"):
+        yield data
+        return
+    with span_of(active_tracer(), "data.place", "pipeline",
+                 bytes=int(data.nbytes), rows=int(data.shape[0])):
+        if klass == "shard":  # the operator's own placement, counted as its
+            placed = DatasetOperator(data).execute([])
+        else:
+            placed = jax.device_put(data)
+        fingerprint.PLACED[id(placed)] = fingerprint.array_fingerprint(data)
+    try:
+        yield placed
+    finally:
+        del fingerprint.PLACED[id(placed)]
 
 
 class DatasetOperator(Operator):
@@ -118,10 +170,12 @@ class DatasetOperator(Operator):
         return jax.device_put(data, data_sharding())
 
     def signature(self):
-        """Content fingerprint for numeric host arrays (hashed once per
-        operator), id fallback otherwise. Content identity means a rerun —
-        or another process — that splices byte-identical data shares cached
-        fits downstream."""
+        """Content fingerprint for numeric arrays, id fallback otherwise.
+        Content identity means a rerun — or another process — that splices
+        byte-identical data shares cached fits downstream. A host array is
+        hashed once per operator, and every walk makes a new operator; a
+        batch that ``placed_batch`` put on the device was hashed once, on
+        the host, and answers with that fingerprint for every walk."""
         sig = getattr(self, "_sig_cache", None)
         if sig is None:
             import jax
@@ -129,6 +183,7 @@ class DatasetOperator(Operator):
 
             from keystone_tpu.workflow.fingerprint import (
                 UNSTABLE,
+                PLACED,
                 array_fingerprint,
             )
 
@@ -136,6 +191,10 @@ class DatasetOperator(Operator):
 
             data = self.data
             if isinstance(data, jax.Array):
+                placed = PLACED.get(id(data))
+                if placed is not None:
+                    self._sig_cache = ("dataset", placed)
+                    return self._sig_cache
                 if data.nbytes > config.fingerprint_max_bytes:
                     # Sampled hashing would still need the full D2H copy
                     # for a device array; not worth it.

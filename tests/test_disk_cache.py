@@ -115,17 +115,29 @@ class TestStableSignatures:
         assert array_fingerprint(b)[3] != dig
         assert array_fingerprint(a.copy())[3] == dig  # deterministic
 
-    def test_sampled_fingerprint_layout_independent(self, monkeypatch):
-        """The same logical matrix, C- vs F-contiguous, must digest equal —
-        the cross-process cache key can't depend on who materialized it."""
+    @pytest.mark.parametrize("shape, order", [
+        ((64, 2048), (1, 0)),  # F-contiguous
+        # Rows the minor axis of an image batch: what ``np.asarray`` of a
+        # TPU array hands back, and what the sampled pass gathers in the
+        # source's own memory order first.
+        ((512, 8, 8, 3), (1, 3, 2, 0)),
+    ], ids=["fortran", "rows_minor"])
+    def test_sampled_fingerprint_layout_independent(self, monkeypatch, shape, order):
+        """The same logical array, C-contiguous or laid out along other
+        axes, must digest equal — the cross-process cache key can't depend
+        on who materialized it."""
         from keystone_tpu.config import config
         from keystone_tpu.workflow.fingerprint import array_fingerprint
 
         monkeypatch.setattr(config, "fingerprint_max_bytes", 1 << 16)
         rng = np.random.default_rng(3)
-        c = np.ascontiguousarray(rng.normal(size=(64, 2048)).astype(np.float32))
-        f = np.asfortranarray(c)
-        assert not f.flags.c_contiguous and f.flags.f_contiguous
+        c = np.ascontiguousarray(rng.normal(size=shape).astype(np.float32))
+        # ``order``: the axes from the slowest-varying in memory to the fastest.
+        f = np.ascontiguousarray(c.transpose(order)).transpose(np.argsort(order))
+        assert f.shape == c.shape and not f.flags.c_contiguous
+        assert f.strides[order[-1]] == f.itemsize
+        np.testing.assert_array_equal(f, c)
+        assert array_fingerprint(c)[0] == "ndarray-sampled"
         assert array_fingerprint(c) == array_fingerprint(f)
 
     def test_sampled_fingerprint_noncontiguous_probed(self, monkeypatch):

@@ -50,6 +50,40 @@ def test_shards_are_disjoint_and_cover():
         list(ImageNetLoader.iter_jobs(root, label_map, shard=(2, 2)))
 
 
+@pytest.mark.parametrize("rows, klass", [(128, "shard"), (125, "pad"), (24, "small")])
+def test_placed_batch_follows_the_dataset_operators_rule_on_the_mesh(rows, klass):
+    """On the fake mesh ``placed_batch`` puts a divisible host batch where
+    ``DatasetOperator.execute`` would, and leaves one the chain has to
+    mask-pad (or one under the row floor) on the host, untouched, for
+    every walk's operator to defer as before."""
+    import jax
+
+    from keystone_tpu.utils.mesh import data_sharding, host_batch_shard_class
+    from keystone_tpu.utils.metrics import sharding_counters
+    from keystone_tpu.workflow import placed_batch
+    from keystone_tpu.workflow.operators import DatasetOperator
+
+    X = np.arange(rows * 6, dtype=np.float32).reshape(rows, 6)
+    assert host_batch_shard_class(X) == klass
+    want = DatasetOperator(X).execute([])
+    before = sharding_counters.snapshot()
+    with placed_batch(X) as placed:
+        after = sharding_counters.snapshot()
+        if klass != "shard":
+            assert placed is X and want is X and after == before
+            return
+        assert isinstance(placed, jax.Array) and placed.sharding == data_sharding()
+        assert placed.sharding == want.sharding
+        assert after.get("batches_sharded") == before.get("batches_sharded", 0) + 1
+        np.testing.assert_array_equal(np.asarray(placed), X)
+        # Placed for every walk: an operator over it places nothing again
+        # and signs as the host array's would.
+        op = DatasetOperator(placed)
+        assert op.execute([]) is placed
+        assert sharding_counters.snapshot() == after
+        assert op.signature() == DatasetOperator(X).signature()
+
+
 _WORKER = r"""
 import json, os, sys
 sys.path.insert(0, {repo!r})
