@@ -32,6 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from keystone_tpu.config import config
 from keystone_tpu.utils.mesh import fold_blocks, register_reshard_adapter
+from keystone_tpu.utils.metrics import device_scope
 from keystone_tpu.linalg.row_matrix import (
     RowMatrix,
     _precision,
@@ -46,7 +47,8 @@ from keystone_tpu.linalg.row_matrix import (
 
 
 def _local_weighted(a_b, w_rows, weighted: bool):
-    return a_b * w_rows[:, None] if weighted else a_b
+    with device_scope("solver.update"):
+        return a_b * w_rows[:, None] if weighted else a_b
 
 
 def _local_gram_inv(a_b, aw, lam, precision, axis, width):
@@ -63,22 +65,25 @@ def _local_gram_inv(a_b, aw, lam, precision, axis, width):
     the λ-regularized SPD gram keeps it well-conditioned, and later epochs
     re-solve against the residual, so per-epoch solve error self-corrects
     instead of accumulating."""
-    gram = sharded_rowsum(
-        lambda awb, ab: solver_matmul(awb.T, ab, precision),
-        axis, width, (aw, a_b),
-    )
-    b = a_b.shape[1]
-    return _batched_spd_inv(gram + lam * jnp.eye(b, dtype=gram.dtype))
+    with device_scope("solver.gram"):
+        gram = sharded_rowsum(
+            lambda awb, ab: solver_matmul(awb.T, ab, precision),
+            axis, width, (aw, a_b),
+        )
+        b = a_b.shape[1]
+        gram = gram + lam * jnp.eye(b, dtype=gram.dtype)
+    return _batched_spd_inv(gram)
 
 
 def _local_solve_update(a_b, aw, inv, r, w_b, precision, axis, width):
-    r_plus = r + solver_matmul(a_b, w_b, precision)
-    rhs = sharded_rowsum(
-        lambda awb, rb: solver_matmul(awb.T, rb, precision),
-        axis, width, (aw, r_plus),
-    )
-    w_b_new = solver_matmul(inv, rhs, precision)
-    r_new = r_plus - solver_matmul(a_b, w_b_new, precision)
+    with device_scope("solver.update"):
+        r_plus = r + solver_matmul(a_b, w_b, precision)
+        rhs = sharded_rowsum(
+            lambda awb, rb: solver_matmul(awb.T, rb, precision),
+            axis, width, (aw, r_plus),
+        )
+        w_b_new = solver_matmul(inv, rhs, precision)
+        r_new = r_plus - solver_matmul(a_b, w_b_new, precision)
     return r_new, w_b_new
 
 
@@ -158,7 +163,10 @@ def _batched_spd_inv(grams, leaf: int = _INV_LEAF):
     at batch 8, leaf 1024), which at b = 8192 against the whole identity
     failed v5e buffer assignment. ``leaf`` is for tests."""
     assert leaf >= 1, f"leaf must be >= 1, got {leaf}"
-    return _tri_gram(_tri_inv(jnp.linalg.cholesky(grams), leaf), leaf)
+    with device_scope("solver.cholesky"):
+        chol = jnp.linalg.cholesky(grams)
+    with device_scope("solver.inverse"):
+        return _tri_gram(_tri_inv(chol, leaf), leaf)
 
 
 def _pad_ridge(lam, nb: int, b: int, pad: int):
@@ -180,10 +188,11 @@ def _stack_blocks_fn(mesh: Mesh, axis: str, nb: int, pad: int = 0):
     (0, and the program without them, where the blocks tile d)."""
 
     def local(a):
-        if pad:
-            a = jnp.pad(a, ((0, 0), (0, pad)))
-        r, d = a.shape
-        return jnp.moveaxis(a.reshape(r, nb, d // nb), 1, 0)
+        with device_scope("solver.stack"):
+            if pad:
+                a = jnp.pad(a, ((0, 0), (0, pad)))
+            r, d = a.shape
+            return jnp.moveaxis(a.reshape(r, nb, d // nb), 1, 0)
 
     sm = shard_map(
         local,
@@ -206,17 +215,19 @@ def _fused_factor_fn(mesh: Mesh, axis: str, precision, weighted: bool,
     width = mesh.shape[axis]
 
     def local(a3, lam, w_rows):  # a3: (chunk, rows_shard, b)
-        aw = a3 * w_rows[None, :, None] if weighted else a3
-        gram = sharded_rowsum(
-            lambda awb, ab: solver_matmul(
-                jnp.swapaxes(awb, 1, 2), ab, precision
-            ),
-            axis, width, (aw, a3), row_axes=(1, 1),
-        )
-        b = a3.shape[2]
-        if pad:
-            lam = _pad_ridge(lam, a3.shape[0], b, pad)
-        return _batched_spd_inv(gram + lam * jnp.eye(b, dtype=gram.dtype))
+        with device_scope("solver.gram"):
+            aw = a3 * w_rows[None, :, None] if weighted else a3
+            gram = sharded_rowsum(
+                lambda awb, ab: solver_matmul(
+                    jnp.swapaxes(awb, 1, 2), ab, precision
+                ),
+                axis, width, (aw, a3), row_axes=(1, 1),
+            )
+            b = a3.shape[2]
+            if pad:
+                lam = _pad_ridge(lam, a3.shape[0], b, pad)
+            gram = gram + lam * jnp.eye(b, dtype=gram.dtype)
+        return _batched_spd_inv(gram)
 
     sm = shard_map(
         local,
@@ -268,7 +279,11 @@ def _fused_epochs_fn(
             rc, w3c = lax.scan(block_step, rc, (a3, invs, w3c) + ridge)
             return (rc, w3c), None
 
-        (r, w3), _ = lax.scan(epoch_step, (r, w3), None, length=num_epochs)
+        # The scans' own slices and stacked results belong to the update;
+        # the uncached body's gram, Cholesky and inverse are scopes inside.
+        with device_scope("solver.update"):
+            (r, w3), _ = lax.scan(
+                epoch_step, (r, w3), None, length=num_epochs)
         return r, w3
 
     sm = shard_map(
@@ -478,10 +493,7 @@ def _solve_fused(
         # Chunked: bounds the factor transient to chunk·b² buffers instead
         # of nb·b².
         chunk = _factor_chunk(b)
-        # leaf and levels say whether the blocked inverse engaged: 0 levels
-        # is one leaf, the unblocked path.
-        with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk,
-                     leaf=_INV_LEAF, levels=_inv_levels(b)):
+        with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk):
             if chunk >= nb:
                 invs = _fused_factor_fn(
                     mesh, axis, precision, weighted, fold, pad

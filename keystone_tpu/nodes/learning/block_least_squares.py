@@ -234,41 +234,47 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 ) @ W
             return BlockLinearMapper(W_blocks, blocks, b)
 
-        X = jnp.asarray(data)
-        Y = jnp.asarray(labels)
-        weights = self._weights(Y)
         from keystone_tpu.linalg.row_matrix import storage_dtype
+        from keystone_tpu.utils.metrics import active_tracer, span_of
 
-        full = jnp.dtype(config.default_dtype)
-        x_mean = y_mean = None
-        if self.fit_intercept:
-            # Weighted problems need weighted centering: the intercept of
-            # weighted ridge absorbs the weighted means, b = ȳ_w − x̄_wᵀW.
-            # The means ride the same re-shard + per-shard-sum + psum path
-            # as the grams (RowMatrix.col_sums), so a fit over a sharded
-            # batch is bit-identical to one over the same bytes on a
-            # single device — no host-side fold, and no dependence on
-            # whatever placement the features arrived with. Centering
-            # derives on-device from the ONE placed copy
-            # (RowMatrix.centered: subtract, re-zero pad rows, cast) —
-            # no second host-to-device transfer of X.
-            Ax = RowMatrix.from_array(X, dtype=X.dtype)
-            Ay = RowMatrix.from_array(Y, dtype=Y.dtype)
-            if weights is None:
-                x_mean = Ax.col_sums() / Ax.n
-                y_mean = Ay.col_sums() / Ay.n
+        # What the device path dispatches in front of the solve: weights,
+        # means, centring, the padded row matrices. It waits for nothing.
+        with span_of(active_tracer(), "solver.setup", "solver",
+                     rows=int(np.shape(data)[0]), dim=int(np.shape(data)[-1]),
+                     classes=int(np.shape(labels)[-1]) if np.ndim(labels) > 1 else 1):
+            X = jnp.asarray(data)
+            Y = jnp.asarray(labels)
+            weights = self._weights(Y)
+            full = jnp.dtype(config.default_dtype)
+            x_mean = y_mean = None
+            if self.fit_intercept:
+                # Weighted problems need weighted centering: the intercept
+                # of weighted ridge absorbs the weighted means, b = ȳ_w −
+                # x̄_wᵀW. The means ride the same re-shard + per-shard-sum +
+                # psum path as the grams (RowMatrix.col_sums), so a fit over
+                # a sharded batch is bit-identical to one over the same
+                # bytes on a single device — no host-side fold, and no
+                # dependence on whatever placement the features arrived
+                # with. Centering derives on-device from the ONE placed copy
+                # (RowMatrix.centered: subtract, re-zero pad rows, cast) —
+                # no second host-to-device transfer of X.
+                Ax = RowMatrix.from_array(X, dtype=X.dtype)
+                Ay = RowMatrix.from_array(Y, dtype=Y.dtype)
+                if weights is None:
+                    x_mean = Ax.col_sums() / Ax.n
+                    y_mean = Ay.col_sums() / Ay.n
+                else:
+                    Aw = RowMatrix.from_array(
+                        weights[:, None], dtype=weights.dtype
+                    )
+                    wsum = jnp.maximum(Aw.col_sums()[0], 1e-12)
+                    x_mean = Ax.weighted_col_sums(Aw) / wsum
+                    y_mean = Ay.weighted_col_sums(Aw) / wsum
+                A = Ax.centered(x_mean, dtype=storage_dtype())
+                B = Ay.centered(y_mean, dtype=full)
             else:
-                Aw = RowMatrix.from_array(
-                    weights[:, None], dtype=weights.dtype
-                )
-                wsum = jnp.maximum(Aw.col_sums()[0], 1e-12)
-                x_mean = Ax.weighted_col_sums(Aw) / wsum
-                y_mean = Ay.weighted_col_sums(Aw) / wsum
-            A = Ax.centered(x_mean, dtype=storage_dtype())
-            B = Ay.centered(y_mean, dtype=full)
-        else:
-            A = RowMatrix.from_array(X, dtype=storage_dtype())
-            B = RowMatrix.from_array(Y)
+                A = RowMatrix.from_array(X, dtype=storage_dtype())
+                B = RowMatrix.from_array(Y)
         W_blocks, blocks = block_coordinate_descent(
             A,
             B,

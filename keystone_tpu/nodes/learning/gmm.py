@@ -20,7 +20,12 @@ import jax.numpy as jnp
 
 from keystone_tpu.config import config
 from keystone_tpu.nodes.learning.kmeans import _fit_kmeans, _sq_dists
-from keystone_tpu.utils.metrics import active_tracer, span_of, upload_nbytes
+from keystone_tpu.utils.metrics import (
+    active_tracer,
+    device_scope,
+    span_of,
+    upload_nbytes,
+)
 from keystone_tpu.workflow import Estimator, Transformer
 
 # HIGHEST precision throughout: ||(x - μ)/σ||² is expanded into gemm-shaped
@@ -83,17 +88,19 @@ def _fit_gmm(X, key, k: int, max_iters: int, min_var: float):
 
     def em(_i, carry):
         weights, means, variances = carry
-        quad = _quad(X, means, 1.0 / variances)
-        log_norm = -0.5 * (
-            d * jnp.log(2 * jnp.pi) + jnp.sum(jnp.log(variances), axis=1)
-        )
-        log_r = jnp.log(weights) + log_norm - 0.5 * quad
-        r = jax.nn.softmax(log_r, axis=-1)  # (n, k)
-        nk = jnp.maximum(r.sum(axis=0), 1e-6)
-        new_means = _mm(r.T, X) / nk[:, None]
-        new_ex2 = _mm(r.T, X * X) / nk[:, None]
-        new_vars = jnp.maximum(new_ex2 - new_means**2, min_var)
-        return nk / n, new_means, new_vars
+        with device_scope("gmm.estep"):
+            quad = _quad(X, means, 1.0 / variances)
+            log_norm = -0.5 * (
+                d * jnp.log(2 * jnp.pi) + jnp.sum(jnp.log(variances), axis=1)
+            )
+            log_r = jnp.log(weights) + log_norm - 0.5 * quad
+            r = jax.nn.softmax(log_r, axis=-1)  # (n, k)
+        with device_scope("gmm.mstep"):
+            nk = jnp.maximum(r.sum(axis=0), 1e-6)
+            new_means = _mm(r.T, X) / nk[:, None]
+            new_ex2 = _mm(r.T, X * X) / nk[:, None]
+            new_vars = jnp.maximum(new_ex2 - new_means**2, min_var)
+            return nk / n, new_means, new_vars
 
     return jax.lax.fori_loop(0, max_iters, em, (weights0, means0, vars0))
 

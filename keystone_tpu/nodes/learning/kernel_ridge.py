@@ -47,7 +47,7 @@ from keystone_tpu.linalg.row_matrix import (
 )
 from keystone_tpu.nodes.learning.kernels import KernelGenerator
 from keystone_tpu.utils.mesh import fold_blocks
-from keystone_tpu.utils.metrics import active_tracer, span_of
+from keystone_tpu.utils.metrics import active_tracer, device_scope, span_of
 from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
@@ -138,25 +138,29 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
             return lax.psum(jnp.where(mine[:, None], held, 0.0), axis)
 
         def visit(alpha, start):
-            col_live = start + jnp.arange(block) < n
-            x_b = take_block(xl, start)
-            k_b = jnp.where(row_live[:, None] & col_live[None, :],
-                            kernel.block(xl, x_b), 0.0)
-            k_bb = take_block(k_b, start)
-            kt_alpha = sharded_rowsum(
-                lambda kb, al: solver_matmul(kb.T, al, precision),
-                axis, width, (k_b, alpha),
-            )
-            r = _block_residual(take_block(yl, start), kt_alpha, k_bb,
-                                take_block(alpha, start), precision)
-            ridge = _ridge_diagonal(col_live, lam)
-            chol = jnp.linalg.cholesky(
-                k_bb + ridge[:, None] * jnp.eye(block, dtype=k_bb.dtype))
-            alpha_b = cho_solve((chol, True), r)
-            at = row_id - start
-            inside = (at >= 0) & (at < block)
-            return jnp.where(inside[:, None],
-                             alpha_b[jnp.clip(at, 0, block - 1)], alpha), None
+            with device_scope("krr.generate"):
+                col_live = start + jnp.arange(block) < n
+                x_b = take_block(xl, start)
+                k_b = jnp.where(row_live[:, None] & col_live[None, :],
+                                kernel.block(xl, x_b), 0.0)
+            with device_scope("krr.reduce"):
+                k_bb = take_block(k_b, start)
+                kt_alpha = sharded_rowsum(
+                    lambda kb, al: solver_matmul(kb.T, al, precision),
+                    axis, width, (k_b, alpha),
+                )
+                r = _block_residual(take_block(yl, start), kt_alpha, k_bb,
+                                    take_block(alpha, start), precision)
+            with device_scope("krr.factor"):
+                ridge = _ridge_diagonal(col_live, lam)
+                chol = jnp.linalg.cholesky(
+                    k_bb + ridge[:, None] * jnp.eye(block, dtype=k_bb.dtype))
+            with device_scope("krr.solve"):
+                alpha_b = cho_solve((chol, True), r)
+                at = row_id - start
+                inside = (at >= 0) & (at < block)
+                return jnp.where(inside[:, None],
+                                 alpha_b[jnp.clip(at, 0, block - 1)], alpha), None
 
         alpha, _ = lax.scan(visit, jnp.zeros_like(yl), starts)
         # The model's weights are replicated, as a linear map's are.

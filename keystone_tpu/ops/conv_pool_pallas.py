@@ -41,6 +41,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from keystone_tpu.utils.metrics import device_scope
+
 _LANES = 128
 _SUBLANES = 8
 # What the kernel asks of the compiler (Mosaic's own default is 16 MiB on a
@@ -200,26 +202,27 @@ def _conv_rectify_pool(images, bank, scale, bias, *, window, stride, alpha,
     # filters (at HIGHEST the three bf16 parts of a float32 add up to it
     # again). Slices of the c-channel image, c values a lane tile, cost XLA
     # 50 times the patches' memory at 6,250 rows; this writes whole lanes.
-    patches = lax.conv_general_dilated(
-        images, jnp.eye(k, kp, dtype=images.dtype).reshape(fh, fw, c, kp),
-        (stride, stride), "VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=lax.Precision.HIGHEST,
-    )
-    if scale is not None:
-        patches = patches * scale
-    ordered = jnp.concatenate([
-        patches[:, r0:r1, c0:c1, :].reshape(n, -1, kp)
-        for r0, r1, c0, c1 in regions
-    ], axis=1).astype(dtype)
-    ordered = jnp.pad(
-        ordered, ((0, 0), (0, positions - ordered.shape[1]), (0, 0))
-    )
-    bank = jnp.pad(bank.astype(dtype), ((0, kp - k), (0, fp - filters)))
-    bias = jnp.pad(bias.astype(jnp.float32), (0, fp - filters))[None, :]
+    with device_scope("conv.patches"):
+        patches = lax.conv_general_dilated(
+            images, jnp.eye(k, kp, dtype=images.dtype).reshape(fh, fw, c, kp),
+            (stride, stride), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=lax.Precision.HIGHEST,
+        )
+        if scale is not None:
+            patches = patches * scale
+        ordered = jnp.concatenate([
+            patches[:, r0:r1, c0:c1, :].reshape(n, -1, kp)
+            for r0, r1, c0, c1 in regions
+        ], axis=1).astype(dtype)
+        ordered = jnp.pad(
+            ordered, ((0, 0), (0, positions - ordered.shape[1]), (0, 0))
+        )
+        bank = jnp.pad(bank.astype(dtype), ((0, kp - k), (0, fp - filters)))
+        bias = jnp.pad(bias.astype(jnp.float32), (0, fp - filters))[None, :]
     groups = groups + ((),) * (positions // _SUBLANES - len(groups))
     tn, fc = _tiles(n, positions, kp, fp, dtype.itemsize)
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _kernel, groups=groups, windows=ph * pw, alpha=alpha,
             max_val=max_val,
@@ -241,9 +244,12 @@ def _conv_rectify_pool(images, bank, scale, bias, *, window, stride, alpha,
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(ordered, bank, bias)
+    )
+    with device_scope("conv.kernel"):
+        out = kernel(ordered, bank, bias)
     # (n, windows, half, filter) -> the rectifier's channel order [pos | neg].
-    return out[..., :filters].reshape(n, ph, pw, 2 * filters)
+    with device_scope("conv.relayout"):
+        return out[..., :filters].reshape(n, ph, pw, 2 * filters)
 
 
 def conv_rectify_pool(

@@ -342,6 +342,40 @@ class Tracer:
         return doc
 
 
+#: The ``jax.named_scope`` names inside the device programs, written once:
+#: the sites enter them through ``device_scope`` and
+#: ``utils/device_trace.py`` puts a profiler trace's device seconds down to
+#: them. A scope acts at trace time only (it extends the operations'
+#: ``op_name``; the compile cache's key leaves metadata out), so it is
+#: always on. Constant names: no shape, id or count. A device scope whose
+#: work a host span dispatches takes that span's name.
+DEVICE_SCOPES = (
+    "solver.stack", "solver.gram", "solver.cholesky", "solver.inverse",
+    "solver.update",
+    "krr.generate", "krr.reduce", "krr.factor", "krr.solve",
+    "conv.patches", "conv.kernel", "conv.relayout",
+    "gmm.estep", "gmm.mstep",
+)
+
+#: A fused chain's step is scoped by this and its stage's class name, as the
+#: walk's host spans are (``node:<label>``); a step in which a stage took the
+#: ones behind it joins the names with ``+``.
+STAGE_SCOPE_PREFIX = "node:"
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)`` for one of ``DEVICE_SCOPES``."""
+    assert name in DEVICE_SCOPES, name
+    return jax.named_scope(name)
+
+
+def stage_scope(stage, taken=()):
+    """The scope of one step of a fused chain: ``node:<Stage>``, or
+    ``node:<Stage>+<Taken>+...`` where the stage runs the ones behind it."""
+    return jax.named_scope(STAGE_SCOPE_PREFIX + "+".join(
+        s._program_name() for s in (stage, *taken)))
+
+
 _tracer_lock = threading.Lock()
 _tracer: Optional[Tracer] = None
 _tracer_key: Optional[int] = None

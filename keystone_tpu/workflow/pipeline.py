@@ -21,12 +21,14 @@ values are (possibly sharded) device arrays.
 from __future__ import annotations
 
 import functools
+from contextlib import nullcontext
 from typing import Any, Callable, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.utils.metrics import stage_scope
 from keystone_tpu.workflow.graph import (
     Graph,
     GraphId,
@@ -73,9 +75,13 @@ def _program(name: str, layout=None, donate: bool = False):
     rows sharded in and out)."""
 
     def apply(transformer, X):
-        if layout is None:
-            return transformer.apply_batch(X)
-        return transformer.apply_sharded(X, layout)
+        # A chain's walk scopes each of its steps; a transformer that is a
+        # program by itself is its one stage.
+        with (nullcontext() if isinstance(transformer, FusedTransformer)
+              else stage_scope(transformer)):
+            if layout is None:
+                return transformer.apply_batch(X)
+            return transformer.apply_sharded(X, layout)
 
     apply.__name__ = ("apply_" + name)[:96]
     if layout is None:
@@ -161,7 +167,8 @@ def _walk(stages, X):
     """``X`` after every step of the chain, in order."""
     out = []
     for stage, taken in _steps(stages):
-        X = stage.apply_with(taken, X) if taken else stage.apply_batch(X)
+        with stage_scope(stage, taken):
+            X = stage.apply_with(taken, X) if taken else stage.apply_batch(X)
         out.append(X)
     return out
 
@@ -670,7 +677,8 @@ class FusedTransformer(Transformer):
         # Thread the layout so stages with a sharded kernel strategy
         # (Pallas shard_map on TPU) see it inside the ONE fused lowering.
         for s in self.stages:
-            X = s.apply_sharded(X, layout)
+            with stage_scope(s):
+                X = s.apply_sharded(X, layout)
         return X
 
     def _program_name(self):

@@ -139,7 +139,9 @@ def test_fisher_estimator_leaves_a_device_array_on_the_device(
         np.asarray(sample), _host_sample(host.reshape(-1, 6), 40, seed=1))
     names = [s["name"] for s in tracer.spans()]
     assert "fisher.fetch" not in names
-    assert ("fisher.flatten" in names) == (where == "host")
+    # The host's flatten is a view with no span of its own: ``on_device``
+    # on ``fisher.sample`` says where the descriptors lay.
+    assert "fisher.flatten" not in names
     (drawn,) = [s for s in tracer.spans() if s["name"] == "fisher.sample"]
     assert drawn["args"]["on_device"] == (where == "device")
     assert drawn["args"]["rows_in"] == N * M and drawn["args"]["rows_out"] == 40

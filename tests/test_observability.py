@@ -25,6 +25,7 @@ What is pinned here:
 import importlib.util
 import json
 import os
+import sys
 import threading
 
 import numpy as np
@@ -847,8 +848,8 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     assert all(s["root_id"] == root["id"] for s in spans)
     names = {s["name"] for s in spans}
     table = {"fit", "pipeline.fit", "fisher.describe", "fisher.sample",
-             "fisher.project", "pca.fit", "gmm.fit",
-             "solver.stack", "solver.factor", "solver.epochs",
+             "fisher.project", "pca.fit", "gmm.fit", "fisher.mixture",
+             "solver.setup", "solver.stack", "solver.factor", "solver.epochs",
              "jax.trace", "jax.lower", "jax.compile"}
     assert table <= names, table - names
     assert any(n.startswith("node:") for n in names)
@@ -856,6 +857,33 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     for s in spans:  # every solver span lies under the solver's node
         if s["name"].startswith("solver."):
             assert by_id[s["parent_id"]]["name"].startswith("node:Block")
+    # What the root kept for itself until PR 36: the encoder's fetch of the
+    # fitted mixture, once a branch, right under the root.
+    mixtures = [s for s in spans if s["name"] == "fisher.mixture"]
+    assert [m["parent_id"] for m in mixtures] == [root["id"]] * 2
+    assert all(m["args"] == {"k": 4, "on_device": 1, "parent": "fit"} for m in mixtures)
+    (setup,) = [s for s in spans if s["name"] == "solver.setup"]
+    assert {k: setup["args"][k] for k in ("rows", "dim", "classes")} == {
+        "rows": 48, "dim": 2 * 2 * 4 * 8, "classes": 4}
+    stack = next(s for s in spans if s["name"] == "solver.stack")
+    assert setup["start_ns"] + setup["dur_ns"] <= stack["start_ns"]
+    # The benchmark's reader sees the new children: the root's own time and
+    # the solver's node's are what no span below them names.
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    try:
+        import spanreaders
+
+        ctx = {"fits": 1}
+        window = spanreaders.window(ctx, ring=spans)
+        setup_ms = spanreaders.span_self_ms(ctx, "solver.setup")
+        coverage = spanreaders.span_coverage(ctx)
+    finally:
+        sys.path.pop(0)
+    assert window["self_ns"]["solver.setup"] > 0
+    assert setup_ms == pytest.approx(window["self_ns"]["solver.setup"] / 1e6)
+    assert window["self_ns"]["fisher.mixture"] == sum(m["dur_ns"] for m in mixtures)
+    children = sum(s["dur_ns"] for s in spans if s["parent_id"] == root["id"])
+    assert coverage == pytest.approx(100.0 * children / root["dur_ns"], abs=0.5)
     # The descriptors never come to the host: nothing is fetched, nothing
     # flattened there, and each branch's sample is gathered on the device
     # from all of its descriptors.
@@ -870,6 +898,13 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     sampled = [s["args"] for s in spans if s["name"] == "fisher.sample"]
     assert len(described) == len(sampled) == 2
     assert [{k: a[k] for k in e} for a, e in zip(sampled, expected)] == expected
+    # Each branch's spans say which branch and how much they moved: the
+    # descriptors' width on ``fisher.describe``, the sample's rows on
+    # ``fisher.project``, and no byte uploaded for the PCA or the mixture.
+    assert [by_id[d["parent_id"]]["args"]["branch"] for d in described] == [
+        d["args"]["shape"][-1] for d in described]
+    assert [s["args"]["rows"] for s in spans if s["name"] == "fisher.project"] == [1000] * 2
+    assert [s["args"]["bytes"] for s in spans if s["name"] in ("pca.fit", "gmm.fit")] == [0] * 4
     # The same spans are in the session's trace, on the profiler's clock.
     mirrors = _host_annotations(trace_dir)
     assert {"ks:" + n for n in table if not n.startswith("jax.")} <= set(mirrors)
@@ -878,10 +913,15 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
             for m, e in zip(mirrors["ks:fisher.sample"], expected)] == expected
 
 
-def test_solver_factor_span_says_whether_the_blocked_inverse_engaged(traced, rng):
-    """``solver.factor`` carries ``leaf`` and ``levels``: 0 levels is one
-    leaf, the unblocked path every toy width runs; the two cells' blocks
-    (4096, 8192) are cut two and three times."""
+def test_the_inverse_scope_says_whether_the_blocked_inverse_engaged(traced, rng):
+    """``solver.factor`` carries what a reader reads (``blocks``, ``chunk``);
+    whether the blocked inverse ran is in the program, under the scope
+    ``solver.inverse``: one ``triangular_solve`` where the block is one leaf
+    (every toy width), one a leaf and products between them where it is cut
+    (the two cells' blocks, 4096 and 8192, two and three times)."""
+    import jax
+    import jax.numpy as jnp
+
     from keystone_tpu.linalg import RowMatrix, bcd
 
     tr = traced(True)
@@ -891,9 +931,19 @@ def test_solver_factor_span_says_whether_the_blocked_inverse_engaged(traced, rng
         RowMatrix.from_array(A), RowMatrix.from_array(B), block_size=8,
         num_iters=2, lam=0.1, cache_grams=True)
     (factor,) = [s for s in tr.spans() if s["name"] == "solver.factor"]
-    assert factor["args"] == {"blocks": 2, "chunk": bcd._factor_chunk(8),
-                              "leaf": bcd._INV_LEAF, "levels": 0}
+    assert factor["args"] == {"blocks": 2, "chunk": bcd._factor_chunk(8)}
     assert [bcd._inv_levels(b) for b in (1024, 4096, 8192)] == [0, 2, 3]
+
+    def under_inverse(leaf):
+        jaxpr = jax.make_jaxpr(lambda g: bcd._batched_spd_inv(g, leaf))(
+            jnp.eye(8, dtype=jnp.float32)[None])
+        names = [(str(e.source_info.name_stack), e.primitive.name) for e in jaxpr.eqns]
+        return [prim for stack, prim in names if "solver.inverse" in stack]
+
+    # One leaf: Y = solve(L, I) and one product YᵀY. Cut once: two leaves'
+    # solves, and the products that join the halves.
+    whole, cut = under_inverse(8), under_inverse(4)
+    assert whole.count("dot_general") == 1 and cut.count("dot_general") == 6
 
 
 def test_solver_programs_keep_the_names_the_benchmark_filters_on():
@@ -922,6 +972,159 @@ def test_solver_programs_keep_the_names_the_benchmark_filters_on():
     }
     for phase, low in lowered.items():
         assert "module @jit_local " in low.as_text(), phase
+
+
+def _compiled_scopes(lowered):
+    """{scope: operations} of a lowered program's compiled ``op_name``s, by
+    the rule the trace reader applies to a device trace's ``tf_op``."""
+    import collections
+    import re
+
+    from keystone_tpu.utils.device_trace import scope_of
+
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    return collections.Counter(scope_of(n) for n in names), names
+
+
+def _solver_lowerings():
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg import RowMatrix, bcd
+    from keystone_tpu.nodes.learning import GaussianKernelGenerator, kernel_ridge
+
+    mesh, axis = RowMatrix.from_array(np.zeros((16, 4), np.float32)).mesh, config.data_axis
+    rows, nb, b, k = 16 * mesh.shape[axis], 2, 8, 3
+    f32, shape = jnp.float32, jax.ShapeDtypeStruct
+    precision = bcd._precision()
+    fold = bcd.fold_blocks(mesh.shape[axis])
+    a3, lam, w_rows = shape((nb, rows, b), f32), shape((), f32), shape((rows,), f32)
+
+    def epochs(cached, pad=0):
+        return bcd._fused_epochs_fn(mesh, axis, precision, True, 2, cached, fold, pad).lower(
+            a3, shape((nb, b, b) if cached else (nb, 1, 1), f32), shape((rows, k), f32),
+            shape((nb, b, k), f32), lam, w_rows)
+
+    return {
+        "stack": lambda: bcd._stack_blocks_fn(mesh, axis, nb, 2).lower(
+            shape((rows, nb * b - 2), f32)),
+        "factor": lambda: bcd._fused_factor_fn(mesh, axis, precision, True, fold, 2).lower(
+            a3, lam, w_rows),
+        "cached epochs": lambda: epochs(True),
+        "uncached epochs": lambda: epochs(False, 2),
+        "streamed first epoch": lambda: bcd._first_epoch_update_fn(
+            mesh, axis, precision, True, fold).lower(
+            shape((rows, b), f32), shape((rows, k), f32), shape((b, k), f32), lam, w_rows),
+        "streamed cached update": lambda: bcd._cached_block_update_fn(
+            mesh, axis, precision, True, fold).lower(
+            shape((rows, b), f32), shape((b, b), f32), shape((rows, k), f32),
+            shape((b, k), f32), w_rows),
+        "kernel solver": lambda: kernel_ridge._block_solve_fn(
+            mesh, axis, kernel_ridge._precision(), fold, 8).lower(
+            shape((rows, 4), f32), shape((rows, 3), f32), shape((), f32),
+            shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5)),
+    }
+
+
+SOLVER_SCOPES = {
+    "stack": {"solver.stack"},
+    "factor": {"solver.gram", "solver.cholesky", "solver.inverse"},
+    "cached epochs": {"solver.update"},
+    "uncached epochs": {"solver.update", "solver.gram", "solver.cholesky", "solver.inverse"},
+    "streamed first epoch": {"solver.update", "solver.gram", "solver.cholesky",
+                             "solver.inverse"},
+    "streamed cached update": {"solver.update"},
+    "kernel solver": {"krr.generate", "krr.reduce", "krr.factor", "krr.solve"},
+}
+
+
+@pytest.mark.parametrize("phase", sorted(SOLVER_SCOPES))
+def test_the_device_scopes_reach_the_compiled_programs_op_names(phase):
+    """A ``jax.named_scope`` at the site is a segment of the compiled
+    operations' ``op_name``, inside ``shard_map`` and inside a scan's body
+    alike: the path a TPU trace keeps as ``tf_op``. The scopes of a program
+    are the ones its row of PERF.md's table names and no other; what has
+    none is the arguments and the loops' own counters."""
+    from keystone_tpu.utils.device_trace import NO_OP_NAME, UNSCOPED
+    from keystone_tpu.utils.metrics import DEVICE_SCOPES
+
+    lowered = _solver_lowerings()[phase]()
+    assert "module @jit_local " in lowered.as_text()
+    scopes, names = _compiled_scopes(lowered)
+    found = set(scopes) - {UNSCOPED, NO_OP_NAME}
+    assert found == SOLVER_SCOPES[phase] and found <= set(DEVICE_SCOPES)
+    for name in names:
+        segments = [s for s in name.split("/") if s in DEVICE_SCOPES]
+        # A scope is entered once on a path but for the update's loops,
+        # which hold the uncached body's gram and inverse.
+        assert len(segments) <= 1 or segments[0] == "solver.update", name
+    heavy = [n for n in names if n.endswith(("dot_general", "cholesky", "triangular_solve"))]
+    assert bool(heavy) == (phase != "stack")
+    assert all(set(n.split("/")) & set(DEVICE_SCOPES) for n in heavy)
+    if phase == "uncached epochs":
+        assert any("/while/body/" in n and n.endswith("solver.gram/dot_general")
+                   for n in names)
+
+
+def test_a_fused_chains_steps_are_scoped_by_stage_and_the_kernels_parts_inside(rng):
+    """One scope a step of the chain's walk, named as the walk's host spans
+    are (``node:``) from the stages' class names; the step in which the
+    convolver took its rectifier and pooler joins the three, and the
+    convolver's own parts lie inside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.images import (
+        Convolver,
+        ImageVectorizer,
+        Pooler,
+        SymmetricRectifier,
+    )
+    from keystone_tpu.nodes.stats.normalizer import L2Normalizer
+    from keystone_tpu.utils.device_trace import stage_of
+    from keystone_tpu.workflow import FusedTransformer
+    from keystone_tpu.workflow.pipeline import _program
+
+    bank = rng.normal(size=(16, 6, 6, 3)).astype(np.float32)
+    chain = FusedTransformer([
+        Convolver(bank, normalize_patches=10.0), SymmetricRectifier(alpha=0.25),
+        Pooler(4, 4, mode="sum"), ImageVectorizer(), L2Normalizer()])
+    assert chain.fused_stages == 3
+    lowered = _program(chain._program_name()).lower(
+        chain, jax.ShapeDtypeStruct((5, 13, 13, 3), jnp.float32))
+    assert ("module @jit_apply_Convolver_SymmetricRectifier_Pooler_ImageVectorizer"
+            "_L2Normalizer ") in lowered.as_text()
+    scopes, names = _compiled_scopes(lowered)
+    taken = "node:Convolver+SymmetricRectifier+Pooler"
+    assert {"conv.patches", "conv.kernel", "conv.relayout", taken,
+            "node:L2Normalizer"} <= set(scopes)
+    for name in names:
+        if any(part in name for part in ("conv.patches", "conv.kernel", "conv.relayout")):
+            assert stage_of(name) == taken, name
+    # A transformer that is a program by itself is its one stage.
+    alone = L2Normalizer()
+    lowered = _program(alone._program_name()).lower(
+        alone, jax.ShapeDtypeStruct((5, 7), jnp.float32))
+    assert "module @jit_apply_L2Normalizer " in lowered.as_text()
+    scopes, _names = _compiled_scopes(lowered)
+    assert "node:L2Normalizer" in scopes
+
+
+def test_the_mixture_fits_loop_body_is_scoped_by_step():
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.gmm import _fit_gmm
+
+    lowered = _fit_gmm.lower(
+        jax.ShapeDtypeStruct((64, 8), jnp.float32), jax.random.PRNGKey(0), k=4,
+        max_iters=3, min_var=1e-4)
+    assert "module @jit__fit_gmm " in lowered.as_text()
+    scopes, names = _compiled_scopes(lowered)
+    assert {"gmm.estep", "gmm.mstep"} <= set(scopes)
+    products = [n for n in names if "/while/body/" in n and n.endswith("dot_general")
+                and "jit(_fit_kmeans)" not in n]
+    assert products and all("gmm.estep" in n or "gmm.mstep" in n for n in products)
 
 
 def test_the_kernel_solvers_program_keeps_the_name_too():

@@ -385,6 +385,49 @@ def test_a_second_fit_on_other_rows_compiles_nothing(fit, data, shared):
         assert not np.allclose(a, b)
 
 
+def _fit_cifar_kernel(seed):
+    import dataclasses
+
+    from keystone_tpu.pipelines.images import random_patch_cifar_kernel as kernel
+
+    conf = kernel.RandomPatchCifarKernelConfig(
+        num_filters=16, patch_sample=500, patch_norm=10.0, pool_size=14, pool_stride=13,
+        lam=1.0, block_size=16, gamma=1e-3, num_epochs=2, num_classes=4)
+    train = _images(seed)
+    return kernel.fit(dataclasses.replace(conf, seed=seed), train.data, train.labels)
+
+
+@pytest.mark.parametrize("fit", [_fit_imagenet, _fit_timit, _fit_cifar, _fit_cifar_kernel],
+                         ids=["imagenet", "timit", "cifar", "cifar-kernel"])
+def test_the_device_scopes_add_no_trace_and_no_compile_request_to_a_second_fit(fit):
+    """A ``jax.named_scope`` acts where a program is traced and is no part
+    of what finds the program again: the second fit of each pipeline, whose
+    solver, kernel solver, chains and mixture fit all carry scopes, traces,
+    lowers and compiles nothing, and traces none of those programs."""
+    prior = config.trace
+    config.trace = True
+    reset_tracer()
+    try:
+        fit(1)
+        mark = len(recorded_tracer().spans())
+        before = COMPILES.count
+        fit(2)
+        requests = COMPILES.count - before
+        second = recorded_tracer().spans()[mark:]
+    finally:
+        config.trace = prior
+        reset_tracer()
+    assert requests == 0
+    assert [s["name"] for s in second if s["name"] in ("jax.lower", "jax.compile")] == []
+    # What a second fit traces again is eager one-operation wrappers of the
+    # pipelines' host code (``less``, ``multiply``), as before the scopes.
+    retraced = {s["args"]["fun_name"] for s in second if s["name"] == "jax.trace"}
+    scoped = {"local", "_fit_gmm", "_fit_kmeans", "_conv_rectify_pool"}
+    assert not retraced & scoped
+    assert not any(name.startswith("apply_") for name in retraced)
+    assert sum(s["name"] == "fit" for s in second) == 1
+
+
 def test_a_pickled_imagenet_pipeline_scores_the_same(tmp_path):
     from keystone_tpu.workflow.serialization import load_pipeline, save_pipeline
 
