@@ -12,16 +12,23 @@ of Tu et al., "Large Scale Kernel Learning using Block Coordinate Descent"
     α_B  <- solve(K_BB + λ I, R)               (Cholesky)
 
 TPU lowering: the block operand is a function of the n x d features, not an
-array. K (n x n: 10 GB at CIFAR-10's 50,000 rows) is never stored; each
-visit generates its n x b block from the row-sharded X, uses it and drops
-it, inside one program whose loop over all the epochs' visits is a
-``lax.scan``: no host round trip a block, and no more of K than one block
-ever live. X_B is replicated for the visit (each shard's rows of the block,
-``psum``), K_Bᵀ α reduces over the sharded rows (``sharded_rowsum``), and
-the b x b solve runs replicated on every chip. A ragged last block and the
-pad rows of X are masked out of K; the pad columns get 1 on the diagonal, so
-their α stays 0. X, Y, λ, the row count, the visit order and the kernel's
-parameters are arguments of the program: a second fit compiles nothing.
+array. K (n x n: 10 GB at CIFAR-10's 50,000 rows) is never an argument;
+each visit's n x b block is generated from the row-sharded X inside one
+program whose loop over all the epochs' visits is a ``lax.scan``: no host
+round trip a block. Whether a block is kept for the later epochs or made
+again is decided before the program is built, from sizes the code can see
+(``_blocks_kept``: one shard's rows, the block, the epochs, the device's
+reported memory). One epoch, or slots that do not fit, keep nothing: each
+block is used and dropped, no more of K than one block ever live. Otherwise
+the first epoch writes the blocks it generates into a store that lives and
+dies inside the program, and the later epochs read them: upstream's
+``cacheKernel``, without the flag. X_B is replicated for the visit (each
+shard's rows of the block, ``psum``), K_Bᵀ α reduces over the sharded rows
+(``sharded_rowsum``), and the b x b solve runs replicated on every chip. A
+ragged last block and the pad rows of X are masked out of K; the pad
+columns get 1 on the diagonal, so their α stays 0. X, Y, λ, the row count,
+the visit order and the kernel's parameters are arguments of the program: a
+second fit compiles nothing.
 
 The matrix-free conjugate-gradient solver that bore this name is
 ``KernelRidgeCG`` (``kernel_ridge_cg.py``).
@@ -47,7 +54,12 @@ from keystone_tpu.linalg.row_matrix import (
 )
 from keystone_tpu.nodes.learning.kernels import KernelGenerator
 from keystone_tpu.utils.mesh import fold_blocks
-from keystone_tpu.utils.metrics import active_tracer, device_scope, span_of
+from keystone_tpu.utils.metrics import (
+    active_tracer,
+    device_hbm_bytes,
+    device_scope,
+    span_of,
+)
 from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
@@ -96,7 +108,13 @@ class KernelBlockLinearMapper(Transformer):
 
 
 def _visit_order(num_blocks: int, num_epochs: int) -> np.ndarray:
-    """The block of every visit of the solve: natural order, every epoch."""
+    """The block of every visit of the solve: natural order, every epoch.
+
+    The only maker of the visits. The store of ``_block_solve_fn`` stands on
+    its contract, which is upstream's permuter's too: the first
+    ``num_blocks`` visits are one epoch, and no later visit goes to a block
+    that epoch left out (``kernel_block_gauss_seidel`` keeps nothing for an
+    order that breaks it)."""
     return np.tile(np.arange(num_blocks, dtype=np.int32), num_epochs)
 
 
@@ -113,12 +131,50 @@ def _ridge_diagonal(col_live, lam):
     return jnp.where(col_live, lam, 1.0)
 
 
+#: What the store leaves of the device's memory to everything the process
+#: holds outside the solver's program, as a divisor of the reported limit:
+#: the caller's images, the features before they were scaled, the model of
+#: the fit before (2.25 GB of 16.9 in ``cifar-kernel-fit``).
+_OUTSIDE_SHARE = 8
+#: The program's temporaries beside the store, in blocks: each of its two
+#: loops holds the block it works on, and the factorisation's scratch is
+#: part of a third (the v5e compile at ``cifar-kernel-fit``'s shape: the
+#: store and 2.24 blocks, ``tests/test_aot_tpu.py``).
+_WORKING_BLOCKS = 3
+
+
+def _blocks_kept(rows: int, block: int, num_blocks: int, num_epochs: int,
+                 argument_bytes: int, itemsize: int, limit: int) -> int:
+    """How many of the first epoch's kernel blocks the solve keeps in HBM
+    for the later epochs to read: store or regenerate, from sizes alone, so
+    every process of a mesh comes out the same. ``rows`` are one shard's
+    padded rows, ``argument_bytes`` its X and Y, ``limit`` the device's
+    reported memory. One epoch visits nothing twice and keeps nothing.
+    Otherwise as many slots of rows x block as fit beside the arguments,
+    the working blocks and ``limit / _OUTSIDE_SHARE`` for what is not this
+    program's."""
+    if num_epochs < 2:
+        return 0
+    slot = rows * block * itemsize
+    room = limit - limit // _OUTSIDE_SHARE - argument_bytes - _WORKING_BLOCKS * slot
+    return max(0, min(num_blocks, room // slot))
+
+
 @lru_cache(maxsize=None)
-def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
+def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
+                    keep: int = 0, num_blocks: int = 0):
     """The whole solve as ONE program: a scan over the visits, per shard
     under shard_map. ``starts`` (the first row of each visit's block) is
     an argument, so epochs and order are data; so are λ, the row count and
-    the kernel's parameters."""
+    the kernel's parameters.
+
+    With ``keep`` > 0 the first ``num_blocks`` visits (one epoch) are a scan
+    of their own that carries a store of ``keep`` slots and writes each
+    block it generates to slot ``start // block``, where that is under
+    ``keep``; the later visits read their block from its slot, and generate
+    it as the first epoch did where it has none. The stored block is the
+    generated block, bit for bit. With ``keep`` 0 there is one scan and no
+    store."""
     width = mesh.shape[axis]
 
     def local(xl, yl, lam, n, starts, kernel):
@@ -137,12 +193,15 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
                 mode="promise_in_bounds", indices_are_sorted=True)
             return lax.psum(jnp.where(mine[:, None], held, 0.0), axis)
 
-        def visit(alpha, start):
+        def generate(start):
             with device_scope("krr.generate"):
                 col_live = start + jnp.arange(block) < n
                 x_b = take_block(xl, start)
-                k_b = jnp.where(row_live[:, None] & col_live[None, :],
-                                kernel.block(xl, x_b), 0.0)
+                return jnp.where(row_live[:, None] & col_live[None, :],
+                                 kernel.block(xl, x_b), 0.0)
+
+        def visit(alpha, start, k_b):
+            """One block's solve on its kernel block, made or read."""
             with device_scope("krr.reduce"):
                 k_bb = take_block(k_b, start)
                 kt_alpha = sharded_rowsum(
@@ -152,7 +211,7 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
                 r = _block_residual(take_block(yl, start), kt_alpha, k_bb,
                                     take_block(alpha, start), precision)
             with device_scope("krr.factor"):
-                ridge = _ridge_diagonal(col_live, lam)
+                ridge = _ridge_diagonal(start + jnp.arange(block) < n, lam)
                 chol = jnp.linalg.cholesky(
                     k_bb + ridge[:, None] * jnp.eye(block, dtype=k_bb.dtype))
             with device_scope("krr.solve"):
@@ -160,9 +219,46 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int):
                 at = row_id - start
                 inside = (at >= 0) & (at < block)
                 return jnp.where(inside[:, None],
-                                 alpha_b[jnp.clip(at, 0, block - 1)], alpha), None
+                                 alpha_b[jnp.clip(at, 0, block - 1)], alpha)
 
-        alpha, _ = lax.scan(visit, jnp.zeros_like(yl), starts)
+        def make(alpha, start):
+            return visit(alpha, start, generate(start)), None
+
+        alpha = jnp.zeros_like(yl)
+        if not keep:
+            alpha, _ = lax.scan(make, alpha, starts)
+            return lax.all_gather(alpha, axis, tiled=True)
+
+        def make_and_keep(carry, start):
+            alpha, store = carry
+            kept = k_b = generate(start)
+            with device_scope("krr.generate"):
+                slot = start // block
+                if keep < num_blocks:
+                    # A block with no slot writes back what its neighbour's
+                    # slot holds: the store stays out of any conditional.
+                    has_slot, slot = slot < keep, jnp.minimum(slot, keep - 1)
+                    kept = jnp.where(has_slot, k_b,
+                                     lax.dynamic_index_in_dim(store, slot, keepdims=False))
+                store = lax.dynamic_update_index_in_dim(store, kept, slot, 0)
+            return (visit(alpha, start, k_b), store), None
+
+        (alpha, store), _ = lax.scan(
+            make_and_keep,
+            (alpha, jnp.zeros((keep, rows, block), xl.dtype)), starts[:num_blocks])
+
+        def fetch(start):
+            with device_scope("krr.fetch"):
+                return lax.dynamic_index_in_dim(store, start // block, keepdims=False)
+
+        def read_or_make(alpha, start):
+            if keep < num_blocks:
+                k_b = lax.cond(start // block < keep, fetch, generate, start)
+            else:
+                k_b = fetch(start)
+            return visit(alpha, start, k_b), None
+
+        alpha, _ = lax.scan(read_or_make, alpha, starts[num_blocks:])
         # The model's weights are replicated, as a linear map's are.
         return lax.all_gather(alpha, axis, tiled=True)
 
@@ -187,22 +283,33 @@ def kernel_block_gauss_seidel(
     mesh, axis = A.mesh, config.data_axis
     block = min(int(block_size), A.n)
     num_blocks = -(-A.n // block)
-    starts = _visit_order(num_blocks, num_epochs) * block
+    visits = _visit_order(num_blocks, num_epochs)
     dtype = A.data.dtype
+    rows = A.padded_rows // mesh.shape[axis]
+    keep = _blocks_kept(
+        rows, block, num_blocks, num_epochs,
+        rows * (A.shape[1] + B.shape[1]) * dtype.itemsize, dtype.itemsize,
+        device_hbm_bytes())
+    if not np.isin(visits[num_blocks:], visits[:num_blocks]).all():
+        keep = 0  # not _visit_order's order: a later visit would read an empty slot
+    generated = len(visits) - int((visits[num_blocks:] < keep).sum())  # the rest are read
+    slot_bytes = A.padded_rows * block * dtype.itemsize
     with span_of(active_tracer(), "krr.fit", "solver", rows=A.n, dim=A.shape[1],
                  block=block, blocks=num_blocks, epochs=num_epochs,
-                 kernel_blocks=len(starts),
-                 kernel_bytes=len(starts) * A.padded_rows * block * dtype.itemsize):
+                 kernel_blocks=generated, kernel_bytes=generated * slot_bytes,
+                 blocks_kept=keep, store_bytes=keep * slot_bytes):
         solve = _block_solve_fn(
-            mesh, axis, _precision(), fold_blocks(mesh.shape[axis]), block)
+            mesh, axis, _precision(), fold_blocks(mesh.shape[axis]), block,
+            keep, num_blocks if keep else 0)
         return solve(A.data, B.data, jnp.asarray(lam, dtype),
-                     jnp.asarray(A.n, jnp.int32), jnp.asarray(starts), kernel)
+                     jnp.asarray(A.n, jnp.int32), jnp.asarray(visits * block), kernel)
 
 
 class KernelRidgeRegression(LabelEstimator):
     """Kernel ridge regression as upstream solves it: block Gauss-Seidel
     over blocks of ``block_size`` training rows, ``num_epochs`` sweeps, each
-    kernel block generated from the features when it is visited."""
+    kernel block generated from the features when it is first visited and,
+    where the device has the room, kept for the sweeps after."""
 
     def __init__(self, kernel: KernelGenerator, lam: float = 1.0,
                  block_size: int = 4096, num_epochs: int = 1):

@@ -19,9 +19,10 @@ defaults below are a laptop's. Those sizes from the command line:
         --gamma 2e-4 --lam 1 --block-size 4096 --num-epochs 3
 
 TPU notes: the featurizer is ``random_patch_cifar``'s, shared, not copied;
-the head never stores the n x n kernel: each n x b block is generated from
-the features inside the solver's one program when it is visited
-(``nodes/learning/kernel_ridge.py``).
+the head never takes the n x n kernel as an array: each n x b block is
+generated from the features inside the solver's one program when it is
+first visited, and kept there for the later epochs where the device's
+memory has room for it (``nodes/learning/kernel_ridge.py``).
 """
 
 from __future__ import annotations

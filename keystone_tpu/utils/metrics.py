@@ -352,7 +352,7 @@ class Tracer:
 DEVICE_SCOPES = (
     "solver.stack", "solver.gram", "solver.cholesky", "solver.inverse",
     "solver.update",
-    "krr.generate", "krr.reduce", "krr.factor", "krr.solve",
+    "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
     "conv.patches", "conv.kernel", "conv.relayout",
     "gmm.estep", "gmm.mstep",
 )

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS = "data"
+# What one v5e chip reports as ``bytes_limit`` (PERF.md section 5).
+V5E_HBM_BYTES = 16_909_336_064
 
 
 def _v5e_mesh(n: int = 8):
@@ -321,12 +323,16 @@ def test_conv_rectify_pool_mosaic_compiles_at_cifar_fit_config(mesh):
     assert memory.temp_size_in_bytes < 7 * 2**30
 
 
-def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh):
+@pytest.mark.parametrize("keep", [0, 13])
+def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh, keep):
     """The kernel solver's one program at ``cifar-kernel-fit``'s sizes
     (50,000 x 4,096 features, k = 10, blocks of 4,096 rows, 39 visits) for
-    one v5e chip: the loop over the visits is in it, and beside its
-    arguments it holds one kernel block (0.82 GB) and little else: never
-    the 10 GB kernel."""
+    one v5e chip: the loop over the visits is in it. Without a store it
+    holds, beside its arguments, one kernel block (0.82 GB) and little
+    else: never the 10 GB kernel. With the cell's 13 slots it holds the
+    store once (10.65 GB, updated in place: a copy would be a second
+    store) and the working block of each of its two loops, and fits the
+    chip beside its arguments."""
     from keystone_tpu.linalg.row_matrix import _precision
     from keystone_tpu.nodes.learning import GaussianKernelGenerator
     from keystone_tpu.nodes.learning.kernel_ridge import _block_solve_fn
@@ -335,15 +341,22 @@ def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh):
     n, d, k, b = 50000, 4096, 10, 4096
     kernel = GaussianKernelGenerator(2e-4)
     kernel.gamma = _sds((), one, P())
-    compiled = _block_solve_fn(one, AXIS, _precision(), _fold(one), b).lower(
+    compiled = _block_solve_fn(
+        one, AXIS, _precision(), _fold(one), b, keep, 13).lower(
         _sds((n, d), one, P(AXIS)), _sds((n, k), one, P(AXIS)), _sds((), one, P()),
         _sds((), one, P(), jnp.int32), _sds((39,), one, P(), jnp.int32), kernel,
     ).compile()
     text = compiled.as_text()
     assert "Cholesky" in text and "while" in text
     memory = compiled.memory_analysis()
+    block = n * b * 4
     assert memory.argument_size_in_bytes < 1.01 * (n * d * 4)
-    assert n * b * 4 <= memory.temp_size_in_bytes < 1.5 * n * b * 4
+    if keep:
+        assert keep * block <= memory.temp_size_in_bytes < (keep + 2.5) * block
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes) < V5E_HBM_BYTES
+    else:
+        assert block <= memory.temp_size_in_bytes < 1.5 * block
 
 
 def test_kernel_block_solve_compiles_sharded_for_v5e(mesh):

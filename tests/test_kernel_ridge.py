@@ -103,6 +103,96 @@ def test_two_epochs_match_the_plain_reference(adapter, n, block):
     assert _gap(model(X[:17]), scores) < 1e-4
 
 
+def _each_epoch_its_own_order(num_blocks, num_epochs):
+    r = np.random.default_rng(5)
+    return np.concatenate([r.permutation(num_blocks) for _ in range(num_epochs)]).astype(np.int32)
+
+
+def _first_epoch_a_block_short(num_blocks, num_epochs):
+    visits = np.tile(np.arange(num_blocks, dtype=np.int32), num_epochs)
+    visits[num_blocks - 1] = 0  # the last block's first visit comes an epoch late
+    return visits
+
+
+NATURAL = kernel_ridge._visit_order
+
+
+@pytest.mark.parametrize("order", [
+    NATURAL, _each_epoch_its_own_order, _first_epoch_a_block_short],
+    ids=["natural", "permuted", "outside-the-contract"])
+@pytest.mark.parametrize("keep", [0, 2, 5])
+def test_the_kept_blocks_are_the_blocks_made_again(adapter, monkeypatch, keep, order):
+    """Three epochs over 150 rows in blocks of 32 (a ragged tail of 22),
+    none, two or all five of the first epoch's blocks kept: the dual
+    weights are the program's without a store bit for bit, the span counts
+    the blocks generated, and an order whose first epoch leaves a block out
+    keeps nothing."""
+    X, Y = _problem(31, 150)
+    gamma, lam = 0.2, 0.5
+    A, B = RowMatrix.from_array(X), RowMatrix.from_array(Y)
+    monkeypatch.setattr(kernel_ridge, "_visit_order", order)
+    monkeypatch.setattr(config, "trace", True)
+
+    def solve(slots):
+        reset_tracer()
+        monkeypatch.setattr(kernel_ridge, "_blocks_kept", lambda *sizes: slots)
+        alpha = np.asarray(kernel_ridge.kernel_block_gauss_seidel(
+            A, B, GaussianKernelGenerator(gamma), lam, block_size=32, num_epochs=3))
+        return alpha, recorded_tracer().spans()[-1]["args"]
+
+    plain, _ = solve(0)
+    alpha, span = solve(keep)
+    assert np.array_equal(alpha, plain)
+    kept = 0 if order is _first_epoch_a_block_short else keep
+    assert {k: span[k] for k in (
+        "blocks", "epochs", "kernel_blocks", "kernel_bytes", "blocks_kept", "store_bytes")} == {
+        "blocks": 5, "epochs": 3, "kernel_blocks": 15 - 2 * kept,
+        "kernel_bytes": (15 - 2 * kept) * 160 * 32 * 4,
+        "blocks_kept": kept, "store_bytes": kept * 160 * 32 * 4}
+    reset_tracer()
+    if order is NATURAL:
+        sizes = {"rows": 150, "block_size": 32, "num_epochs": 3, "gamma": gamma, "lam": lam}
+        with jax.default_matmul_precision("highest"):
+            want = adapter.solve(jnp.asarray(X), jnp.asarray(Y), sizes)
+        assert _gap(alpha[:150], want) < 1e-4
+
+
+# (one shard's rows, block, blocks, epochs, the shard's X and Y in bytes,
+# the device's limit) -> slots, float32. 16,909,336,064 is what a v5e
+# reports (PERF.md section 5).
+V5E = 16_909_336_064
+
+
+@pytest.mark.parametrize("rows, block, blocks, epochs, limit, keep", [
+    (50_000, 4096, 13, 3, V5E, 13),        # cifar-kernel-fit: room for 14, all 13 kept
+    (50_000, 4096, 13, 1, V5E, 0),         # one epoch visits nothing twice
+    (50_000, 4096, 13, 2, V5E, 13),
+    (100_000, 4096, 25, 3, V5E, 5),        # twice the rows: slots of 1.64 GB
+    (500_000, 4096, 123, 3, V5E, 0),       # the augmented set: a slot is 8.2 GB
+    (125_000, 4096, 123, 3, V5E, 3),       # the same on a four-wide mesh: 12 slots in all
+    (12_512, 4096, 13, 3, V5E, 13),        # cifar-kernel-fit's rows a shard of four
+    (50_000, 4096, 13, 3, 12 * 2**30, 9),  # the CPU's config.hbm_budget_bytes
+    (50_000, 4096, 13, 3, 4 * 2**30, 0),   # no room beside the working blocks
+    (160, 32, 5, 3, 12 * 2**30, 5),        # a test's sizes
+])
+def test_the_rule_keeps_what_fits_beside_its_reserve(rows, block, blocks, epochs, limit, keep):
+    arguments = rows * (4096 + 10) * 4
+    assert kernel_ridge._blocks_kept(rows, block, blocks, epochs, arguments, 4, limit) == keep
+
+
+def test_the_rule_reads_the_devices_limit_or_the_cpus_budget(monkeypatch):
+    """``kernel_block_gauss_seidel`` hands the rule one shard's rows and
+    ``device_hbm_bytes()``, which on the CPU is ``config.hbm_budget_bytes``."""
+    seen = []
+    monkeypatch.setattr(kernel_ridge, "_blocks_kept", lambda *sizes: seen.append(sizes) or 0)
+    monkeypatch.setattr(config, "hbm_budget_bytes", 123_456_789)
+    X, Y = _problem(33, 150)
+    kernel_ridge.kernel_block_gauss_seidel(
+        RowMatrix.from_array(X), RowMatrix.from_array(Y), GaussianKernelGenerator(0.2),
+        0.5, block_size=32, num_epochs=3)
+    assert seen == [(20, 32, 5, 3, 20 * (6 + 3) * 4, 4, 123_456_789)]
+
+
 def test_many_epochs_reach_the_direct_solve():
     X, Y = _problem(3, 120)
     gamma, lam = 0.3, 0.5
@@ -129,7 +219,9 @@ def test_any_kernel_generator_runs_the_block_solver():
     assert _gap(model(X), K @ direct) < 1e-3
 
 
-def test_the_fit_on_the_mesh_matches_one_device():
+@pytest.mark.parametrize("keep", [0, 2, 5])
+def test_the_fit_on_the_mesh_matches_one_device(monkeypatch, keep):
+    monkeypatch.setattr(kernel_ridge, "_blocks_kept", lambda *sizes: keep)
     X, Y = _problem(7, 150)
     est = KernelRidgeRegression(
         GaussianKernelGenerator(0.2), lam=0.5, block_size=32, num_epochs=3)
@@ -137,9 +229,9 @@ def test_the_fit_on_the_mesh_matches_one_device():
     sharded = est.fit(X, Y)
     mesh_util.set_default_mesh(Mesh(np.asarray(jax.devices()[:1]), (config.data_axis,)))
     single = est.fit(X, Y)
-    # The same visits on the same blocks; K_B^T alpha sums in the fold's
-    # order on both widths, X_B and K_BB reach every shard by a psum of
-    # one non-zero term a row.
+    # The same visits on the same blocks, kept or made again; K_B^T alpha
+    # sums in the fold's order on both widths, X_B and K_BB reach every
+    # shard by a psum of one non-zero term a row.
     assert _gap(sharded.alpha, single.alpha) < 1e-6
     assert _gap(sharded(X[:9]), single(X[:9])) < 1e-6
 
@@ -174,23 +266,26 @@ def test_a_second_fit_compiles_nothing():
     assert _gap(first.alpha, second.alpha) > 0.1  # and not the first fit's answer
 
 
-def test_the_solvers_program_takes_its_operands_as_arguments():
+@pytest.mark.parametrize("keep", [0, 2, 5])
+def test_the_solvers_program_takes_its_operands_as_arguments(keep):
     mesh = mesh_util.default_mesh()
     rows, d, k, block = 160, 6, 3, 32
     f32, shape = jnp.float32, jax.ShapeDtypeStruct
     solve = kernel_ridge._block_solve_fn(
         mesh, config.data_axis, kernel_ridge._precision(),
-        mesh_util.fold_blocks(mesh.shape[config.data_axis]), block)
+        mesh_util.fold_blocks(mesh.shape[config.data_axis]), block, keep, 5)
     text = solve.lower(
         shape((rows, d), f32), shape((rows, k), f32), shape((), f32),
         shape((), jnp.int32), shape((10,), jnp.int32),
         GaussianKernelGenerator(0.2)).as_text()
     # X, Y, lam, n, the visits' starts and gamma: six arguments.
     assert _arguments(text) == 6
-    # The loop over the visits is in the program, and no kernel larger
-    # than one block of it: nothing of rows x rows.
-    assert "stablehlo.while" in text
+    # The loop over the visits is in the program (two with a store: the
+    # epoch that fills it and the ones that read it), and no kernel larger
+    # than the store's slots: nothing of rows x rows.
+    assert text.count("stablehlo.while") == (2 if keep else 1)
     assert f"tensor<{rows}x{rows}x" not in text
+    assert (f"tensor<{keep}x{rows // mesh.shape[config.data_axis]}x{block}x" in text) == bool(keep)
 
 
 def test_the_mapper_names_its_arrays_and_applies_in_blocks():
@@ -264,9 +359,12 @@ def test_the_pipeline_fits_and_predicts(monkeypatch):
     assert solve["root_id"] == root["id"]
     # 112 rows of 2 x 2 x 2 x 16 features in blocks of 48: 3 blocks, 3 epochs.
     assert {k: solve["args"][k] for k in (
-        "rows", "dim", "block", "blocks", "epochs", "kernel_blocks", "kernel_bytes")} == {
+        "rows", "dim", "block", "blocks", "epochs", "kernel_blocks", "kernel_bytes",
+        "blocks_kept", "store_bytes")} == {
         "rows": 112, "dim": 128, "block": 48, "blocks": 3, "epochs": 3,
-        "kernel_blocks": 9, "kernel_bytes": 9 * 112 * 48 * 4}
+        # The CPU's budget holds all three: one generation each, two reads.
+        "kernel_blocks": 3, "kernel_bytes": 3 * 112 * 48 * 4,
+        "blocks_kept": 3, "store_bytes": 3 * 112 * 48 * 4}
     applied = [s for s in spans if s["name"] == "krr.apply"]
     assert applied[-1]["args"] == {"rows": 7, "train_rows": 112, "blocks": 3}
     reset_tracer()

@@ -1019,10 +1019,12 @@ def _solver_lowerings():
             mesh, axis, precision, True, fold).lower(
             shape((rows, b), f32), shape((b, b), f32), shape((rows, k), f32),
             shape((b, k), f32), w_rows),
-        "kernel solver": lambda: kernel_ridge._block_solve_fn(
-            mesh, axis, kernel_ridge._precision(), fold, 8).lower(
+        **{name: lambda keep=keep: kernel_ridge._block_solve_fn(
+            mesh, axis, kernel_ridge._precision(), fold, 8, keep, 3).lower(
             shape((rows, 4), f32), shape((rows, 3), f32), shape((), f32),
-            shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5)),
+            shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5))
+           for name, keep in (("kernel solver", 0), ("kernel solver, blocks kept", 3),
+                              ("kernel solver, some blocks kept", 2))},
     }
 
 
@@ -1035,6 +1037,10 @@ SOLVER_SCOPES = {
                              "solver.inverse"},
     "streamed cached update": {"solver.update"},
     "kernel solver": {"krr.generate", "krr.reduce", "krr.factor", "krr.solve"},
+    "kernel solver, blocks kept": {
+        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve"},
+    "kernel solver, some blocks kept": {
+        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve"},
 }
 
 

@@ -894,7 +894,10 @@ def test_trainer_cadence_loop_refreshes(tmp_path):
         Xs, Ys = _data(n=64, seed=9)
         trainer2.submit(Xs, Ys)
         deadline = time.monotonic() + 20
-        while daemon.generation < 1 and time.monotonic() < deadline:
+        # The trainer counts a refresh after the daemon has swapped: wait
+        # for both, or a poll between the two reads 0 refreshes.
+        while ((daemon.generation < 1 or trainer2.stats()["refreshes"] < 1)
+               and time.monotonic() < deadline):
             time.sleep(0.02)
         assert daemon.generation >= 1
         assert trainer2.stats()["refreshes"] >= 1
