@@ -7,6 +7,12 @@
 # takes the TPU when there is one. Compiled programs are kept where
 # JAX_COMPILATION_CACHE_DIR says, else in .xla_compile_cache/ of the repo.
 #
+# A fit takes every chip of the host (the mesh is jax.devices(); rows are
+# sharded over it, nothing else chooses it). The ImageNet fit at published
+# width on a four-chip host, 8,192 rows a chip:
+#   bin/run-pipeline.sh ImageNetSiftLcsFV --gmm-k 256 --synthetic-n 32768 \
+#     --synthetic-classes 1000
+#
 # Env knobs (the KEYSTONE_MEM analog):
 #   KEYSTONE_NUM_DEVICES=N         virtual CPU device count (testing meshes)
 #   KEYSTONE_NO_FUSE=1             disable chain fusion (debugging)

@@ -36,6 +36,7 @@ from keystone_tpu.utils.metrics import device_scope
 from keystone_tpu.linalg.row_matrix import (
     RowMatrix,
     _precision,
+    count_reduced,
     donate_argnums as _donate,
     sharded_rowsum,
     solver_matmul,
@@ -68,7 +69,7 @@ def _local_gram_inv(a_b, aw, lam, precision, axis, width):
     with device_scope("solver.gram"):
         gram = sharded_rowsum(
             lambda awb, ab: solver_matmul(awb.T, ab, precision),
-            axis, width, (aw, a_b),
+            axis, width, (aw, a_b), scope="coll.gram",
         )
         b = a_b.shape[1]
         gram = gram + lam * jnp.eye(b, dtype=gram.dtype)
@@ -80,7 +81,7 @@ def _local_solve_update(a_b, aw, inv, r, w_b, precision, axis, width):
         r_plus = r + solver_matmul(a_b, w_b, precision)
         rhs = sharded_rowsum(
             lambda awb, rb: solver_matmul(awb.T, rb, precision),
-            axis, width, (aw, r_plus),
+            axis, width, (aw, r_plus), scope="coll.atr",
         )
         w_b_new = solver_matmul(inv, rhs, precision)
         r_new = r_plus - solver_matmul(a_b, w_b_new, precision)
@@ -221,7 +222,7 @@ def _fused_factor_fn(mesh: Mesh, axis: str, precision, weighted: bool,
                 lambda awb, ab: solver_matmul(
                     jnp.swapaxes(awb, 1, 2), ab, precision
                 ),
-                axis, width, (aw, a3), row_axes=(1, 1),
+                axis, width, (aw, a3), row_axes=(1, 1), scope="coll.gram",
             )
             b = a3.shape[2]
             if pad:
@@ -493,6 +494,7 @@ def _solve_fused(
         # Chunked: bounds the factor transient to chunk·b² buffers instead
         # of nb·b².
         chunk = _factor_chunk(b)
+        count_reduced(mesh, nb * b * b * R.dtype.itemsize)  # the grams
         with span_of(tracer, "solver.factor", "solver", blocks=nb, chunk=chunk):
             if chunk >= nb:
                 invs = _fused_factor_fn(
@@ -521,6 +523,9 @@ def _solve_fused(
         # Dummy scan operand: the uncached body re-derives each block's
         # inverse in-place; scan only needs a leading-nb structure to carry.
         invs = jnp.zeros((nb, 1, 1), dtype=R.dtype)
+    # Aᵀ R a block visit, and without cached inverses the gram again.
+    count_reduced(mesh, (num_iters - start_epoch) * nb * b * R.dtype.itemsize
+                  * (R.shape[1] + (0 if cache_grams else b)))
     with span_of(tracer, "solver.epochs", "solver", blocks=nb,
                  epochs=num_iters - start_epoch):
         if pad:

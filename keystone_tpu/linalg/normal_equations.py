@@ -74,7 +74,7 @@ def _accum_gram_atb_fn(mesh: Mesh, axis: str, precision):
                 solver_matmul(ab.T, ab, precision),
                 solver_matmul(ab.T, bb, precision),
             ),
-            axis, width, (a, b),
+            axis, width, (a, b), scope="coll.gram",
         )
         return gram + g, atb + t
 

@@ -19,6 +19,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from keystone_tpu.config import config
+from keystone_tpu.utils.metrics import device_scope, program_counters
 from keystone_tpu.utils.mesh import (
     default_mesh,
     fold_blocks,
@@ -75,9 +76,25 @@ def solver_matmul(x, y, precision):
     return jnp.matmul(x, y, precision=precision)
 
 
-def sharded_rowsum(block_fn, axis: str, width: int, operands, row_axes=None):
+def count_reduced(mesh: Mesh, nbytes: int) -> None:
+    """Add ``nbytes`` to ``program_counters``' ``collective_bytes``: what a
+    reduction over ``mesh``'s data axis is handed, from shapes, on the host
+    that dispatches it (a second fit traces nothing, so nothing inside a
+    program can count). The size of the array that is summed, once,
+    whatever the exchange's algorithm sends; nothing on a mesh one wide,
+    where nothing crosses."""
+    if mesh.shape[config.data_axis] > 1:
+        program_counters.bump("collective_bytes", int(nbytes))
+
+
+def sharded_rowsum(block_fn, axis: str, width: int, operands, row_axes=None,
+                   *, scope: str):
     """THE reduction over the sharded row axis for every solver
     accumulator (grams, AᵀB, column sums) — call inside a shard_map body.
+    What crosses the mesh (the butterfly's exchanges and adds, or the
+    ``psum``) runs under ``device_scope(scope)``, one of the ``coll.``
+    names of ``DEVICE_SCOPES``; the partial sums a shard makes alone stay
+    in the caller's scope.
 
     ``block_fn(*row_slices)`` maps row slices of ``operands`` to a pytree
     of partial sums. With the canonical fold active
@@ -97,9 +114,9 @@ def sharded_rowsum(block_fn, axis: str, width: int, operands, row_axes=None):
         row_axes = (0,) * len(operands)
     C = fold_blocks(width)
     if not C:
-        return jax.tree_util.tree_map(
-            lambda v: lax.psum(v, axis), block_fn(*operands)
-        )
+        local = block_fn(*operands)
+        with device_scope(scope):
+            return jax.tree_util.tree_map(lambda v: lax.psum(v, axis), local)
     blocks_per_shard = C // width
     parts = []
     for i in range(blocks_per_shard):
@@ -120,12 +137,13 @@ def sharded_rowsum(block_fn, axis: str, width: int, operands, row_axes=None):
         ]
     acc = parts[0]
     step = 1
-    while step < width:
-        perm = [(i, i ^ step) for i in range(width)]
-        acc = jax.tree_util.tree_map(
-            lambda v, p=perm: v + lax.ppermute(v, axis, p), acc
-        )
-        step *= 2
+    with device_scope(scope):
+        while step < width:
+            perm = [(i, i ^ step) for i in range(width)]
+            acc = jax.tree_util.tree_map(
+                lambda v, p=perm: v + lax.ppermute(v, axis, p), acc
+            )
+            step *= 2
     return acc
 
 
@@ -137,7 +155,8 @@ def _gram_fn(mesh: Mesh, axis: str, precision, fold: int):
     @partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     def gram(a):
         return sharded_rowsum(
-            lambda ab: solver_matmul(ab.T, ab, precision), axis, width, (a,)
+            lambda ab: solver_matmul(ab.T, ab, precision), axis, width, (a,),
+            scope="coll.gram",
         )
 
     return gram
@@ -152,7 +171,7 @@ def _atb_fn(mesh: Mesh, axis: str, precision, fold: int):
     def atb(a, b):
         return sharded_rowsum(
             lambda ab, bb: solver_matmul(ab.T, bb, precision),
-            axis, width, (a, b),
+            axis, width, (a, b), scope="coll.atr",
         )
 
     return atb
@@ -171,7 +190,7 @@ def _gram_and_atb_fn(mesh: Mesh, axis: str, precision, fold: int):
                 solver_matmul(ab.T, ab, precision),
                 solver_matmul(ab.T, bb, precision),
             ),
-            axis, width, (a, b),
+            axis, width, (a, b), scope="coll.gram",
         )
 
     return gram_and_atb
@@ -185,7 +204,8 @@ def _col_sum_fn(mesh: Mesh, axis: str, fold: int):
     @partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     def col_sum(a):
         return sharded_rowsum(
-            lambda ab: jnp.sum(ab, axis=0), axis, width, (a,)
+            lambda ab: jnp.sum(ab, axis=0), axis, width, (a,),
+            scope="coll.moments",
         )
 
     return col_sum
@@ -199,7 +219,8 @@ def _weighted_col_sum_fn(mesh: Mesh, axis: str, fold: int):
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(), check_vma=False)
     def weighted_col_sum(w, a):
         return sharded_rowsum(
-            lambda wb, ab: jnp.sum(wb * ab, axis=0), axis, width, (w, a)
+            lambda wb, ab: jnp.sum(wb * ab, axis=0), axis, width, (w, a),
+            scope="coll.moments",
         )
 
     return weighted_col_sum
@@ -213,6 +234,11 @@ def _matmul_fn(mesh: Mesh, axis: str, precision):
         return solver_matmul(a, w, precision)
 
     return mm
+
+
+def _width(matrix: "RowMatrix") -> int:
+    """A row's width: 1 for the vector a one-column B may be."""
+    return int(np.prod(matrix.data.shape[1:], dtype=np.int64))
 
 
 class RowMatrix:
@@ -273,6 +299,8 @@ class RowMatrix:
     def gram(self) -> jax.Array:
         """AᵀA, replicated: per-shard MXU gemm + psum over ICI
         (the ``treeAggregate`` of local grams in NormalEquations)."""
+        d = _width(self)
+        count_reduced(self.mesh, d * d * self.data.dtype.itemsize)
         return _gram_fn(
             self.mesh, config.data_axis, _precision(),
             fold_blocks(self.num_shards),
@@ -281,6 +309,8 @@ class RowMatrix:
     def atb(self, other: "RowMatrix") -> jax.Array:
         """AᵀB for a row-aligned B."""
         self._check_aligned(other)
+        count_reduced(self.mesh, _width(self) * _width(other)
+                      * self.data.dtype.itemsize)
         return _atb_fn(
             self.mesh, config.data_axis, _precision(),
             fold_blocks(self.num_shards),
@@ -289,6 +319,9 @@ class RowMatrix:
     def gram_and_atb(self, other: "RowMatrix"):
         """(AᵀA, AᵀB) in one fused program — A is read once."""
         self._check_aligned(other)
+        d = _width(self)
+        count_reduced(self.mesh, d * (d + _width(other))
+                      * self.data.dtype.itemsize)
         return _gram_and_atb_fn(
             self.mesh, config.data_axis, _precision(),
             fold_blocks(self.num_shards),
@@ -301,6 +334,7 @@ class RowMatrix:
         the same mesh, the result is bit-identical no matter what
         placement the source array arrived with (the property that keeps
         intercept means — and thus whole fits — placement-invariant)."""
+        count_reduced(self.mesh, _width(self) * self.data.dtype.itemsize)
         return _col_sum_fn(
             self.mesh, config.data_axis, fold_blocks(self.num_shards)
         )(self.data)
@@ -309,6 +343,7 @@ class RowMatrix:
         """Σ_i w_i · row_i for a row-aligned (n, 1) weight column — the
         weighted-centering reduction, psum'd like ``col_sums``."""
         self._check_aligned(weights)
+        count_reduced(self.mesh, _width(self) * self.data.dtype.itemsize)
         return _weighted_col_sum_fn(
             self.mesh, config.data_axis, fold_blocks(self.num_shards)
         )(weights.data, self.data)

@@ -179,10 +179,16 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         stream = self.stream
         itemsize = jnp.dtype(config.default_dtype).itemsize
         if stream is None:
+            from keystone_tpu.utils.mesh import num_data_shards
             from keystone_tpu.utils.metrics import device_hbm_bytes
 
+            # What ONE device would hold: the solve shards the rows over
+            # the mesh wherever they arrived, so a matrix four chips hold a
+            # quarter each of is not one that "exceeds HBM" (counted whole,
+            # 32,768 x 65,536 rows on a v5e-4 went to the host and back a
+            # block at a time: 45 s a fit for 2: PERF.md, PR 38).
             a_bytes = int(np.prod(np.shape(data))) * itemsize
-            stream = a_bytes > device_hbm_bytes() // 2
+            stream = a_bytes // num_data_shards() > device_hbm_bytes() // 2
 
         if stream:
             # Features stay in host RAM — the caller's array, uncopied and
@@ -417,7 +423,12 @@ class BlockWeightedLeastSquaresEstimator(BlockLeastSquaresEstimator):
         classes = jnp.argmax(Y, axis=1)
         k = Y.shape[1]
         n = Y.shape[0]
-        counts = jnp.bincount(classes, length=k).astype(Y.dtype)
+        # The classes' counts are column sums of the rows' indicators: over
+        # sharded rows they reduce as the means do (``RowMatrix.col_sums``),
+        # where a ``bincount`` would be left to the partitioner.
+        counts = RowMatrix.from_array(
+            jax.nn.one_hot(classes, k, dtype=Y.dtype), dtype=Y.dtype
+        ).col_sums()
         counts = jnp.maximum(counts, 1.0)
         per_class = (1.0 - self.mixture_weight) + self.mixture_weight * n / (
             k * counts

@@ -206,7 +206,7 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
                 k_bb = take_block(k_b, start)
                 kt_alpha = sharded_rowsum(
                     lambda kb, al: solver_matmul(kb.T, al, precision),
-                    axis, width, (k_b, alpha),
+                    axis, width, (k_b, alpha), scope="coll.atr",
                 )
                 r = _block_residual(take_block(yl, start), kt_alpha, k_bb,
                                     take_block(alpha, start), precision)

@@ -40,6 +40,7 @@ from keystone_tpu.nodes.images.external.fisher_vector import (
 from keystone_tpu.nodes.images.lcs import LCSExtractor
 from keystone_tpu.nodes.learning import BlockWeightedLeastSquaresEstimator
 from keystone_tpu.nodes.util import ClassLabelIndicators, TopKClassifier
+from keystone_tpu.utils.mesh import num_data_shards
 from keystone_tpu.utils.metrics import active_tracer, program_counters, span_of
 from keystone_tpu.workflow import Pipeline, placed_batch
 
@@ -366,10 +367,14 @@ def fit(conf: ImageNetSiftLcsFVConfig, train: LabeledData, num_classes: int):
     # root_id. It closes when the solver's programs are dispatched, not
     # when the device has run them. It carries how the fit's transformer
     # programs were found (``program_counters``): a closure call is a
-    # program traced for this fit alone.
+    # program traced for this fit alone; and over how many devices of the
+    # mesh its rows lie, with what its reductions across them were handed
+    # (``collective_bytes``).
+    shards = num_data_shards()
     with span_of(active_tracer(), "fit", "pipeline",
                  pipeline="ImageNetSiftLcsFV",
-                 rows=int(len(train.data))) as attrs:
+                 rows=int(len(train.data)), shards=shards,
+                 rows_per_shard=-(-int(len(train.data)) // shards)) as attrs:
         calls = program_counters.calls()
         # Four walks read the train images (each branch's descriptors,
         # then each branch's whole chain): they reach the device once.

@@ -355,6 +355,11 @@ DEVICE_SCOPES = (
     "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
     "conv.patches", "conv.kernel", "conv.relayout",
     "gmm.estep", "gmm.mstep",
+    # What crosses the mesh: ``row_matrix.sharded_rowsum``'s exchanges by
+    # what is summed (grams; AᵀR and the like; column sums for the means;
+    # the mixture's statistics of an EM sweep), and the descriptor sample's
+    # gather across the shards.
+    "coll.gram", "coll.atr", "coll.moments", "coll.em", "coll.sample",
 )
 
 #: A fused chain's step is scoped by this and its stage's class name, as the
@@ -1966,10 +1971,15 @@ class ProgramCounters(CounterSet):
       walk's prefix hashes or a disk-cache key. A fit's root span carries
       this and the two call counts (``since``); a fit that places its
       host batch once (``placed_batch``) reads 1 here
+    - ``collective_bytes``: the size of every array a reduction across the
+      mesh was handed (``linalg/row_matrix.count_reduced``: grams, AᵀR,
+      column sums, the mixture's statistics a sweep, the descriptor
+      sample), from shapes on the dispatching host; 0 on one device. On
+      the fit's root span with the others
     """
 
     _A_FIT = ("shared_program_calls", "closure_program_calls",
-              "dataset_fingerprints")
+              "dataset_fingerprints", "collective_bytes")
 
     def calls(self) -> Dict[str, int]:
         """The counts a fit reports as they stand: a mark for ``since``."""

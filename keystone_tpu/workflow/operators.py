@@ -74,7 +74,8 @@ def placed_batch(data: Any):
     is in flight. ``DatasetOperator.signature`` and
     ``fingerprint.batch_fingerprint`` read that fingerprint for the placed
     array, so every prefix hash and disk-cache key is the host array's.
-    One ``data.place`` span (``bytes``: what crossed to the device).
+    One ``data.place`` span (``bytes``: what crossed to the device;
+    ``sharded``: 1 where each device was put its own rows).
     """
     import jax
 
@@ -91,7 +92,8 @@ def placed_batch(data: Any):
         yield data
         return
     with span_of(active_tracer(), "data.place", "pipeline",
-                 bytes=int(data.nbytes), rows=int(data.shape[0])):
+                 bytes=int(data.nbytes), rows=int(data.shape[0]),
+                 sharded=int(klass == "shard")):
         if klass == "shard":  # the operator's own placement, counted as its
             placed = DatasetOperator(data).execute([])
         else:

@@ -674,8 +674,22 @@ class FusedTransformer(Transformer):
         )
 
     def apply_sharded(self, X, layout):
-        # Thread the layout so stages with a sharded kernel strategy
-        # (Pallas shard_map on TPU) see it inside the ONE fused lowering.
+        """A chain of row-independent stages runs every shard's rows
+        through the one-device lowering (``apply_batch`` under
+        ``shard_map``: the tile rule asked of the shard's own shape, a step
+        that takes the stages behind it, a kernel on the rows it is
+        handed), the chain's arrays replicated beside them. A chain that
+        couples rows is walked stage by stage and left to the partitioner,
+        each stage told the layout."""
+        if self.row_independent:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            return shard_map(
+                lambda chain, x: chain.apply_batch(x), mesh=layout.mesh,
+                in_specs=(P(), P(layout.axis)), out_specs=P(layout.axis),
+                check_vma=False,
+            )(self, X)
         for s in self.stages:
             with stage_scope(s):
                 X = s.apply_sharded(X, layout)

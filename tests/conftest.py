@@ -44,3 +44,22 @@ def fresh_env():
     yield
     PipelineEnv.reset()
     reset_default_mesh()
+
+
+def assert_same_to_rounding(got, want, limit: float = 1e-5):
+    """``got`` is ``want`` up to the order in which float32 sums were made:
+    the norm of the difference is at most ``limit`` of ``want``'s norm.
+
+    What a mesh computes and what one device computes were pinned byte for
+    byte until the installed XLA:CPU (jax 0.9.0) began to choose its dot
+    kernel by shape: a product of 9 rows a shard and one of all 72 rows now
+    sum each inner product in another order (1.2e-6 apart in a third of the
+    entries; the commit the tests came with fails the same way here). A
+    changed order moves a float32 inner product by a few ulp times the root
+    of its depth, 1e-6 of the norm at these widths, and a solve on top of it
+    by its condition number times that; a wrong row, a pad row that leaked
+    or a shard left out moves it by 1e-2 or more."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape, (got.shape, want.shape)
+    gap = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert gap <= limit, f"{gap:.3g} of the norm apart (limit {limit:g})"

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_same_to_rounding
 
 from keystone_tpu.config import config
 from keystone_tpu.utils import mesh as mesh_util
@@ -171,8 +172,9 @@ def test_dataset_operator_places_and_counts():
 
 def test_fused_chain_pads_trims_and_counts():
     """A non-divisible batch through a jittable chain: output rows are
-    unchanged, values are bit-identical to the unsharded walk, and the
-    pad traffic is registry-counted — zero silent fallbacks."""
+    unchanged, values are the unsharded walk's to rounding (9 rows a shard
+    against 70 in one product), and the pad traffic is registry-counted:
+    zero silent fallbacks."""
     t = MatmulChain(3)
     X = np.random.default_rng(0).normal(size=(70, 32)).astype(np.float32)
     config.shard_data_batches = False
@@ -181,7 +183,7 @@ def test_fused_chain_pads_trims_and_counts():
     sharding_counters.reset()
     out = t.batch_call(X)
     assert out.shape[0] == 70
-    np.testing.assert_array_equal(np.asarray(out), ref)
+    assert_same_to_rounding(out, ref)
     snap = sharding_counters.snapshot()
     assert snap.get("batches_padded") == 1
     assert snap.get("pad_rows_added") == 2
@@ -218,7 +220,7 @@ def test_sharded_input_uses_explicit_specs():
     assert sharding_counters.get("sharded_chain_calls") == 1
     assert layout_of_array(out) == layout
     config.shard_data_batches = False
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(t.batch_call(X)))
+    assert_same_to_rounding(out, t.batch_call(X))
 
 
 # ---------------------------------------------------------------------------
@@ -229,8 +231,9 @@ def test_sharded_input_uses_explicit_specs():
 @pytest.mark.parametrize("rows", [512, 518])
 def test_two_branch_fit_apply_bit_identical(rows):
     """The two-branch featurize→solve shape, divisible and mask-padded:
-    the sharded walk's held-out predictions equal the single-device
-    walk's byte for byte."""
+    the sharded walk's held-out predictions are the single-device
+    walk's to rounding (its products and grams are summed a shard at a
+    time), through a ridge solve at lambda 1e-3."""
     rng = np.random.default_rng(0)
     X = rng.normal(size=(rows, 32)).astype(np.float32)
     y = rng.normal(size=(rows, 4)).astype(np.float32)
@@ -239,7 +242,7 @@ def test_two_branch_fit_apply_bit_identical(rows):
     ref = _fit_apply(build, X_test, shard=False)
     sharding_counters.reset()
     got = _fit_apply(build, X_test, shard=True)
-    np.testing.assert_array_equal(ref, got)
+    assert_same_to_rounding(got, ref)
     snap = sharding_counters.snapshot()
     assert snap.get("sharded_chain_calls", 0) > 0
     assert "fallback_small_batch" not in snap
@@ -322,8 +325,8 @@ def test_chaos_parity_sharded_fit():
 
 
 def test_fitted_forward_with_layout():
-    """The functional replay lowered once with explicit shardings is
-    bit-identical to the un-jitted replay and row-sharded on output."""
+    """The functional replay lowered once with explicit shardings is the
+    un-jitted replay's to rounding and row-sharded on output."""
     from keystone_tpu.workflow.functional import fitted_forward
 
     rng = np.random.default_rng(0)
@@ -337,7 +340,7 @@ def test_fitted_forward_with_layout():
     ref = np.asarray(jax.jit(fn_plain)(Xb))
     out = fn_sharded(layout.put(Xb))
     assert layout_of_array(out) == layout
-    np.testing.assert_array_equal(np.asarray(out), ref)
+    assert_same_to_rounding(out, ref)
 
 
 # ---------------------------------------------------------------------------

@@ -845,6 +845,14 @@ def test_imagenet_fit_under_a_session_is_one_tree_with_every_span(session):
     assert [r["name"] for r in roots] == ["fit"]
     (root,) = roots
     assert root["args"]["rows"] == 48
+    # The mesh the fit's reductions cross (the tests' 8 devices), the rows'
+    # share of each, and what those reductions were handed, from shapes.
+    assert root["args"]["shards"] == 8 and root["args"]["rows_per_shard"] == 6
+    assert root["args"]["collective_bytes"] > 0
+    from keystone_tpu.utils.metrics import DEVICE_SCOPES
+
+    assert {"coll.gram", "coll.atr", "coll.moments", "coll.em", "coll.sample"} <= set(
+        DEVICE_SCOPES)
     assert all(s["root_id"] == root["id"] for s in spans)
     names = {s["name"] for s in spans}
     table = {"fit", "pipeline.fit", "fisher.describe", "fisher.sample",
@@ -1028,19 +1036,23 @@ def _solver_lowerings():
     }
 
 
+# On the tests' mesh of 8 each program's reductions across it carry their
+# ``coll.`` scope too (``sharded_rowsum``: the grams under ``coll.gram``,
+# Aᵀ R and the kernel's Kᵀ α under ``coll.atr``), innermost on the path.
 SOLVER_SCOPES = {
     "stack": {"solver.stack"},
-    "factor": {"solver.gram", "solver.cholesky", "solver.inverse"},
-    "cached epochs": {"solver.update"},
-    "uncached epochs": {"solver.update", "solver.gram", "solver.cholesky", "solver.inverse"},
+    "factor": {"solver.gram", "solver.cholesky", "solver.inverse", "coll.gram"},
+    "cached epochs": {"solver.update", "coll.atr"},
+    "uncached epochs": {"solver.update", "solver.gram", "solver.cholesky", "solver.inverse",
+                        "coll.gram", "coll.atr"},
     "streamed first epoch": {"solver.update", "solver.gram", "solver.cholesky",
-                             "solver.inverse"},
-    "streamed cached update": {"solver.update"},
-    "kernel solver": {"krr.generate", "krr.reduce", "krr.factor", "krr.solve"},
+                             "solver.inverse", "coll.gram", "coll.atr"},
+    "streamed cached update": {"solver.update", "coll.atr"},
+    "kernel solver": {"krr.generate", "krr.reduce", "krr.factor", "krr.solve", "coll.atr"},
     "kernel solver, blocks kept": {
-        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve"},
+        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve", "coll.atr"},
     "kernel solver, some blocks kept": {
-        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve"},
+        "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve", "coll.atr"},
 }
 
 
@@ -1061,6 +1073,12 @@ def test_the_device_scopes_reach_the_compiled_programs_op_names(phase):
     assert found == SOLVER_SCOPES[phase] and found <= set(DEVICE_SCOPES)
     for name in names:
         segments = [s for s in name.split("/") if s in DEVICE_SCOPES]
+        # What crosses the mesh is the innermost scope of its path, inside
+        # the scope of what is summed.
+        if segments and segments[-1].startswith("coll."):
+            assert len(segments) >= 2 and not segments[-2].startswith("coll."), name
+            segments.pop()
+        assert not [s for s in segments if s.startswith("coll.")], name
         # A scope is entered once on a path but for the update's loops,
         # which hold the uncached body's gram and inverse.
         assert len(segments) <= 1 or segments[0] == "solver.update", name

@@ -252,7 +252,7 @@ def test_what_does_not_hash_by_value_keeps_its_own_closure(make):
     assert _program(name)._cache_size() == entries
     assert program_counters.since(calls) == {
         "shared_program_calls": 0, "closure_program_calls": 1,
-        "dataset_fingerprints": 0}
+        "dataset_fingerprints": 0, "collective_bytes": 0}
 
 
 def test_value_hashed_fields_of_many_kinds_share():

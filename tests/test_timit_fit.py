@@ -129,7 +129,11 @@ def test_the_chains_program_holds_no_constant_of_the_projections_size():
 
     layout = SpecLayout.for_mesh()
     sharded = chain._jitted_sharded(layout).lower(layout.put(X)).as_text()
-    assert len(re.findall(r"%arg\d+: tensor", sharded)) == 5
+    # The same five into the program, and again into the body that
+    # ``shard_map`` runs a shard's rows through (PR 38).
+    (main,) = re.findall(r"func\.func public @main\(([^\n]*)", sharded)
+    assert len(re.findall(r"%arg\d+: tensor", main)) == 5
+    assert len(re.findall(r"%arg\d+: tensor", sharded)) == 10
     assert all(n <= 1 for n in _constants(sharded))
 
 
