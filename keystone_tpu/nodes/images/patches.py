@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from keystone_tpu.utils.metrics import device_scope
 from keystone_tpu.workflow import Transformer
 
 
@@ -32,26 +33,35 @@ def windows(X, fh: int, fw: int, stride: int = 1):
 @functools.partial(jax.jit, static_argnames="size")
 def _take_patches(X, image, top, left, size: int):
     """The (size, size, c) windows of ``X`` (n, h, w, c) at ``top``,
-    ``left`` of the images ``image``: (patches, size, size, c). Each is one
-    slice of the (n, h, w·c) view, size rows by size·c contiguous values:
-    a gather whose rows are c values wide (three for colour images) takes
-    the TPU's compiler eight minutes at 100,000 patches, this one seconds
-    (it runs as a loop of slices, 3 us a patch: PERF.md, PR 32). The
-    indices are arguments: one program for every draw."""
+    ``left`` of the images ``image``: (patches, size, size, c). Each patch's
+    ``size`` whole rows are one slice of the (n, h, w·c) view; a slice as
+    wide as the row lowers to one gather, where a narrower one ran as a
+    loop of 100,000 slices (0.30 s of a ``cifar-fit`` fit: PERF.md, PR 39)
+    and a gather of c-wide rows took the TPU's compiler eight minutes (PR
+    32). The patch's size·c columns are then picked from each row by a
+    select and a sum over the row's lanes, on the values' bits: one term of
+    each sum is not zero, so the patches are the images' values exactly.
+    The indices are arguments: one program for every draw."""
     n, h, w, c = X.shape
-    rows = X.reshape(n, h, w * c)
-
-    def window(i, t, l):
-        return lax.dynamic_slice(rows, (i, t, l * c), (1, size, size * c))[0]
-
-    return jax.vmap(window)(image, top, left).reshape(-1, size, size, c)
+    with device_scope("filters.rows"):
+        bits = lax.bitcast_convert_type(X, jnp.dtype(f"uint{8 * X.dtype.itemsize}"))
+        rows = bits.reshape(n, h, w * c)
+        slabs = jax.vmap(  # (patches, size, w·c)
+            lambda i, t: lax.dynamic_slice(rows, (i, t, 0), (1, size, w * c))[0]
+        )(image, top)
+    with device_scope("filters.cols"):
+        cols = left[:, None] * c + jnp.arange(size * c)  # (patches, size·c)
+        pick = jnp.arange(w * c) == cols[:, None, :, None]
+        picked = jnp.where(pick, slabs[:, :, None, :], 0).sum(-1, dtype=rows.dtype)
+    return lax.bitcast_convert_type(picked, X.dtype).reshape(-1, size, size, c)
 
 
 class RandomPatcher(Transformer):
     """Extract `num_patches` random (size × size) patches from the batch —
     the filter-learning sampler of RandomPatchCifar. Deterministic by seed.
 
-    Host-side index generation (tiny), one device gather (fast).
+    The indices are drawn on the host (numpy, from the seed); the patches
+    are cut on the device by one gather of their rows (``_take_patches``).
     """
 
     jittable = False  # output count depends on num_patches, not batch size

@@ -354,6 +354,7 @@ DEVICE_SCOPES = (
     "solver.update",
     "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
     "conv.patches", "conv.kernel", "conv.relayout",
+    "filters.rows", "filters.cols",
     "gmm.estep", "gmm.mstep",
     # What crosses the mesh: ``row_matrix.sharded_rowsum``'s exchanges by
     # what is summed (grams; AᵀR and the like; column sums for the means;

@@ -56,6 +56,20 @@ COUNTED_BYTES = 1 << 20
 PLACED: dict = {}
 
 
+def host_values(a) -> np.ndarray:
+    """The values of the device array ``a`` on the host, in C order, to be
+    hashed. The TPU lays a batch of many axes out with its rows minor (6,250
+    CIFAR images of 32 x 32 x 3: host strides (4, 2400000, 25000, 800000)),
+    the host copy keeps that order, and putting it in C order on the host
+    took 0.20 s where hashing it took 0.12 (PR 39). Such an array is
+    flattened on the device and fetched again: it comes back in C order in
+    0.024 s, the same bytes, so the same fingerprint."""
+    values = np.asarray(a)
+    if values.flags.c_contiguous:
+        return values
+    return np.asarray(a.reshape(-1)).reshape(a.shape)
+
+
 def batch_fingerprint(a) -> tuple:
     """``array_fingerprint`` of a numeric batch wherever it lies. A batch
     that ``operators.placed_batch`` put on the device answers with the
@@ -63,7 +77,7 @@ def batch_fingerprint(a) -> tuple:
     and nothing comes back from the device. Any other device array is
     fetched, so the caller bounds its size."""
     fp = PLACED.get(id(a))
-    return fp if fp is not None else array_fingerprint(np.asarray(a))
+    return fp if fp is not None else array_fingerprint(host_values(a))
 
 
 def array_fingerprint(a: np.ndarray) -> tuple:
@@ -194,7 +208,7 @@ def stable_value(v: Any) -> Any:
             tuple((k, stable_value(v[k])) for k in sorted(v)),
         )
     if _is_jax_array(v):
-        v = np.asarray(v)  # one host fetch, then content-addressed like numpy
+        v = host_values(v)  # one host fetch, then content-addressed like numpy
     if isinstance(v, np.ndarray):
         if v.dtype.kind in "biufc":
             return array_fingerprint(v)

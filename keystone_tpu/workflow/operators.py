@@ -187,6 +187,7 @@ class DatasetOperator(Operator):
                 UNSTABLE,
                 PLACED,
                 array_fingerprint,
+                host_values,
             )
 
             from keystone_tpu.config import config
@@ -202,7 +203,7 @@ class DatasetOperator(Operator):
                     # for a device array; not worth it.
                     self._sig_cache = ("dataset", id(self.data), UNSTABLE)
                     return self._sig_cache
-                data = np.asarray(data)
+                data = host_values(data)
             if isinstance(data, np.ndarray) and data.dtype.kind in "biufc":
                 # array_fingerprint switches to a bounded chunk-sampled
                 # digest above config.fingerprint_max_bytes, so huge fit

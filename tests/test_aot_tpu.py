@@ -323,6 +323,25 @@ def test_conv_rectify_pool_mosaic_compiles_at_cifar_fit_config(mesh):
     assert memory.temp_size_in_bytes < 7 * 2**30
 
 
+def test_patch_cut_is_one_gather_at_cifar_fit_shape(mesh):
+    """The filter fit's patch cut at ``cifar-fit``'s sizes (100,000 patches
+    of 6 x 6 x 3 from 6,250 images) for one v5e chip: no loop (a slice
+    narrower than the image row ran as 100,000 of them, 0.30 s a fit) and
+    no more scratch than the row gather's 0.54 GB (a gather of single
+    values would hold 3.3 GB, more than ``cifar-kernel-fit``'s chip has
+    free beside its solver)."""
+    from keystone_tpu.nodes.images.patches import _take_patches
+
+    one = Mesh(np.array(mesh.devices.flat[:1]), ("d",))
+    idx = _sds((100_000,), one, P(), jnp.int32)
+    compiled = _take_patches.lower(
+        _sds((6250, 32, 32, 3), one, P()), idx, idx, idx, size=6).compile()
+    text = compiled.as_text()
+    assert "while" not in text and "gather" in text
+    assert "filters.rows" in text and "filters.cols" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.7e9
+
+
 @pytest.mark.parametrize("keep", [0, 13])
 def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh, keep):
     """The kernel solver's one program at ``cifar-kernel-fit``'s sizes

@@ -259,9 +259,13 @@ def test_the_recorded_trace_holds_every_scope_with_time_of_its_own(recorded):
     # (``conv.relayout``) into a neighbour; every other scope runs alone.
     # And on the one chip that recorded it nothing crosses a mesh: the
     # ``coll.`` scopes name nothing there (``tests/test_imagenet_mesh.py``
-    # holds them in the mesh's compiled programs).
+    # holds them in the mesh's compiled programs). The recording predates
+    # the patch cut's scopes (``filters.``, PR 39), which
+    # ``tools/record_scoped_trace.py`` now runs: recorded again on a chip,
+    # they join the rest; ``tests/test_aot_tpu.py`` holds them meanwhile.
     for scope in DEVICE_SCOPES:
-        if scope != "conv.relayout" and not scope.startswith("coll."):
+        if (scope != "conv.relayout" and not scope.startswith("coll.")
+                and not scope.startswith("filters.")):
             assert seconds.get(scope, 0.0) > 0.0, scope
     assert not [scope for scope in seconds if scope.startswith("coll.")]
     stages = {r["stage"] for r in table["rows"] if r["stage"]}

@@ -2,6 +2,7 @@
 strategy: compare against naive convolution; SURVEY.md §4)."""
 
 import numpy as np
+import pytest
 
 from keystone_tpu.nodes.images import (
     CenterCornerPatcher,
@@ -80,6 +81,34 @@ def test_random_patcher_shapes_and_determinism(rng):
     b = np.asarray(RandomPatcher(32, 5, seed=7)(X))
     assert a.shape == (32, 5, 5, 3)
     np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("n,h,w,c,size", [
+    (4, 10, 10, 3, 3),   # colour, square
+    (3, 7, 11, 1, 4),    # one channel, not square
+    (2, 5, 6, 3, 1),     # one pixel a patch
+    (2, 6, 9, 3, 6),     # as tall as the image: every top is 0
+])
+def test_take_patches_equals_slices(n, h, w, c, size):
+    """The patch cut (a gather of whole rows, then a select on the values'
+    bits) gives each patch's values exactly, signed zero and NaN too, at
+    the last top and left and for indices drawn twice."""
+    from keystone_tpu.nodes.images.patches import _take_patches
+
+    rng = np.random.default_rng(n * h * w * c * size)
+    X = rng.normal(size=(n, h, w, c)).astype(np.float32)
+    X[0, 0, 0, 0], X[-1, -1, -1, -1] = -0.0, np.nan
+    image = rng.integers(0, n, size=40)
+    top = rng.integers(0, h - size + 1, size=40)
+    left = rng.integers(0, w - size + 1, size=40)
+    image[:3], top[:3], left[:3] = n - 1, h - size, w - size
+    image[3:5], top[3:5], left[3:5] = 0, 0, 0
+    out = np.asarray(_take_patches(
+        X, *(a.astype(np.int32) for a in (image, top, left)), size=size))
+    ref = np.stack([X[i, t:t + size, l:l + size, :]
+                    for i, t, l in zip(image, top, left)])
+    assert out.shape == (40, size, size, c)
+    assert np.array_equal(out.view(np.int32), ref.view(np.int32))
 
 
 def test_windower_matches_direct(rng):

@@ -173,6 +173,38 @@ def test_the_placed_batch_signs_as_its_host_array(train, monkeypatch, limit):
     assert not PLACED
 
 
+class _RowsMinor:
+    """A device array whose host copy comes back with its rows minor, as a
+    TPU hands back a batch of images."""
+
+    def __init__(self, x):
+        self.x, self.shape = x, x.shape
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asfortranarray(np.asarray(self.x))
+
+    def reshape(self, *shape):
+        return self.x.reshape(*shape)
+
+
+@pytest.mark.parametrize("shape", [(7,), (5, 3), (6, 4, 4, 3)])
+def test_a_device_array_signs_as_its_host_array(shape):
+    """A device array's values reach the hash in C order
+    (``fingerprint.host_values``: one whose host copy is not is flattened on
+    the device and fetched again); the bytes, and so every signature over
+    them, are the host array's."""
+    from keystone_tpu.workflow.fingerprint import host_values, stable_value
+
+    host = np.arange(np.prod(shape), dtype=np.float32).reshape(shape) - 3.5
+    on_device = jax.device_put(host)
+    for got in (host_values(on_device), host_values(_RowsMinor(on_device))):
+        assert got.flags.c_contiguous and got.shape == shape
+        np.testing.assert_array_equal(got, host)
+    assert DatasetOperator(on_device).signature() == DatasetOperator(host).signature()
+    assert batch_fingerprint(on_device) == array_fingerprint(host)
+    assert stable_value(on_device) == stable_value(host)
+
+
 def test_what_is_no_numeric_host_array_passes_through():
     on_device = jax.numpy.ones((4, 3))
     for data in (on_device, ["a", "b"], np.array(["a", "b"]), 3.0):

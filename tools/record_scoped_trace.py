@@ -3,7 +3,8 @@ traced "fits" at toy widths, each running every family of device scopes
 (``keystone_tpu.utils.metrics.DEVICE_SCOPES`` and a fused chain's stages)
 once: a cached and an uncached block least-squares solve with a ragged last
 block, the kernel solver, a chain whose convolver takes its rectifier and
-pooler, and a mixture fit. On the chip, through the chip tool:
+pooler, the filter fit's patch cut, and a mixture fit. On the chip, through
+the chip tool:
 
     python3 tools/record_scoped_trace.py chiprun_out/scoped-fit.xplane.pb
 
@@ -32,6 +33,7 @@ def one_fit(rng_seed: int = 0):
         Convolver,
         ImageVectorizer,
         Pooler,
+        RandomPatcher,
         SymmetricRectifier,
     )
     from keystone_tpu.nodes.learning import GaussianKernelGenerator
@@ -57,6 +59,7 @@ def one_fit(rng_seed: int = 0):
         KernelRidgeRegression(GaussianKernelGenerator(0.01), lam=1.0, block_size=64,
                               num_epochs=2).fit(x[:, :32], y).alpha,
         chain.batch_call(jnp.asarray(images)),
+        RandomPatcher(num_patches=64, patch_size=6).apply_batch(jnp.asarray(images)),
         GaussianMixtureModelEstimator(k=4, max_iters=3).fit(x[:, :8]).means,
     ]
     jax.block_until_ready(out)
