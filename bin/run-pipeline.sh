@@ -13,6 +13,11 @@
 #   bin/run-pipeline.sh ImageNetSiftLcsFV --gmm-k 256 --synthetic-n 32768 \
 #     --synthetic-classes 1000
 #
+# MnistRandomFFT at upstream's launch, 200 FFTs (102,400 features, never
+# held: the block solver makes each block of 2,048 from the images):
+#   bin/run-pipeline.sh MnistRandomFFT --num-ffts 200 --block-size 2048 --lam 1e-4 \
+#     --num-epochs 3 --synthetic-n 60000
+#
 # Env knobs (the KEYSTONE_MEM analog):
 #   KEYSTONE_NUM_DEVICES=N         virtual CPU device count (testing meshes)
 #   KEYSTONE_NO_FUSE=1             disable chain fusion (debugging)

@@ -17,14 +17,17 @@ from keystone_tpu.linalg.normal_equations import (
 )
 from keystone_tpu.linalg.tsqr import tsqr_r, solve_least_squares_tsqr
 from keystone_tpu.linalg.bcd import (
+    BlockSource,
     assemble_blocks,
     block_coordinate_descent,
+    block_coordinate_descent_generated,
     block_coordinate_descent_streamed,
 )
 from keystone_tpu.linalg.ring_bcd import block_coordinate_descent_ring
 
 __all__ = [
     "RowMatrix",
+    "BlockSource",
     "assemble_blocks",
     "solve_least_squares_normal",
     "solve_least_squares_chunked",
@@ -32,5 +35,6 @@ __all__ = [
     "solve_least_squares_tsqr",
     "block_coordinate_descent",
     "block_coordinate_descent_streamed",
+    "block_coordinate_descent_generated",
     "block_coordinate_descent_ring",
 ]

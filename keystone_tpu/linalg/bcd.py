@@ -35,9 +35,12 @@ from keystone_tpu.utils.mesh import fold_blocks, register_reshard_adapter
 from keystone_tpu.utils.metrics import device_scope
 from keystone_tpu.linalg.row_matrix import (
     RowMatrix,
+    _blocks_kept,
     _precision,
     count_reduced,
     donate_argnums as _donate,
+    keep_block,
+    read_or_make,
     sharded_rowsum,
     solver_matmul,
     storage_dtype,
@@ -550,6 +553,206 @@ def _solve_fused(
                 )
             wait_for_checkpoints(checkpoint_dir)
     return true_widths(W3), blocks
+
+
+# -- the generated-block solve: a block operand made from the narrow input --
+
+
+class BlockSource:
+    """A feature matrix the solver never holds: the narrow rows ``X`` it is
+    made from, and ``generator``, a pytree whose ``block(X, b)`` makes
+    column block ``b`` of those rows for a traced ``b``: ``block`` columns,
+    [b·block, (b+1)·block) of a matrix ``dim`` wide, zeros past ``dim`` in
+    a ragged last block. It has to be a pure function of each row alone:
+    the solver calls it on a shard's rows, the generator's arrays
+    replicated. ``BlockLeastSquaresEstimator.fit`` takes the generated path
+    for an operand of this type; an array takes the stored one."""
+
+    def __init__(self, X, generator, block: int, dim: int):
+        self.X = X
+        self.generator = generator
+        self.block = int(block)
+        self.dim = int(dim)
+
+
+@lru_cache(maxsize=None)
+def _making_bytes_of(tree, leaves, rows: int, cols: int, dtype) -> int:
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+    generator = jax.tree_util.tree_unflatten(tree, [aval(*a) for a in leaves])
+    memory = jax.jit(lambda g, x, b: g.block(x, b)).lower(
+        generator, aval((rows, cols), dtype), aval((), jnp.int32)).compile().memory_analysis()
+    if memory is None:
+        return 0
+    return int(memory.argument_size_in_bytes + memory.temp_size_in_bytes
+               + memory.output_size_in_bytes)
+
+
+def _making_bytes(generator, rows: int, cols: int, dtype) -> int:
+    """What making one block of ``rows`` rows holds beside the solver's
+    working blocks: ``generator.block`` compiled alone for this backend,
+    its temporaries, the block it makes (masked and centred into another),
+    and its arguments, for the copy of the rows in the layout the block is
+    made from (the described v5e's compile of the whole solve holds one);
+    once a process and a shape."""
+    leaves, tree = jax.tree_util.tree_flatten(generator)
+    return _making_bytes_of(tree, tuple((np.shape(a), jnp.result_type(a)) for a in leaves),
+                            rows, cols, jnp.dtype(dtype))
+
+
+def _remake(generator, xl, b):
+    """A later epoch's block that was not kept, made again as the first
+    epoch made it."""
+    return generator.block(xl, b)
+
+
+def _fetch(store, b):
+    """A later epoch's kept block: its slot of the store."""
+    return lax.dynamic_index_in_dim(store, b, keepdims=False)
+
+
+@lru_cache(maxsize=None)
+def _generated_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
+                        num_blocks: int, num_epochs: int, keep: int, pad: int,
+                        centre: bool):
+    """The whole generated-block solve as ONE program, per shard under
+    shard_map, like ``kernel_ridge._block_solve_fn``. The first epoch's scan
+    makes each block from the shard's rows (``generator.block``), centres
+    its columns over the logical rows (the means reduced like a gram, and
+    kept), forms its gram's ridge inverse, updates, and writes the block to
+    slot ``b`` of a store of ``keep`` slots where ``b < keep``. The later
+    epochs' scan reads a kept block from its slot and makes the others
+    again, centred by the kept means: the same arithmetic on the same
+    values, so a block made again is the block the first epoch made, bit
+    for bit. The store is an argument the program writes in place and
+    hands back (donated on a chip): an array of the process for the
+    length of the solve, not scratch the runtime keeps reserved for as
+    long as the program stays loaded. The cached inverses stay in the
+    program. Pad rows are masked out of every block; a ragged last block's
+    pad columns are zero, with 1 on their ridge diagonal (``_pad_ridge``)."""
+    width = mesh.shape[axis]
+
+    def local(xl, generator, yl, lam, n, store):
+        rows = xl.shape[0]
+        live = (lax.axis_index(axis) * rows + jnp.arange(rows) < n)[:, None]
+        ridge = _pad_ridge(lam, num_blocks, block, pad)
+
+        def centred(a, mean):
+            with device_scope("solver.generate"):
+                return jnp.where(live, a - mean, 0.0) if centre else a
+
+        def make(b):
+            with device_scope("solver.generate"):
+                return jnp.where(live, generator.block(xl, b), 0.0)
+
+        def first(carry, xs):
+            r, store = carry
+            b, w_b, ridge_b = xs
+            a = make(b)
+            mean = jnp.zeros((1, block), a.dtype)
+            if centre:
+                mean = sharded_rowsum(
+                    lambda v: v.sum(axis=0, keepdims=True), axis, width, (a,),
+                    scope="coll.moments",
+                ) / n.astype(a.dtype)
+            a = centred(a, mean)
+            if keep:
+                with device_scope("solver.generate"):
+                    store = keep_block(store, a, b, keep, num_blocks)
+            inv = _local_gram_inv(a, a, ridge_b, precision, axis, width)
+            r, w_b = _local_solve_update(a, a, inv, r, w_b, precision, axis, width)
+            return (r, store), (w_b, inv, mean)
+
+        def fetched(b, _mean):
+            with device_scope("solver.fetch"):
+                return _fetch(store, b)
+
+        def remade(b, mean):
+            with device_scope("solver.generate"):
+                a = jnp.where(live, _remake(generator, xl, b), 0.0)
+            return centred(a, mean)
+
+        def later(r, xs):
+            b, w_b, inv, mean = xs
+            a = read_or_make(b < keep, keep, num_blocks, fetched, remade, b, mean)
+            return _local_solve_update(a, a, inv, r, w_b, precision, axis, width)
+
+        def epoch(carry, _):
+            r, w3 = carry
+            r, w3 = lax.scan(later, r, (order, w3, invs, means))
+            return (r, w3), None
+
+        order = jnp.arange(num_blocks)
+        w3 = jnp.zeros((num_blocks, block, yl.shape[1]), yl.dtype)
+        # The scans' own slices and stacked results belong to the update;
+        # the generation, gram, Cholesky and inverse are scopes inside.
+        with device_scope("solver.update"):
+            (r, store), (w3, invs, means) = lax.scan(
+                first, (yl, store), (order, w3, ridge))
+            (r, w3), _ = lax.scan(epoch, (r, w3), None, length=num_epochs - 1)
+        return w3, means, store
+
+    sm = shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(axis), P(), P(axis), P(), P(), P(None, axis)),
+        out_specs=(P(), P(), P(None, axis)),
+        check_vma=False,
+    )
+    return jax.jit(sm, donate_argnums=_donate(mesh, 5))
+
+
+def block_coordinate_descent_generated(
+    source: BlockSource, B: RowMatrix, num_iters: int, lam: float = 0.0,
+    centre: bool = True,
+) -> Tuple[List[jax.Array], List[Tuple[int, int]], Optional[jax.Array]]:
+    """``block_coordinate_descent`` on a feature matrix that is never
+    held: each column block is made from ``source.X`` when a visit needs
+    it (``BlockSource``). Which of the first epoch's blocks stay in HBM for
+    the later epochs, and which are made again, is
+    ``row_matrix._blocks_kept``'s answer from sizes the code can see, with
+    the cached inverses and what making a block holds (``_making_bytes``)
+    counted beside the store. ``centre`` subtracts each
+    column's mean over the rows as its block is made (the estimator's
+    intercept). Returns (weight blocks at their true widths, block column
+    ranges, the columns' means (dim,), or None without ``centre``)."""
+    from keystone_tpu.utils.metrics import active_tracer, device_hbm_bytes, span_of
+
+    A = RowMatrix.from_array(source.X)
+    A._check_aligned(B)
+    mesh, axis = A.mesh, config.data_axis
+    block, dim = source.block, source.dim
+    nb = -(-dim // block)
+    pad = nb * block - dim
+    k = B.data.shape[1]
+    cdtype = jnp.dtype(config.accum_dtype)
+    itemsize = cdtype.itemsize
+    rows = A.padded_rows // mesh.shape[axis]
+    keep = _blocks_kept(
+        rows, block, nb, num_iters, rows * (A.data.shape[1] + k) * itemsize,
+        itemsize, device_hbm_bytes(), factor_bytes=nb * block * block * itemsize,
+        making_bytes=_making_bytes(source.generator, rows, A.data.shape[1], A.data.dtype))
+    # The grams, the means and Aᵀ R of every visit cross the mesh.
+    count_reduced(mesh, nb * block * itemsize * (block + int(centre))
+                  + num_iters * nb * block * k * itemsize)
+    # What the solve makes: every block once, and again in each later epoch
+    # where it has no slot. ``branches`` where the generator is a stack of
+    # branches (``workflow.pipeline.BranchStack``).
+    with span_of(active_tracer(), "bcd.generated", "solver",
+                 generated_blocks=nb + (num_iters - 1) * (nb - keep),
+                 kept_blocks=keep, kept_bytes=keep * A.padded_rows * block * itemsize,
+                 branches=int(getattr(source.generator, "branches", 0)),
+                 block=block, epochs=num_iters):
+        solve = _generated_solve_fn(
+            mesh, axis, _precision(), fold_blocks(mesh.shape[axis]), block, nb,
+            num_iters, keep, pad, bool(centre))
+        store = jnp.zeros((keep, A.padded_rows, block), A.data.dtype,
+                          device=jax.sharding.NamedSharding(mesh, P(None, axis)))
+        W3, means, _ = solve(A.data, source.generator, B.data.astype(cdtype),
+                             jnp.asarray(lam, cdtype), jnp.asarray(A.n, jnp.int32),
+                             store)
+    blocks = [(s, min(s + block, dim)) for s in range(0, dim, block)]
+    W = [W3[i][: e - s] for i, (s, e) in enumerate(blocks)]
+    return W, blocks, means.reshape(-1)[:dim] if centre else None
 
 
 def _make_fingerprint(

@@ -76,6 +76,66 @@ def solver_matmul(x, y, precision):
     return jnp.matmul(x, y, precision=precision)
 
 
+#: What a store of blocks leaves of the device's memory to everything the
+#: process holds outside the solver's program, as a divisor of the reported
+#: limit: the caller's images, the features before they were scaled, the
+#: model of the fit before (2.25 GB of 16.9 in ``cifar-kernel-fit``).
+_OUTSIDE_SHARE = 8
+#: The program's temporaries beside the store, in blocks: each of its two
+#: loops holds the block it works on, and the factorisation's scratch is
+#: part of a third (the v5e compile at ``cifar-kernel-fit``'s shape: the
+#: store and 2.24 blocks, ``tests/test_aot_tpu.py``).
+_WORKING_BLOCKS = 3
+
+
+def _blocks_kept(rows: int, block: int, num_blocks: int, num_epochs: int,
+                 argument_bytes: int, itemsize: int, limit: int,
+                 factor_bytes: int = 0, making_bytes: int = 0) -> int:
+    """How many of the first epoch's generated blocks a solve keeps in HBM
+    for the later epochs to read, the rest made again: store or
+    regenerate, from sizes alone, so every process of a mesh comes out the
+    same. The one rule of both solvers that generate their block operand
+    (``kernel_ridge._block_solve_fn``: kernel blocks;
+    ``bcd._generated_solve_fn``: feature blocks). ``rows`` are one shard's
+    padded rows, ``argument_bytes`` its arguments (X and Y),
+    ``factor_bytes`` what the program keeps beside the store for every
+    block (the dense solver's cached inverses; the kernel solver keeps
+    none), ``making_bytes`` what making a block holds beyond the working
+    blocks (the dense solver's generator compiled alone; the kernel
+    block's making is inside the working blocks' calibration), ``limit``
+    the device's reported memory. One epoch visits nothing twice and
+    keeps nothing. Otherwise as many slots of rows x block as fit beside
+    those, the working blocks and ``limit / _OUTSIDE_SHARE`` for what is
+    not this program's."""
+    if num_epochs < 2:
+        return 0
+    slot = rows * block * itemsize
+    room = (limit - limit // _OUTSIDE_SHARE - argument_bytes - factor_bytes
+            - making_bytes - _WORKING_BLOCKS * slot)
+    return max(0, min(num_blocks, room // slot))
+
+
+def keep_block(store, a, slot, keep: int, num_blocks: int):
+    """``store`` with the first epoch's block ``a`` written to ``slot``
+    where that is under ``keep``. A block with no slot writes back what the
+    last slot holds: the store stays out of any conditional."""
+    if keep < num_blocks:
+        has_slot, slot = slot < keep, jnp.minimum(slot, keep - 1)
+        a = jnp.where(has_slot, a, lax.dynamic_index_in_dim(store, slot, keepdims=False))
+    return lax.dynamic_update_index_in_dim(store, a, slot, 0)
+
+
+def read_or_make(kept, keep: int, num_blocks: int, fetch, make, *args):
+    """A later epoch's block: ``fetch(*args)`` where ``kept`` (traced) says
+    it has a slot, ``make(*args)`` again as the first epoch made it where
+    not; no conditional where every block or none was kept."""
+    if keep >= num_blocks:
+        return fetch(*args)
+    if not keep:
+        return make(*args)
+    return lax.cond(kept, fetch, make, *args)
+
+
 def count_reduced(mesh: Mesh, nbytes: int) -> None:
     """Add ``nbytes`` to ``program_counters``' ``collective_bytes``: what a
     reduction over ``mesh``'s data axis is handed, from shapes, on the host

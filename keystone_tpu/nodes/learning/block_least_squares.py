@@ -9,11 +9,15 @@ class-rebalanced ImageNet variant (SURVEY.md §2.4, §3.2) [unverified].
 
 TPU lowering: see keystone_tpu/linalg/bcd.py. The intercept is fit by
 centering features and labels (b = ȳ − x̄ᵀW), matching the reference's
-mean-scaler pairing.
+mean-scaler pairing. Features handed over as a ``BlockSource`` (the narrow
+rows and what makes each column block of them) are never held whole: the
+solver makes each block when a visit needs it, and the fitted mapper makes
+them again a block at a time to apply.
 """
 
 from __future__ import annotations
 
+import copy
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -22,8 +26,10 @@ import numpy as np
 
 from keystone_tpu.config import config
 from keystone_tpu.linalg import (
+    BlockSource,
     RowMatrix,
     block_coordinate_descent,
+    block_coordinate_descent_generated,
     block_coordinate_descent_streamed,
 )
 from keystone_tpu.workflow import LabelEstimator, Transformer
@@ -34,26 +40,48 @@ class BlockLinearMapper(Transformer):
 
     Keeping per-block weights (instead of one dense (d, k) matrix) is what
     lets a 256k-dim model stream through memory; XLA fuses the per-block
-    gemm+accumulate chain.
+    gemm+accumulate chain. With a ``generator`` (a fit on a ``BlockSource``)
+    it is handed the narrow rows and makes X_b from them a block at a time,
+    inside one loop: the wide features are never held.
     """
 
-    # The weights are arguments of the program; the column ranges are its
-    # static part (a tuple of tuples, so that it hashes).
-    array_fields = ("W_blocks", "b")
+    # The weights and the generator are arguments of the program; the
+    # column ranges are its static part (a tuple of tuples, so it hashes).
+    array_fields = ("W_blocks", "b", "generator")
 
     def __init__(
         self,
         W_blocks: Sequence[jax.Array],
         blocks: Sequence[Tuple[int, int]],
         b: Optional[jax.Array] = None,
+        generator=None,
     ):
         self.W_blocks = [jnp.asarray(w) for w in W_blocks]
         self.blocks = tuple((int(s), int(e)) for s, e in blocks)
         self.b = None if b is None else jnp.asarray(b)
+        self.generator = generator
+
+    def _apply_generated(self, X):
+        """Σ_b generator.block(X, b) W_b in a loop over the blocks, the
+        ragged last block's weights padded with zero rows."""
+        block = self.blocks[0][1] - self.blocks[0][0]
+        W3 = jnp.stack([jnp.pad(w, ((0, block - w.shape[0]), (0, 0)))
+                        for w in self.W_blocks])
+
+        def add(i, out):
+            return out + jnp.matmul(self.generator.block(X, i), W3[i],
+                                    precision=jax.lax.Precision.HIGHEST)
+
+        out = jax.lax.fori_loop(
+            0, len(self.blocks), add,
+            jnp.zeros((X.shape[0], W3.shape[2]), W3.dtype))
+        return out if self.b is None else out + self.b
 
     def apply_batch(self, X):
         from keystone_tpu.utils.sparse import SparseBatch
 
+        if self.generator is not None:
+            return self._apply_generated(X)
         out = None
         if isinstance(X, SparseBatch):
             # matmul densifies per column block internally — same streaming
@@ -104,6 +132,12 @@ def resolve_block_size(block_size, d: int) -> int:
 
 
 class BlockLeastSquaresEstimator(LabelEstimator):
+    #: (generator, dim) where ``workflow.rules.GeneratedBlocksRule`` has put
+    #: this estimator in the place of gather → estimator (``generating``):
+    #: it is then handed the rows the features are made of, and fits them
+    #: as a ``BlockSource``.
+    generated = None
+
     def __init__(
         self,
         block_size="auto",
@@ -161,9 +195,21 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         W, b = state.solve(self.lam, fit_intercept=self.fit_intercept)
         return BlockLinearMapper([W], [(0, state.d)], b)
 
+    def generating(self, generator, dim: int) -> "BlockLeastSquaresEstimator":
+        """This estimator fitted on what ``generator`` makes of the rows it
+        is handed, ``dim`` columns that are never held."""
+        est = copy.copy(self)
+        est.generated = (generator, int(dim))
+        return est
+
     def fit(self, data, labels) -> BlockLinearMapper:
         from keystone_tpu.utils.sparse import SparseBatch
 
+        if self.generated is not None:
+            generator, dim = self.generated
+            data = BlockSource(data, generator, self.block_size, dim)
+        if isinstance(data, BlockSource):
+            return self._fit_generated(data, labels)
         if isinstance(data, SparseBatch):
             if self.parallelism == "model":
                 raise ValueError(
@@ -296,6 +342,41 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             b = y_mean - x_mean @ W
         return BlockLinearMapper(W_blocks, blocks, b)
 
+    def generates_blocks(self) -> bool:
+        """Can this estimator be handed a ``BlockSource``? A fixed block
+        width, rows sharded, in HBM, no epoch checkpoints and no weights of
+        its own: what ``_fit_generated`` solves."""
+        return (isinstance(self.block_size, (int, np.integer))
+                and self.parallelism == "data" and not self.stream
+                and self.checkpoint_dir is None
+                and type(self)._weights is BlockLeastSquaresEstimator._weights)
+
+    def _fit_generated(self, source: BlockSource, labels) -> BlockLinearMapper:
+        """The fit on a feature matrix that is never held: the labels
+        centred as the device path centres them, each feature block centred
+        as the solver makes it (``block_coordinate_descent_generated``), the
+        intercept from the means it kept."""
+        if not self.generates_blocks():
+            raise ValueError(
+                "a BlockSource is solved with a fixed block size, data "
+                "parallel, unweighted, in HBM and without checkpoints")
+        if source.block != self.block_size:
+            raise ValueError(
+                f"the source makes blocks of {source.block} columns, the "
+                f"estimator solves blocks of {self.block_size}")
+        Y = jnp.asarray(labels, dtype=config.default_dtype)
+        if Y.ndim == 1:
+            Y = Y[:, None]
+        Ay = RowMatrix.from_array(Y)
+        y_mean = Ay.col_sums() / Ay.n if self.fit_intercept else None
+        B = Ay if y_mean is None else Ay.centered(y_mean)
+        W_blocks, blocks, x_mean = block_coordinate_descent_generated(
+            source, B, self.num_iters, self.lam, centre=self.fit_intercept)
+        b = None
+        if self.fit_intercept:
+            b = y_mean - jnp.matmul(x_mean, jnp.concatenate(W_blocks, axis=0),
+                                    precision=jax.lax.Precision.HIGHEST)
+        return BlockLinearMapper(W_blocks, blocks, b, generator=source.generator)
 
     def _fit_ring(self, data, labels) -> BlockLinearMapper:
         """Model-parallel fit: columns of A shard across the mesh and the

@@ -17,12 +17,17 @@ each visit's n x b block is generated from the row-sharded X inside one
 program whose loop over all the epochs' visits is a ``lax.scan``: no host
 round trip a block. Whether a block is kept for the later epochs or made
 again is decided before the program is built, from sizes the code can see
-(``_blocks_kept``: one shard's rows, the block, the epochs, the device's
-reported memory). One epoch, or slots that do not fit, keep nothing: each
+(``row_matrix._blocks_kept``, the rule the dense solver's generated blocks
+share: one shard's rows, the block, the epochs, the device's reported
+memory). One epoch, or slots that do not fit, keep nothing: each
 block is used and dropped, no more of K than one block ever live. Otherwise
 the first epoch writes the blocks it generates into a store that lives and
 dies inside the program, and the later epochs read them: upstream's
-``cacheKernel``, without the flag. X_B is replicated for the visit (each
+``cacheKernel``, without the flag. The writes and the reads are the dense
+generated solve's too (``row_matrix.keep_block``, ``read_or_make``); that
+solve hands its store in as a donated argument instead, which the runtime
+frees after the fit, where a store inside the program stays reserved for as
+long as the program is loaded. X_B is replicated for the visit (each
 shard's rows of the block, ``psum``), K_Bᵀ α reduces over the sharded rows
 (``sharded_rowsum``), and the b x b solve runs replicated on every chip. A
 ragged last block and the pad rows of X are masked out of K; the pad
@@ -48,7 +53,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from keystone_tpu.config import config
 from keystone_tpu.linalg.row_matrix import (
     RowMatrix,
+    _blocks_kept,
     _precision,
+    keep_block,
+    read_or_make,
     sharded_rowsum,
     solver_matmul,
 )
@@ -131,35 +139,6 @@ def _ridge_diagonal(col_live, lam):
     return jnp.where(col_live, lam, 1.0)
 
 
-#: What the store leaves of the device's memory to everything the process
-#: holds outside the solver's program, as a divisor of the reported limit:
-#: the caller's images, the features before they were scaled, the model of
-#: the fit before (2.25 GB of 16.9 in ``cifar-kernel-fit``).
-_OUTSIDE_SHARE = 8
-#: The program's temporaries beside the store, in blocks: each of its two
-#: loops holds the block it works on, and the factorisation's scratch is
-#: part of a third (the v5e compile at ``cifar-kernel-fit``'s shape: the
-#: store and 2.24 blocks, ``tests/test_aot_tpu.py``).
-_WORKING_BLOCKS = 3
-
-
-def _blocks_kept(rows: int, block: int, num_blocks: int, num_epochs: int,
-                 argument_bytes: int, itemsize: int, limit: int) -> int:
-    """How many of the first epoch's kernel blocks the solve keeps in HBM
-    for the later epochs to read: store or regenerate, from sizes alone, so
-    every process of a mesh comes out the same. ``rows`` are one shard's
-    padded rows, ``argument_bytes`` its X and Y, ``limit`` the device's
-    reported memory. One epoch visits nothing twice and keeps nothing.
-    Otherwise as many slots of rows x block as fit beside the arguments,
-    the working blocks and ``limit / _OUTSIDE_SHARE`` for what is not this
-    program's."""
-    if num_epochs < 2:
-        return 0
-    slot = rows * block * itemsize
-    room = limit - limit // _OUTSIDE_SHARE - argument_bytes - _WORKING_BLOCKS * slot
-    return max(0, min(num_blocks, room // slot))
-
-
 @lru_cache(maxsize=None)
 def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
                     keep: int = 0, num_blocks: int = 0):
@@ -231,16 +210,9 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
 
         def make_and_keep(carry, start):
             alpha, store = carry
-            kept = k_b = generate(start)
+            k_b = generate(start)
             with device_scope("krr.generate"):
-                slot = start // block
-                if keep < num_blocks:
-                    # A block with no slot writes back what its neighbour's
-                    # slot holds: the store stays out of any conditional.
-                    has_slot, slot = slot < keep, jnp.minimum(slot, keep - 1)
-                    kept = jnp.where(has_slot, k_b,
-                                     lax.dynamic_index_in_dim(store, slot, keepdims=False))
-                store = lax.dynamic_update_index_in_dim(store, kept, slot, 0)
+                store = keep_block(store, k_b, start // block, keep, num_blocks)
             return (visit(alpha, start, k_b), store), None
 
         (alpha, store), _ = lax.scan(
@@ -251,14 +223,11 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
             with device_scope("krr.fetch"):
                 return lax.dynamic_index_in_dim(store, start // block, keepdims=False)
 
-        def read_or_make(alpha, start):
-            if keep < num_blocks:
-                k_b = lax.cond(start // block < keep, fetch, generate, start)
-            else:
-                k_b = fetch(start)
+        def later(alpha, start):
+            k_b = read_or_make(start // block < keep, keep, num_blocks, fetch, generate, start)
             return visit(alpha, start, k_b), None
 
-        alpha, _ = lax.scan(read_or_make, alpha, starts[num_blocks:])
+        alpha, _ = lax.scan(later, alpha, starts[num_blocks:])
         # The model's weights are replicated, as a linear map's are.
         return lax.all_gather(alpha, axis, tiled=True)
 

@@ -14,6 +14,9 @@ from keystone_tpu.workflow import Transformer
 
 
 class RandomSignNode(Transformer):
+    # The signs are an argument of the program, not a constant in it.
+    array_fields = ("signs",)
+
     def __init__(self, signs: jax.Array):
         self.signs = jnp.asarray(signs)
 

@@ -351,7 +351,7 @@ class Tracer:
 #: work a host span dispatches takes that span's name.
 DEVICE_SCOPES = (
     "solver.stack", "solver.gram", "solver.cholesky", "solver.inverse",
-    "solver.update",
+    "solver.update", "solver.generate", "solver.fetch",
     "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
     "conv.patches", "conv.kernel", "conv.relayout",
     "filters.rows", "filters.cols",

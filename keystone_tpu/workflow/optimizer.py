@@ -203,12 +203,16 @@ class Optimizer:
 def default_optimizer() -> Optimizer:
     from keystone_tpu.workflow.rules import (
         AutoCacheRule,
+        GeneratedBlocksRule,
         NodeOptimizationRule,
         PlanResourcesRule,
     )
 
     batches: List[Tuple[str, List[Rule], int]] = [
         ("dedup", [EquivalentNodeMergeRule()], 3),
+        # Gathered branches of one chain in front of a block solver: the
+        # solver makes each feature block from the branches' input.
+        ("generated-blocks", [GeneratedBlocksRule()], 1),
         ("node-level", [NodeOptimizationRule()], 1),
         # Profile-guided resource planning (exec workers / solve chunk
         # rows): acts only on a measured-profile hit; gated per-apply on

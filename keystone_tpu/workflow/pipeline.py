@@ -755,6 +755,79 @@ class GatherTransformer(Transformer):
         return "Gather(" + ", ".join(repr(b) for b in self.branches) + ")"
 
 
+class BranchStack(Transformer):
+    """Gather branches that are one chain with different arrays, as one
+    transformer: the chain once, each of its arrays stacked on a leading
+    axis of ``branches`` (``of``), one argument of the program where the
+    branches would be a closure each. ``apply_batch`` is the gather's
+    output, every branch's columns side by side; ``block(X, b)`` is column
+    block ``b`` alone, its ``per_block`` branches: what a solver makes one
+    block at a time (``linalg.bcd.BlockSource``). A ragged last block's
+    missing branches are zero columns."""
+
+    array_fields = ("chain",)
+
+    def __init__(self, chain: FusedTransformer, branches: int, per_block: int,
+                 width: int):
+        self.chain = chain
+        self.branches = int(branches)
+        self.per_block = int(per_block)
+        self.width = int(width)
+
+    @staticmethod
+    def of(chains: Sequence[Sequence[Transformer]], block: int, x) -> Optional["BranchStack"]:
+        """The branches ``chains`` (each a branch's stages, in order)
+        stacked for blocks of ``block`` columns and an input like ``x`` (a
+        shape and dtype), or None where they are not one chain (the same
+        classes and static fields, arrays of the same shapes: one pytree
+        structure), are not jittable and row-independent, or a branch's
+        width does not divide ``block``."""
+        fused = [FusedTransformer(c) for c in chains]
+
+        def structure(f):
+            leaves, tree = jax.tree_util.tree_flatten(f)
+            return tree, [(np.shape(a), jnp.result_type(a)) for a in leaves]
+
+        try:
+            one = all(structure(f) == structure(fused[0]) for f in fused[1:])
+        except (TypeError, ValueError):  # a static field that compares as an array
+            return None
+        if not one or not all(f.jittable and f.row_independent for f in fused):
+            return None
+        out = jax.eval_shape(fused[0]._apply_stages, x)
+        if len(out.shape) != 2 or block % out.shape[1]:
+            return None
+        chain = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *fused)
+        return BranchStack(chain, len(fused), block // out.shape[1], out.shape[1])
+
+    def _columns(self, X, first, count):
+        """Branches ``first`` to ``first + count`` on ``X``, their columns
+        side by side as the gather lays them, one branch at a time into
+        its slice of the output (what a branch lays out in between is
+        held for one branch, not for all); past the last branch, zeros."""
+        w = self.width
+
+        def branch(i, out):
+            at = first + i
+            chain = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, jnp.minimum(at, self.branches - 1), keepdims=False),
+                self.chain)
+            y = chain._apply_stages(X)
+            if self.branches % self.per_block:
+                y = jnp.where(at < self.branches, y, 0)
+            return jax.lax.dynamic_update_slice_in_dim(out, y.astype(out.dtype), i * w, 1)
+
+        out = jnp.zeros((X.shape[0], count * w), X.dtype)
+        return jax.lax.fori_loop(0, count, branch, out)
+
+    def apply_batch(self, X):
+        return self._columns(X, 0, self.branches)
+
+    def block(self, X, b):
+        return self._columns(X, b * self.per_block, self.per_block)
+
+
 # ---------------------------------------------------------------------------
 # Estimators
 # ---------------------------------------------------------------------------

@@ -29,12 +29,16 @@ import weakref
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
+import numpy as np
+
 from keystone_tpu.config import config
 from keystone_tpu.workflow.cache import CacheOperator, NodeProfile, Profiler
 from keystone_tpu.workflow.graph import Graph, GraphId, NodeId
 from keystone_tpu.workflow.operators import (
     DatasetOperator,
+    DelegatingOperator,
     EstimatorOperator,
+    GatherOperator,
     TransformerOperator,
 )
 from keystone_tpu.workflow.optimizer import Rule, active_profile_key
@@ -120,6 +124,95 @@ def _scaled_shape(value, scale: float):
     if scale == 1.0:
         return tuple(shape)
     return (int(round(shape[0] * scale)),) + tuple(shape[1:])
+
+
+def _gathered_branches(graph: Graph, gid, consumers):
+    """(the one input, each branch's transformers in order) of a gather
+    node that nothing else reads and whose branches are chains of
+    transformer nodes over one input, each read by the next alone; None
+    for anything else."""
+    if len(consumers.get(gid, [])) != 1:
+        return None
+    if not isinstance(graph.operators.get(gid), GatherOperator):
+        return None
+    source, chains = None, []
+    for tail in graph.dependencies[gid]:
+        chain, nid = [], tail
+        while isinstance(graph.operators.get(nid), TransformerOperator):
+            if len(consumers.get(nid, [])) != 1:
+                return None
+            chain.append(graph.operators[nid].transformer)
+            (nid,) = graph.dependencies[nid]
+        if not chain or source not in (None, nid):
+            return None
+        source = nid
+        chains.append(chain[::-1])
+    return source, chains
+
+
+class GeneratedBlocksRule(Rule):
+    """``gather(branches) → BlockLeastSquaresEstimator`` lowered to the
+    generated-block solve, where every branch is one chain of the same
+    transformers that differ only in the arrays they name, the estimator's
+    block is a whole number of branches, and the fit's input is a bound
+    batch: the branches stacked into one ``BranchStack``, the estimator fit
+    on the branches' input as a ``BlockSource`` (each block made from it
+    inside the solve), and every application of the fitted map handed the
+    input too, where it applies the same branches. The gathered features
+    never exist. Any other gather keeps its path."""
+
+    def apply(self, graph: Graph, targets: Sequence[GraphId]) -> Graph:
+        import jax
+
+        from keystone_tpu.nodes.learning.block_least_squares import (
+            BlockLeastSquaresEstimator,
+        )
+        from keystone_tpu.workflow.pipeline import BranchStack
+
+        consumers = graph.consumers(targets)
+        ops, dps = dict(graph.operators), dict(graph.dependencies)
+        for nid in graph.reachable(targets):
+            op = graph.operators[nid]
+            if not (isinstance(op, EstimatorOperator)
+                    and isinstance(op.estimator, BlockLeastSquaresEstimator)
+                    and op.estimator.generates_blocks()
+                    and len(graph.dependencies[nid]) == 2):
+                continue
+            features, labels = graph.dependencies[nid]
+            found = _gathered_branches(graph, features, consumers)
+            data = graph.operators.get(found[0]) if found else None
+            if not isinstance(data, DatasetOperator) or np.ndim(data.data) != 2:
+                continue
+            applied = []
+            for user in consumers.get(nid, []):
+                if not isinstance(graph.operators[user], DelegatingOperator):
+                    break
+                est_dep, x_dep = graph.dependencies[user]
+                there = _gathered_branches(graph, x_dep, consumers)
+                if (est_dep != nid or there is None
+                        or not _same_objects(there[1], found[1])):
+                    break
+                applied.append((user, there[0]))
+            else:
+                x = jax.ShapeDtypeStruct((1, np.shape(data.data)[1]),
+                                         np.asarray(data.data[:1]).dtype)
+                stack = BranchStack.of(found[1], op.estimator.block_size, x)
+                if stack is None:
+                    continue
+                ops[nid] = EstimatorOperator(op.estimator.generating(
+                    stack, stack.branches * stack.width))
+                dps[nid] = (found[0], labels)
+                for user, x_dep in applied:
+                    dps[user] = (nid, x_dep)
+        if ops == graph.operators and dps == graph.dependencies:
+            return graph
+        return Graph(ops, dps).pruned(list(targets))
+
+
+def _same_objects(a, b) -> bool:
+    return len(a) == len(b) and all(
+        len(x) == len(y) and all(s is t for s, t in zip(x, y))
+        for x, y in zip(a, b))
 
 
 class NodeOptimizationRule(Rule):

@@ -378,6 +378,50 @@ def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh, keep):
         assert block <= memory.temp_size_in_bytes < 1.5 * block
 
 
+def test_generated_block_solve_compiles_at_mnist_fft_fit_shape(mesh):
+    """The generated-block dense solve at ``mnist-fft-fit``'s sizes (60,000
+    images of 784, 200 branches of random signs, padded FFT and rectifier,
+    blocks of 2,048 columns, k = 10, 3 epochs) for one v5e chip, with the
+    slots the shared rule keeps there when it is given the cached inverses
+    and what making a block holds (the generator compiled alone for the
+    chip, ``bcd._making_bytes``): the store is the argument the program writes in place and hands
+    back (aliased, not a second store), and the program's arguments and
+    temporaries fit in what the rule leaves of the chip, an eighth kept for
+    what is not this program's. Never the 24.6 GB of features."""
+    from keystone_tpu.linalg import bcd
+    from keystone_tpu.linalg.row_matrix import _blocks_kept, _precision
+    from keystone_tpu.nodes.stats import LinearRectifier, PaddedFFT, RandomSignNode
+    from keystone_tpu.workflow.pipeline import BranchStack
+
+    one = Mesh(np.array(mesh.devices.flat[:1]), (AXIS,))
+    n, d_in, k, b = 60000, 784, 10, 2048
+    stack = BranchStack.of(
+        [[RandomSignNode.create(d_in, seed=i), PaddedFFT(), LinearRectifier()]
+         for i in range(200)], b, jax.ShapeDtypeStruct((1, d_in), jnp.float32))
+    generator = jax.tree_util.tree_map(lambda a: _sds(a.shape, one, P(), a.dtype), stack)
+    x = _sds((n, d_in), one, P(AXIS))
+    making = jax.jit(lambda g, x, i: g.block(x, i)).lower(
+        generator, x, _sds((), one, P(), jnp.int32)).compile().memory_analysis()
+    block = n * b * 4
+    keep = _blocks_kept(n, b, 50, 3, n * (d_in + k) * 4, 4, V5E_HBM_BYTES,
+                        factor_bytes=50 * b * b * 4,
+                        making_bytes=(making.argument_size_in_bytes + making.temp_size_in_bytes
+                                      + making.output_size_in_bytes))
+    assert 0 < keep < 50
+    compiled = bcd._generated_solve_fn(one, AXIS, _precision(), _fold(one), b, 50, 3,
+                                       keep, 0, True).lower(
+        x, generator, _sds((n, k), one, P(AXIS)),
+        _sds((), one, P()), _sds((), one, P(), jnp.int32),
+        _sds((keep, n, b), one, P(None, AXIS)),
+    ).compile()
+    assert "Cholesky" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < keep * block + 0.2e9
+    assert memory.alias_size_in_bytes >= keep * block
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            <= V5E_HBM_BYTES - V5E_HBM_BYTES // 8)
+
+
 def test_kernel_block_solve_compiles_sharded_for_v5e(mesh):
     """The same program over the mesh: X_B and K_BB reach every chip by a
     collective, K_B^T alpha reduces over the sharded rows, and the dual

@@ -32,11 +32,18 @@ def test_random_sign_node(rng):
 
 
 def test_padded_fft_matches_numpy(rng):
+    """Upstream's output: zero-padded to the next power of two, the real
+    parts of the first n/2 bins, unscaled (784 pixels give 512)."""
     X = rng.normal(size=(3, 7)).astype(np.float32)
     out = np.asarray(PaddedFFT()(X))
-    ref = np.fft.rfft(np.pad(X, ((0, 0), (0, 1))), axis=-1) / np.sqrt(8)
-    np.testing.assert_allclose(out[:, :5], ref.real, atol=1e-5)
-    np.testing.assert_allclose(out[:, 5:], ref.imag, atol=1e-5)
+    ref = np.fft.fft(np.pad(X.astype(np.float64), ((0, 0), (0, 1))), axis=-1)
+    assert out.shape == (3, 4)
+    np.testing.assert_allclose(out, ref[:, :4].real, atol=1e-5)
+    wide = rng.normal(size=(2, 784)).astype(np.float32)
+    got = np.asarray(PaddedFFT()(wide))
+    assert got.shape == (2, 512)
+    want = np.fft.fft(np.pad(wide.astype(np.float64), ((0, 0), (0, 240))), axis=-1)
+    np.testing.assert_allclose(got, want[:, :512].real, rtol=1e-4, atol=1e-3)
 
 
 def test_linear_rectifier():

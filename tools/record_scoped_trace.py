@@ -3,7 +3,8 @@ traced "fits" at toy widths, each running every family of device scopes
 (``keystone_tpu.utils.metrics.DEVICE_SCOPES`` and a fused chain's stages)
 once: a cached and an uncached block least-squares solve with a ragged last
 block, the kernel solver, a chain whose convolver takes its rectifier and
-pooler, the filter fit's patch cut, and a mixture fit. On the chip, through
+pooler, the filter fit's patch cut, a mixture fit, and a block solve whose
+blocks are made from the narrow rows (random signs, FFT, rectifier). On the chip, through
 the chip tool:
 
     python3 tools/record_scoped_trace.py chiprun_out/scoped-fit.xplane.pb
@@ -42,13 +43,19 @@ def one_fit(rng_seed: int = 0):
     )
     from keystone_tpu.nodes.learning.gmm import GaussianMixtureModelEstimator
     from keystone_tpu.nodes.learning.kernel_ridge import KernelRidgeRegression
+    from keystone_tpu.linalg import BlockSource
+    from keystone_tpu.nodes.stats import LinearRectifier, PaddedFFT, RandomSignNode
     from keystone_tpu.workflow import FusedTransformer
+    from keystone_tpu.workflow.pipeline import BranchStack
 
     rng = np.random.default_rng(rng_seed)
     x = rng.normal(size=(256, 160)).astype(np.float32)
     y = rng.normal(size=(256, 6)).astype(np.float32)
     images = rng.uniform(0, 255, size=(16, 13, 13, 3)).astype(np.float32)
     bank = rng.normal(size=(32, 6, 6, 3)).astype(np.float32)
+    stack = BranchStack.of(
+        [[RandomSignNode.create(12, seed=i), PaddedFFT(), LinearRectifier()]
+         for i in range(6)], 16, jax.ShapeDtypeStruct((1, 12), jnp.float32))
     chain = FusedTransformer([
         Convolver(bank, normalize_patches=10.0), SymmetricRectifier(alpha=0.25),
         Pooler(4, 4, mode="sum"), ImageVectorizer()])
@@ -61,6 +68,9 @@ def one_fit(rng_seed: int = 0):
         chain.batch_call(jnp.asarray(images)),
         RandomPatcher(num_patches=64, patch_size=6).apply_batch(jnp.asarray(images)),
         GaussianMixtureModelEstimator(k=4, max_iters=3).fit(x[:, :8]).means,
+        # Six branches of 12 values (8 features each) in blocks of two.
+        BlockLeastSquaresEstimator(block_size=16, num_iters=3, lam=0.1).fit(
+            BlockSource(x[:, :12], stack, 16, 48), y).W,
     ]
     jax.block_until_ready(out)
 
