@@ -23,17 +23,23 @@ memory). One epoch, or slots that do not fit, keep nothing: each
 block is used and dropped, no more of K than one block ever live. Otherwise
 the first epoch writes the blocks it generates into a store that lives and
 dies inside the program, and the later epochs read them: upstream's
-``cacheKernel``, without the flag. The writes and the reads are the dense
-generated solve's too (``row_matrix.keep_block``, ``read_or_make``); that
-solve hands its store in as a donated argument instead, which the runtime
-frees after the fit, where a store inside the program stays reserved for as
-long as the program is loaded. X_B is replicated for the visit (each
-shard's rows of the block, ``psum``), K_Bᵀ α reduces over the sharded rows
-(``sharded_rowsum``), and the b x b solve runs replicated on every chip. A
-ragged last block and the pad rows of X are masked out of K; the pad
-columns get 1 on the diagonal, so their α stays 0. X, Y, λ, the row count,
-the visit order and the kernel's parameters are arguments of the program: a
-second fit compiles nothing.
+``cacheKernel``, without the flag. A slot holds its block as the visit used
+it. The writes and the reads are the dense generated solve's too
+(``row_matrix.keep_block``, ``read_or_make``); that solve hands its store in
+as a donated argument instead, which the runtime frees after the fit, where
+a store inside the program stays reserved for as long as the program is
+loaded. K is symmetric, so on a mesh one shard wide a block with a store
+beside it is made by row tiles of ``block``: its rows B_t where slot t is
+written already are that slot's rows of the block, transposed
+(K[B_t, B] = K[B, B_t]ᵀ), and only the other tiles are generated, so each
+entry of K is made once. Across shards the transpose's rows live on
+another chip, and every block is generated whole. X_B is replicated for
+the visit (each shard's rows of the block, ``psum``), K_Bᵀ α reduces over
+the sharded rows (``sharded_rowsum``), and the b x b solve runs replicated
+on every chip. A ragged last block and the pad rows of X are masked out
+of K; the pad columns get 1 on the diagonal, so their α stays 0. X, Y, λ,
+the row count, the visit order and the kernel's parameters are arguments
+of the program: a second fit compiles nothing.
 
 The matrix-free conjugate-gradient solver that bore this name is
 ``KernelRidgeCG`` (``kernel_ridge_cg.py``).
@@ -47,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.scipy.linalg import cho_solve
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -126,6 +133,23 @@ def _visit_order(num_blocks: int, num_epochs: int) -> np.ndarray:
     return np.tile(np.arange(num_blocks, dtype=np.int32), num_epochs)
 
 
+def _mirrored_tiles(visits: np.ndarray, num_blocks: int, keep: int) -> int:
+    """How many row tiles of the blocks it makes a one-shard solve copies
+    from its store (``_block_solve_fn``'s ``assemble``): at each block made,
+    the leading run of slots the first epoch has written so far. Each is a
+    whole tile of live rows: the run never takes in the last block's slot,
+    which the first epoch writes after all the others or a later epoch
+    that makes blocks again does not have."""
+    written, tiles = np.zeros(keep, bool), 0
+    for visit, b in enumerate(visits):
+        first_epoch = visit < num_blocks
+        if first_epoch or b >= keep:  # made, not read
+            tiles += int(np.cumprod(written).sum())
+        if first_epoch and b < keep:
+            written[b] = True
+    return tiles
+
+
 def _block_residual(y_b, kt_alpha, k_bb, alpha_b, precision):
     """R = Y_B − K_Bᵀ α + K_BB α_B: the targets less what the other blocks
     explain."""
@@ -139,6 +163,9 @@ def _ridge_diagonal(col_live, lam):
     return jnp.where(col_live, lam, 1.0)
 
 
+_ROW_MAJOR = Layout(major_to_minor=(0, 1))
+
+
 @lru_cache(maxsize=None)
 def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
                     keep: int = 0, num_blocks: int = 0):
@@ -149,12 +176,20 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
 
     With ``keep`` > 0 the first ``num_blocks`` visits (one epoch) are a scan
     of their own that carries a store of ``keep`` slots and writes each
-    block it generates to slot ``start // block``, where that is under
-    ``keep``; the later visits read their block from its slot, and generate
-    it as the first epoch did where it has none. The stored block is the
-    generated block, bit for bit. With ``keep`` 0 there is one scan and no
-    store."""
+    block it makes to slot ``start // block``, where that is under
+    ``keep``; the later visits read their block from its slot, and make it
+    again where it has none. A slot holds the block as its visit used it.
+    With ``keep`` 0 there is one scan and no store.
+
+    On one shard with a store (``mirror``) a block is made by row tiles of
+    ``block``: the tiles of the leading run of slots written so far in this
+    fit are copied from those slots, transposed (``assemble``), the others
+    generated. The first epoch carries which slots it has written; in
+    ``_visit_order``'s natural order visit j copies tiles min(j, keep).
+    ``_mirrored_tiles`` counts the same on the host. On a wider mesh every
+    block is generated whole."""
     width = mesh.shape[axis]
+    mirror = bool(keep) and width == 1
 
     def local(xl, yl, lam, n, starts, kernel):
         rows = xl.shape[0]
@@ -178,6 +213,44 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
                 x_b = take_block(xl, start)
                 return jnp.where(row_live[:, None] & col_live[None, :],
                                  kernel.block(xl, x_b), 0.0)
+
+        def assemble(store, start, m):
+            """One shard's block at ``start`` by row tiles of ``block``:
+            tiles m and on generated (the last one the rows that are left),
+            then tiles 0 to m - 1 copied from their slots: slot t holds
+            K[:, B_t], so its rows of this block are K[B_t, B] transposed.
+            The block is held row major: left free, XLA lays it out
+            transposed to spare the copies their transposes, and then
+            transposes the whole block to write it to the store."""
+            col_live = start + jnp.arange(block) < n
+            last = (rows - 1) // block * block
+            with device_scope("krr.generate"):
+                x_b = take_block(xl, start)
+
+                def made(at, size):
+                    live = (at + jnp.arange(size) < n)[:, None] & col_live[None, :]
+                    return jnp.where(
+                        live, kernel.block(lax.dynamic_slice_in_dim(xl, at, size), x_b), 0.0)
+
+                k_b = jnp.zeros((rows, block), xl.dtype).at[last:].set(made(last, rows - last))
+                k_b = lax.fori_loop(
+                    m, last // block, lambda t, k_b: lax.dynamic_update_slice_in_dim(
+                        k_b, made(t * block, block), t * block, 0), k_b)
+            with device_scope("krr.mirror"):
+
+                def mirrored(t, k_b):
+                    at = jnp.clip(start + jnp.arange(block), 0, rows - 1)
+                    k_bt = store.at[t, at].get(mode="promise_in_bounds",
+                                               indices_are_sorted=True)
+                    return with_layout_constraint(lax.dynamic_update_slice_in_dim(
+                        k_b, jnp.where(col_live[None, :], k_bt.T, 0.0), t * block, 0),
+                        _ROW_MAJOR)
+
+                return lax.fori_loop(0, m, mirrored, k_b)
+
+        def leading(written):
+            """How many slots from the first on are written."""
+            return jnp.sum(jnp.cumprod(written.astype(jnp.int32)))
 
         def visit(alpha, start, k_b):
             """One block's solve on its kernel block, made or read."""
@@ -209,22 +282,31 @@ def _block_solve_fn(mesh: Mesh, axis: str, precision, fold: int, block: int,
             return lax.all_gather(alpha, axis, tiled=True)
 
         def make_and_keep(carry, start):
-            alpha, store = carry
-            k_b = generate(start)
+            alpha, store, *written = carry
+            if mirror:
+                k_b = assemble(store, start, leading(written[0]))
+                written = [written[0] | (jnp.arange(keep) == start // block)]
+            else:
+                k_b = generate(start)
             with device_scope("krr.generate"):
                 store = keep_block(store, k_b, start // block, keep, num_blocks)
-            return (visit(alpha, start, k_b), store), None
+            return (visit(alpha, start, k_b), store, *written), None
 
-        (alpha, store), _ = lax.scan(
+        (alpha, store, *written), _ = lax.scan(
             make_and_keep,
-            (alpha, jnp.zeros((keep, rows, block), xl.dtype)), starts[:num_blocks])
+            (alpha, jnp.zeros((keep, rows, block), xl.dtype),
+             *([jnp.zeros(keep, bool)] if mirror else [])),
+            starts[:num_blocks])
 
         def fetch(start):
             with device_scope("krr.fetch"):
                 return lax.dynamic_index_in_dim(store, start // block, keepdims=False)
 
+        def make_again(start):
+            return assemble(store, start, leading(written[0])) if mirror else generate(start)
+
         def later(alpha, start):
-            k_b = read_or_make(start // block < keep, keep, num_blocks, fetch, generate, start)
+            k_b = read_or_make(start // block < keep, keep, num_blocks, fetch, make_again, start)
             return visit(alpha, start, k_b), None
 
         alpha, _ = lax.scan(later, alpha, starts[num_blocks:])
@@ -262,11 +344,13 @@ def kernel_block_gauss_seidel(
     if not np.isin(visits[num_blocks:], visits[:num_blocks]).all():
         keep = 0  # not _visit_order's order: a later visit would read an empty slot
     generated = len(visits) - int((visits[num_blocks:] < keep).sum())  # the rest are read
+    mirrored = _mirrored_tiles(visits, num_blocks, keep) if mesh.shape[axis] == 1 else 0
     slot_bytes = A.padded_rows * block * dtype.itemsize
     with span_of(active_tracer(), "krr.fit", "solver", rows=A.n, dim=A.shape[1],
                  block=block, blocks=num_blocks, epochs=num_epochs,
                  kernel_blocks=generated, kernel_bytes=generated * slot_bytes,
-                 blocks_kept=keep, store_bytes=keep * slot_bytes):
+                 blocks_kept=keep, store_bytes=keep * slot_bytes,
+                 mirrored_rows=mirrored * block):
         solve = _block_solve_fn(
             mesh, axis, _precision(), fold_blocks(mesh.shape[axis]), block,
             keep, num_blocks if keep else 0)

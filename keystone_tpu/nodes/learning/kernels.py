@@ -20,16 +20,20 @@ import numpy as np
 from jax import lax
 
 
-def pairwise_sq_dists(X, Z, precision=None):
+def pairwise_sq_dists(X, Z, precision=None, symmetric=False):
     """||x − z||² for all pairs, gemm-shaped (MXU-friendly), clamped ≥ 0
     against cancellation. The single source of truth for this expansion.
     ``precision`` is the product's (None: the backend's default, as
-    k-means and the conjugate-gradient solver take it)."""
-    sq = (
-        jnp.sum(X * X, axis=1, keepdims=True)
-        - 2.0 * jnp.matmul(X, Z.T, precision=precision)
-        + jnp.sum(Z * Z, axis=1)
-    )
+    k-means and the conjugate-gradient solver take it). ``symmetric`` adds
+    the two norms first, so the pair (x, z) and the pair (z, x) round alike
+    wherever the product does: a kernel block's rows of another block are
+    then that block's rows of it, transposed, to the bit."""
+    sx = jnp.sum(X * X, axis=1, keepdims=True)
+    xz = 2.0 * jnp.matmul(X, Z.T, precision=precision)
+    if symmetric:
+        sq = (sx + jnp.sum(Z * Z, axis=1)) - xz
+    else:
+        sq = sx - xz + jnp.sum(Z * Z, axis=1)
     return jnp.maximum(sq, 0.0)
 
 
@@ -67,8 +71,10 @@ class GaussianKernelGenerator(KernelGenerator):
 
     def block(self, X, Z):
         # HIGHEST, as the solvers' products: the squared norms the product
-        # is taken from are float32 sums.
-        return jnp.exp(-self.gamma * pairwise_sq_dists(X, Z, lax.Precision.HIGHEST))
+        # is taken from are float32 sums. Symmetric: the kernel solver
+        # copies K[B_t, B] from K[B, B_t] where it holds that block.
+        return jnp.exp(-self.gamma * pairwise_sq_dists(
+            X, Z, lax.Precision.HIGHEST, symmetric=True))
 
 
 class LinearKernelGenerator(KernelGenerator):

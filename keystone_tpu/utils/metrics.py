@@ -352,7 +352,7 @@ class Tracer:
 DEVICE_SCOPES = (
     "solver.stack", "solver.gram", "solver.cholesky", "solver.inverse",
     "solver.update", "solver.generate", "solver.fetch",
-    "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
+    "krr.generate", "krr.mirror", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve",
     "conv.patches", "conv.kernel", "conv.relayout",
     "filters.rows", "filters.cols",
     "gmm.estep", "gmm.mstep",

@@ -14,6 +14,7 @@ numerics are covered by the CPU-mesh tests elsewhere in the suite.
 from __future__ import annotations
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -351,7 +352,9 @@ def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh, keep):
     else: never the 10 GB kernel. With the cell's 13 slots it holds the
     store once (10.65 GB, updated in place: a copy would be a second
     store) and the working block of each of its two loops, and fits the
-    chip beside its arguments."""
+    chip beside its arguments; on the one chip each block's rows of the
+    blocks already kept are copied from their slots (``krr.mirror``) into
+    the block in place, never a copy of the whole block around them."""
     from keystone_tpu.linalg.row_matrix import _precision
     from keystone_tpu.nodes.learning import GaussianKernelGenerator
     from keystone_tpu.nodes.learning.kernel_ridge import _block_solve_fn
@@ -374,6 +377,8 @@ def test_kernel_block_solve_compiles_at_cifar_kernel_fit_shape(mesh, keep):
         assert keep * block <= memory.temp_size_in_bytes < (keep + 2.5) * block
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + memory.output_size_in_bytes) < V5E_HBM_BYTES
+        assert "krr.mirror" in text
+        assert not re.search(rf"= f32\[{n},{b}\]\{{[^}}]*\}} copy\(", text)
     else:
         assert block <= memory.temp_size_in_bytes < 1.5 * block
 

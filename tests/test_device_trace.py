@@ -260,12 +260,13 @@ def test_the_recorded_trace_holds_every_scope_with_time_of_its_own(recorded):
     # And on the one chip that recorded it nothing crosses a mesh: the
     # ``coll.`` scopes name nothing there (``tests/test_imagenet_mesh.py``
     # holds them in the mesh's compiled programs). The recording predates
-    # the patch cut's scopes (``filters.``) and the generated-block solve's
-    # (``solver.generate``, ``solver.fetch``), which
-    # ``tools/record_scoped_trace.py`` now runs: recorded again on a chip,
-    # they join the rest; ``tests/test_aot_tpu.py`` and
-    # ``tests/test_generated_blocks.py`` hold them meanwhile.
-    newer = ("solver.generate", "solver.fetch")
+    # the patch cut's scopes (``filters.``), the generated-block solve's
+    # (``solver.generate``, ``solver.fetch``) and the kernel solver's copies
+    # (``krr.mirror``), which ``tools/record_scoped_trace.py`` now runs:
+    # recorded again on a chip, they join the rest; ``tests/test_aot_tpu.py``,
+    # ``tests/test_generated_blocks.py`` and ``tests/test_observability.py``
+    # hold them meanwhile.
+    newer = ("solver.generate", "solver.fetch", "krr.mirror")
     for scope in DEVICE_SCOPES:
         if (scope != "conv.relayout" and not scope.startswith("coll.")
                 and not scope.startswith("filters.") and scope not in newer):

@@ -145,16 +145,80 @@ def test_the_kept_blocks_are_the_blocks_made_again(adapter, monkeypatch, keep, o
     assert np.array_equal(alpha, plain)
     kept = 0 if order is _first_epoch_a_block_short else keep
     assert {k: span[k] for k in (
-        "blocks", "epochs", "kernel_blocks", "kernel_bytes", "blocks_kept", "store_bytes")} == {
+        "blocks", "epochs", "kernel_blocks", "kernel_bytes", "blocks_kept", "store_bytes",
+        "mirrored_rows")} == {
         "blocks": 5, "epochs": 3, "kernel_blocks": 15 - 2 * kept,
         "kernel_bytes": (15 - 2 * kept) * 160 * 32 * 4,
-        "blocks_kept": kept, "store_bytes": kept * 160 * 32 * 4}
+        "blocks_kept": kept, "store_bytes": kept * 160 * 32 * 4,
+        # Eight shards: a block's rows of another block lie on other chips.
+        "mirrored_rows": 0}
     reset_tracer()
     if order is NATURAL:
         sizes = {"rows": 150, "block_size": 32, "num_epochs": 3, "gamma": gamma, "lam": lam}
         with jax.default_matmul_precision("highest"):
             want = adapter.solve(jnp.asarray(X), jnp.asarray(Y), sizes)
         assert _gap(alpha[:150], want) < 1e-4
+
+
+def _rows_mirrored(visits, keep, block=32):
+    """What the rule copies, counted apart from the program's counter: at
+    each block made (every visit of the first epoch, a later one to a block
+    with no slot), the slots 0, 1, ... up to the first the first epoch has
+    not written yet, a whole tile of live rows each."""
+    written, rows = set(), 0
+    for i, b in enumerate(visits):
+        if i < 5 or b >= keep:
+            rows += block * next(t for t in range(keep + 1) if t not in written)
+        if i < 5 and b < keep:
+            written.add(b)
+    return rows
+
+
+@pytest.mark.parametrize("order", [
+    NATURAL, _each_epoch_its_own_order, _first_epoch_a_block_short],
+    ids=["natural", "permuted", "outside-the-contract"])
+@pytest.mark.parametrize("keep", [0, 2, 5])
+def test_one_shard_copies_the_rows_a_kept_block_holds(adapter, monkeypatch, keep, order):
+    """On one device a block's rows of the blocks already kept are copied
+    from their slots, transposed, not made again: three epochs over 150
+    rows in blocks of 32. The weights are the same mesh's without a store,
+    to the bit (the Gaussian kernel adds |x|² and |z|² before it takes
+    2x·z off, and the CPU's product of x and z is that of z and x), the
+    span counts the rows copied, and the pad rows and the ragged block's
+    pad stay exactly 0."""
+    mesh_util.set_default_mesh(Mesh(np.asarray(jax.devices()[:1]), (config.data_axis,)))
+    X, Y = _problem(31, 150)
+    gamma, lam = 0.2, 0.5
+    A, B = RowMatrix.from_array(X), RowMatrix.from_array(Y)
+    assert A.mesh.shape[config.data_axis] == 1 and A.padded_rows == 160
+    monkeypatch.setattr(kernel_ridge, "_visit_order", order)
+    monkeypatch.setattr(config, "trace", True)
+
+    def solve(slots):
+        reset_tracer()
+        monkeypatch.setattr(kernel_ridge, "_blocks_kept", lambda *sizes: slots)
+        alpha = np.asarray(kernel_ridge.kernel_block_gauss_seidel(
+            A, B, GaussianKernelGenerator(gamma), lam, block_size=32, num_epochs=3))
+        return alpha, recorded_tracer().spans()[-1]["args"]
+
+    plain, none = solve(0)
+    alpha, span = solve(keep)
+    reset_tracer()
+    assert none["mirrored_rows"] == 0
+    assert np.array_equal(alpha, plain)
+    kept = 0 if order is _first_epoch_a_block_short else keep
+    assert span["blocks_kept"] == kept
+    assert span["mirrored_rows"] == _rows_mirrored(order(5, 3), kept)
+    if order is NATURAL:
+        # Visit j of the first epoch copies min(j, keep) tiles; with two
+        # slots each later visit to blocks 2, 3 and 4 copies both.
+        assert span["mirrored_rows"] == {0: 0, 2: (7 + 12) * 32, 5: 10 * 32}[keep]
+        sizes = {"rows": 150, "block_size": 32, "num_epochs": 3, "gamma": gamma, "lam": lam}
+        with jax.default_matmul_precision("highest"):
+            want = adapter.solve(jnp.asarray(X), jnp.asarray(Y), sizes)
+        assert _gap(alpha[:150], want) < 1e-4
+    assert (alpha[150:] == 0.0).all() and np.isfinite(alpha).all()
+    assert np.abs(alpha[:150]).min() > 0.0
 
 
 # (one shard's rows, block, blocks, epochs, the shard's X and Y in bytes,
@@ -266,9 +330,11 @@ def test_a_second_fit_compiles_nothing():
     assert _gap(first.alpha, second.alpha) > 0.1  # and not the first fit's answer
 
 
-@pytest.mark.parametrize("keep", [0, 2, 5])
-def test_the_solvers_program_takes_its_operands_as_arguments(keep):
-    mesh = mesh_util.default_mesh()
+@pytest.mark.parametrize("keep, width", [
+    (0, 8), (2, 8), (5, 8), (0, 1), (2, 1), (5, 1)],
+    ids=["0", "2", "5", "one-shard-0", "one-shard-2", "one-shard-5"])
+def test_the_solvers_program_takes_its_operands_as_arguments(keep, width):
+    mesh = Mesh(np.asarray(jax.devices()[:width]), (config.data_axis,))
     rows, d, k, block = 160, 6, 3, 32
     f32, shape = jnp.float32, jax.ShapeDtypeStruct
     solve = kernel_ridge._block_solve_fn(
@@ -282,10 +348,14 @@ def test_the_solvers_program_takes_its_operands_as_arguments(keep):
     assert _arguments(text) == 6
     # The loop over the visits is in the program (two with a store: the
     # epoch that fills it and the ones that read it), and no kernel larger
-    # than the store's slots: nothing of rows x rows.
-    assert text.count("stablehlo.while") == (2 if keep else 1)
+    # than the store's slots: nothing of rows x rows. On one shard with a
+    # store a block is made by two loops over its row tiles, generated and
+    # copied: in the first epoch, and where a later one makes a block again.
+    mirror = 2 * (1 + (keep < 5)) if keep and width == 1 else 0
+    assert text.count("stablehlo.while") == (2 if keep else 1) + mirror
     assert f"tensor<{rows}x{rows}x" not in text
-    assert (f"tensor<{keep}x{rows // mesh.shape[config.data_axis]}x{block}x" in text) == bool(keep)
+    assert (f"tensor<{keep}x{rows // width}x{block}x" in text) == bool(keep)
+    assert f"tensor<{keep + 1}x{rows // width}x{block}x" not in text
 
 
 def test_the_mapper_names_its_arrays_and_applies_in_blocks():

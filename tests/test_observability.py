@@ -997,11 +997,13 @@ def _compiled_scopes(lowered):
 def _solver_lowerings():
     import jax
     import jax.numpy as jnp
+    from jax.sharding import Mesh
 
     from keystone_tpu.linalg import RowMatrix, bcd
     from keystone_tpu.nodes.learning import GaussianKernelGenerator, kernel_ridge
 
     mesh, axis = RowMatrix.from_array(np.zeros((16, 4), np.float32)).mesh, config.data_axis
+    one = Mesh(np.asarray(jax.devices()[:1]), (axis,))
     rows, nb, b, k = 16 * mesh.shape[axis], 2, 8, 3
     f32, shape = jnp.float32, jax.ShapeDtypeStruct
     precision = bcd._precision()
@@ -1033,6 +1035,10 @@ def _solver_lowerings():
             shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5))
            for name, keep in (("kernel solver", 0), ("kernel solver, blocks kept", 3),
                               ("kernel solver, some blocks kept", 2))},
+        "kernel solver, one shard, some blocks kept": lambda: kernel_ridge._block_solve_fn(
+            one, axis, kernel_ridge._precision(), bcd.fold_blocks(1), 8, 2, 3).lower(
+            shape((16, 4), f32), shape((16, 3), f32), shape((), f32),
+            shape((), jnp.int32), shape((6,), jnp.int32), GaussianKernelGenerator(0.5)),
     }
 
 
@@ -1053,6 +1059,10 @@ SOLVER_SCOPES = {
         "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve", "coll.atr"},
     "kernel solver, some blocks kept": {
         "krr.generate", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve", "coll.atr"},
+    # One shard: a block's rows of the kept blocks are copied from their
+    # slots (``krr.mirror``), and nothing crosses a mesh.
+    "kernel solver, one shard, some blocks kept": {
+        "krr.generate", "krr.mirror", "krr.fetch", "krr.reduce", "krr.factor", "krr.solve"},
 }
 
 
